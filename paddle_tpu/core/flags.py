@@ -373,7 +373,9 @@ define_flag("compile_cache_dir", "",
             "rebuilt engine in a FRESH process (durability."
             "restore_from_dir) warm-starts its executables from disk "
             "instead of recompiling.  Process-global (jax config), "
-            "applied at the first engine construction that sees it")
+            "applied at the first engine construction that sees it; "
+            "JAX_COMPILATION_CACHE_DIR in the environment wins over it "
+            "(core.compile_cache)")
 define_flag("step_timeout_ms", 0.0,
             "hung-step watchdog (inference.durability.StepWatchdog): a "
             "DecodeEngine.step exceeding this wall-clock budget is "
@@ -405,9 +407,11 @@ define_flag("flight_dir", "",
 define_flag("cost_model", True,
             "serving cost observatory (observability.costmodel): "
             "extract a static FLOP/byte profile per compiled step "
-            "executable at compile time (HLO cost analysis over the "
-            "lowered computation — tracing only, never a second "
-            "compile), predict step cost from the profiles with a "
+            "executable at compile time (HLO cost analysis of the "
+            "lowered computation on the CPU backend, of the compiled "
+            "program on a TPU — the jit call that follows reuses that "
+            "executable, so no step compiles twice), predict step "
+            "cost from the profiles with a "
             "per-executable EWMA calibration learned from the flight "
             "recorder's measured step times, account live device "
             "bytes in the HBM ledger, and compute per-phase MFU / "
@@ -443,11 +447,11 @@ define_flag("peak_ici_gbps", 0.0,
             "0 (default) = autodetect from the device kind (CPU pins "
             "a fixed test value so CI gauges are deterministic)")
 define_flag("cost_memory_analysis", False,
-            "additionally compile the lowered computation AOT and "
-            "record each executable's peak temp-buffer allocation "
-            "(Compiled.memory_analysis) into its cost profile and the "
-            "HBM ledger's temp_scratch category — one EXTRA XLA "
-            "compile per unique executable, so default off")
+            "additionally record each executable's peak temp-buffer "
+            "allocation (Compiled.memory_analysis of the lowered "
+            "computation, compiled at profile extraction; the jit "
+            "call that follows reuses the executable) into its cost "
+            "profile and the HBM ledger's temp_scratch category")
 define_flag("cost_ledger_interval_steps", 128,
             "engine steps between HBM-ledger audits "
             "(observability.costmodel.CostModel.hbm_ledger: attribute "

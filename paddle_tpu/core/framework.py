@@ -111,13 +111,9 @@ def _rng_impl() -> str:
     process-global jax default impl is never touched."""
     global _RNG_IMPL
     if _RNG_IMPL is None:
-        impl = "threefry2x32"
-        try:
-            if _flags.flag("use_rbg_rng") and jax.default_backend() == "tpu":
-                impl = "rbg"
-        except Exception:
-            pass
-        _RNG_IMPL = impl
+        on_tpu = _flags.flag("use_rbg_rng") and \
+            jax.default_backend() == "tpu"
+        _RNG_IMPL = "rbg" if on_tpu else "threefry2x32"
     return _RNG_IMPL
 
 

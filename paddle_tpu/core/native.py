@@ -13,63 +13,115 @@ The native layer provides the C++ substrate that the reference implements in
                      (reference buffered_reader.cc / reader_py.cc)
 * flags / stats / tracer — platform/flags.cc, monitor.cc, profiler.h
 
-Build: ``cmake -B build -G Ninja csrc && ninja -C build``.  If the shared
-library is absent this module builds it on first import (g++ toolchain is a
-baked-in dependency); all consumers degrade gracefully through
-``native_available()``.
+Build: the shared library is built from ``csrc/`` on first use, once per
+checkout (`_build`): under a cross-process file lock, into a scratch
+directory whose products are renamed into ``build/`` only when complete.
+A failed build is kept in `build_error()` and shown once as a warning;
+consumers that can do without the library check ``native_available()``.
 """
 from __future__ import annotations
 
 import ctypes
+import fcntl
 import os
+import shutil
 import subprocess
+import tempfile
 import threading
+import warnings
 
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
+_BUILD_DIR = os.path.join(_REPO_ROOT, "build")
+_LIB_NAME = "libpaddle_tpu_rt.so"
 _LIB_CANDIDATES = (
     # source-tree builds first so a rebuild is never shadowed by a stale
     # packaged copy; the packaged location (setup.py puts the lib there
     # for wheels) is the fallback when no source build exists
-    os.path.join(_REPO_ROOT, "build", "libpaddle_tpu_rt.so"),
-    os.path.join(_REPO_ROOT, "csrc", "libpaddle_tpu_rt.so"),
+    os.path.join(_BUILD_DIR, _LIB_NAME),
+    os.path.join(_REPO_ROOT, "csrc", _LIB_NAME),
     os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-                 "lib", "libpaddle_tpu_rt.so"),
+                 "lib", _LIB_NAME),
 )
 
 _lib = None
 _lib_lock = threading.Lock()
+# why the one build attempt of this process failed (None: not tried, or
+# it succeeded).  Only the BUILD is attempted once; the library itself is
+# looked for again on every call, so one that another process finishes
+# later is still picked up.
+_build_error: str | None = None
 
 
-def _try_build() -> str | None:
-    """Build the native library in-tree (best effort, quiet)."""
+def build_error() -> str | None:
+    """The captured cmake/ninja failure of this process's build attempt."""
+    return _build_error
+
+
+def _run(cmd, timeout):
+    r = subprocess.run(cmd, capture_output=True, text=True, timeout=timeout)
+    if r.returncode != 0:
+        raise RuntimeError(
+            f"{' '.join(cmd)} exited {r.returncode}:\n"
+            f"{(r.stdout + r.stderr)[-2000:]}")
+
+
+def _build() -> str:
+    """Build ``csrc/`` into ``build/`` — one build per checkout however
+    many processes ask at once.  The file lock serialises them; whoever
+    holds it first compiles in a scratch directory and renames the
+    finished libraries into place, so ``build/`` never holds a partial
+    product and everyone after finds it there.  Raises with the tool's
+    own output on failure."""
     src = os.path.join(_REPO_ROOT, "csrc")
-    build = os.path.join(_REPO_ROOT, "build")
+    final = os.path.join(_BUILD_DIR, _LIB_NAME)
     if not os.path.isdir(src):
-        return None
+        raise RuntimeError(f"no native sources at {src}")
+    with open(os.path.join(_REPO_ROOT, ".native_build.lock"), "w") as lock:
+        fcntl.flock(lock, fcntl.LOCK_EX)  # released when the file closes
+        if os.path.exists(final):
+            return final
+        work = tempfile.mkdtemp(prefix="build.tmp.", dir=_REPO_ROOT)
+        try:
+            tree = os.path.join(work, "cmake")
+            _run(["cmake", "-S", src, "-B", tree, "-G", "Ninja"], 120)
+            _run(["ninja", "-C", tree], 300)
+            out = os.path.join(work, "out")
+            os.mkdir(out)
+            for name in os.listdir(tree):
+                if name.endswith(".so"):
+                    os.rename(os.path.join(tree, name),
+                              os.path.join(out, name))
+            # a build/ without the library is an interrupted older
+            # attempt (we hold the lock, so nobody is writing it)
+            shutil.rmtree(_BUILD_DIR, ignore_errors=True)
+            os.rename(out, _BUILD_DIR)
+        finally:
+            shutil.rmtree(work, ignore_errors=True)
+    return final
+
+
+def _find_or_build() -> str | None:
+    global _build_error
+    path = next((p for p in _LIB_CANDIDATES if os.path.exists(p)), None)
+    if path is not None or _build_error is not None:
+        return path
     try:
-        subprocess.run(["cmake", "-B", build, "-G", "Ninja", src],
-                       check=True, capture_output=True, timeout=120)
-        subprocess.run(["ninja", "-C", build], check=True,
-                       capture_output=True, timeout=300)
-    except Exception:
+        return _build()
+    except (OSError, RuntimeError, subprocess.TimeoutExpired) as e:
+        _build_error = f"{type(e).__name__}: {e}"
+        warnings.warn(f"native runtime not built: {_build_error}",
+                      RuntimeWarning, stacklevel=3)
         return None
-    path = os.path.join(build, "libpaddle_tpu_rt.so")
-    return path if os.path.exists(path) else None
 
 
 def _load():
     global _lib
     with _lib_lock:
         if _lib is not None:
-            # False = a previous attempt failed; don't re-run cmake/ninja on
-            # every facade call.
-            return None if _lib is False else _lib
-        path = next((p for p in _LIB_CANDIDATES if os.path.exists(p)), None)
+            return _lib
+        path = _find_or_build()
         if path is None:
-            path = _try_build()
-        if path is None:
-            _lib = False
             return None
         lib = ctypes.CDLL(path)
         # ---- signatures ----
@@ -126,6 +178,15 @@ def native_available() -> bool:
     return _load() is not None
 
 
+def _require():
+    """The loaded library, or an error that says why there is none."""
+    lib = _load()
+    if lib is None:
+        raise RuntimeError(
+            f"native runtime unavailable: {_build_error or 'not found'}")
+    return lib
+
+
 def _check(rc: int):
     if rc != 0:
         lib = _load()
@@ -141,11 +202,8 @@ class Arena:
     """Best-fit auto-growth host arena (see csrc/allocator.cc)."""
 
     def __init__(self, chunk_size: int = 64 << 20):
-        lib = _load()
-        if lib is None:
-            raise RuntimeError("native runtime unavailable")
-        self._lib = lib
-        self._h = lib.ptrt_arena_create(chunk_size)
+        self._lib = _require()
+        self._h = self._lib.ptrt_arena_create(chunk_size)
 
     def alloc(self, size: int) -> int:
         out = ctypes.c_void_p()
@@ -184,12 +242,9 @@ class TaskGraph:
     """Dependency-counted DAG run on a native thread pool."""
 
     def __init__(self, n_threads: int = 0):
-        lib = _load()
-        if lib is None:
-            raise RuntimeError("native runtime unavailable")
-        self._lib = lib
-        self._pool = lib.ptrt_pool_create(n_threads)
-        self._g = lib.ptrt_graph_create()
+        self._lib = _require()
+        self._pool = self._lib.ptrt_pool_create(n_threads)
+        self._g = self._lib.ptrt_graph_create()
         self._cbs = []  # keep trampolines alive
 
     def add_node(self, fn) -> int:
@@ -232,10 +287,7 @@ class PrefetchQueue:
 
     def __init__(self, producer, capacity: int = 4, n_workers: int = 1,
                  ordered: bool = True, arena: Arena | None = None):
-        lib = _load()
-        if lib is None:
-            raise RuntimeError("native runtime unavailable")
-        self._lib = lib
+        self._lib = _require()
         self._arena = arena or Arena(16 << 20)
         self._producer = producer
         self._error = None  # first producer exception, re-raised in pop()
@@ -259,7 +311,7 @@ class PrefetchQueue:
             return 0
 
         self._cb = _PRODUCER_CB(_produce)
-        self._h = lib.ptrt_prefetch_create(
+        self._h = self._lib.ptrt_prefetch_create(
             capacity, n_workers, ctypes.cast(self._cb, ctypes.c_void_p),
             None, 1 if ordered else 0)
 

@@ -33,9 +33,9 @@ class Place:
     def jax_device(self) -> jax.Device:
         devs = [d for d in jax.devices() if d.platform == self.device_type]
         if not devs:
-            # fall back to the default backend (e.g. running TPU code paths
-            # on the CPU simulator mesh)
-            devs = jax.devices()
+            raise RuntimeError(
+                f"{self!r} asked for a {self.device_type!r} device and JAX "
+                f"has none (devices: {jax.devices()})")
         return devs[self.device_id % len(devs)]
 
 

@@ -11,9 +11,13 @@ Usage:
         [--servers ip:port,...] [--workers ip:port,...]
         [--log_dir dir] script.py [script args...]
 
-TPU note: one host process per chip-group; JAX process env
-(`JAX_PROCESS_COUNT` etc.) rides alongside the PADDLE_* variables so both
-API families see the same topology.
+Who owns the chip: the children.  The launcher only supervises processes;
+importing `paddle_tpu` initializes no JAX backend and nothing here asks
+for devices, so the launcher never holds a chip.  A chip belongs to one
+process at a time: the single-controller layout is ONE child per host
+driving all of its chips (`--nproc_per_node 1`, the default), and more
+children per host only work where the caller's ``env_extra`` gives each
+its own chips (or, as in the tests, pins them to CPU devices).
 """
 from __future__ import annotations
 

@@ -47,9 +47,7 @@ _PS_SIGS = False
 
 
 def _lib():
-    lib = native._load()
-    if lib is None:
-        raise RuntimeError("native runtime unavailable (PS needs csrc build)")
+    lib = native._require()
     global _PS_SIGS
     if not _PS_SIGS:
         lib.ptrt_ps_server_create.restype = ctypes.c_void_p

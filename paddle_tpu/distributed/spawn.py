@@ -1,9 +1,14 @@
 """paddle.distributed.spawn (reference `distributed/spawn.py`).
 
 In the single-controller TPU model one process drives all local chips, so
-`spawn(fn, nprocs=-1)` simply runs `fn` once in-process.  Multi-host spawn
-launches one process per host via multiprocessing when explicitly requested
-(each child must set PADDLE_TRAINER_ID / COORDINATOR_ADDRESS).
+`spawn(fn, nprocs=-1)` simply runs `fn` once in-process — the caller owns
+the chips.  Multi-host spawn launches one process per host via
+multiprocessing when explicitly requested (each child must set
+PADDLE_TRAINER_ID / COORDINATOR_ADDRESS).  There the CHILDREN own the
+chips, one process at a time per chip: a parent that has already touched
+a JAX backend holds the host's chips, and its children then fail or hang,
+so call `spawn(nprocs>1)` before anything in the parent asks JAX for
+devices.
 """
 from __future__ import annotations
 

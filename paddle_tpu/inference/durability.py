@@ -30,7 +30,7 @@ remained, and this module closes both plus a third failure class:
   construction, so the jit caches stay warm — no recompile, no warm
   retrace), falling back to recompile on any mismatch.  Cross-process
   restarts warm-start through JAX's persistent compilation cache
-  (``FLAGS_compile_cache_dir``, `enable_compile_cache`).
+  (``FLAGS_compile_cache_dir``, `core.compile_cache`).
 
 * **Hung steps** — a step that RAISES rides the containment ladder; a
   step that simply never returns (device wedge, runtime deadlock) used
@@ -66,7 +66,7 @@ from .errors import FaultInfo, HungStep
 
 __all__ = ["RequestWire", "SnapshotWire", "DurabilityManager",
            "StepWatchdog", "read_journal", "load_snapshot",
-           "restore_from_dir", "enable_compile_cache", "set_health",
+           "restore_from_dir", "set_health",
            "clear_health", "retire_engine_series", "HEALTH_STATES",
            "JOURNAL_NAME", "SNAPSHOT_NAME", "KV_PAGES_NAME"]
 
@@ -1027,55 +1027,6 @@ def adopt_from_dir(journal_dir: str, engine,
         engine._flight.event("adopt", requests=len(reqs),
                              donor=journal_dir)
     return reqs, meta
-
-
-# ---------------------------------------------------------------------------
-# JAX persistent compilation cache (cross-process executable warm start)
-# ---------------------------------------------------------------------------
-_compile_cache_applied: Optional[str] = None
-
-
-def enable_compile_cache(cache_dir: str) -> bool:
-    """Point JAX's persistent compilation cache at ``cache_dir`` so a
-    fresh process's executables deserialize from disk instead of
-    recompiling (the cross-process half of fast recovery; in-process
-    recovery uses `DecodeEngine.adopt_executables`).  Process-global
-    and idempotent; returns False when this jax build does not expose
-    the cache config."""
-    global _compile_cache_applied
-
-    cache_dir = str(cache_dir)
-    if _compile_cache_applied == cache_dir:
-        return True
-    import jax
-
-    os.makedirs(cache_dir, exist_ok=True)
-    try:
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-    except Exception:
-        return False
-    # CPU compiles are small and fast — without these thresholds the
-    # cache would skip exactly the executables a CPU test bed needs
-    for opt, val in (
-            ("jax_persistent_cache_min_entry_size_bytes", -1),
-            ("jax_persistent_cache_min_compile_time_secs", 0.0)):
-        try:
-            jax.config.update(opt, val)
-        except Exception:
-            pass
-    # jax latches its cache decision at the FIRST compile; anything
-    # jitted before this call (model construction, eager dispatch)
-    # already concluded "no cache" — reset so the next compile
-    # re-initializes against the directory
-    try:
-        from jax.experimental.compilation_cache import (
-            compilation_cache as _cc)
-
-        _cc.reset_cache()
-    except Exception:
-        pass
-    _compile_cache_applied = cache_dir
-    return True
 
 
 # ---------------------------------------------------------------------------

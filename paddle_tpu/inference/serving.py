@@ -228,6 +228,10 @@ class _JitTracker:
         self.compile_key = compile_key
         self._seen = 0
         self._warm = False
+        # abstract (shape, dtype, sharding) signature of the first call
+        # — the one shape this executable serves; `lower` rebuilds the
+        # program from it after the donated operands are gone
+        self.signature = None
         # cost observatory (observability.costmodel): the profile key
         # of this executable's static FLOP/byte profile, stamped at
         # compile time (first invocation) when FLAGS_cost_model is on
@@ -239,17 +243,21 @@ class _JitTracker:
         if san is not None:
             for a in args:
                 san.check_live(a, context=f"argument of {self.site}")
-        if not self._warm and _costmodel.enabled():
-            # compile-time profile extraction, once per executable:
-            # lower the same traced call and read the HLO cost
-            # analysis (tracing only — no second compile, no new
-            # executable, _cache_size untouched).  BEFORE the call:
-            # donated operands are still live here, deleted after.
-            try:
+        if not self._warm:
+            self.signature = jax.tree_util.tree_map(
+                lambda a: jax.ShapeDtypeStruct(
+                    a.shape, a.dtype, sharding=a.sharding,
+                    weak_type=a.weak_type), args)
+            if _costmodel.enabled():
+                # compile-time profile extraction, once per executable:
+                # lower the same traced call and read the HLO cost
+                # analysis (_cache_size untouched; where the backend
+                # analyses compiled programs only, the compile happens
+                # there and the call below reuses its executable).
+                # BEFORE the call: donated operands are still live
+                # here, deleted after.
                 self.cost_sig = _costmodel.note_executable(
                     self.site, self.fn, args)
-            except Exception:
-                self.cost_sig = None  # analytical fallback covers it
         out = self.fn(*args)
         self.check_retrace()
         if san is not None:
@@ -258,12 +266,18 @@ class _JitTracker:
                     san.tombstone(args[i], self.site)
         return out
 
+    def lower(self):
+        """The step program lowered against its first call's signature:
+        ``.compile()`` of the result gives the executable's HLO text
+        (is the Pallas kernel in it?) and its memory analysis, without
+        touching live buffers.  Only after the tracker's first call."""
+        if self.signature is None:
+            raise RuntimeError(f"{self.site} has not been called yet")
+        return self.fn.lower(*self.signature)
+
     def check_retrace(self):
         """Runs after every invocation (``__call__`` does it)."""
-        try:
-            n = self.fn._cache_size()
-        except AttributeError:  # older jax without _cache_size
-            n = 1
+        n = self.fn._cache_size()
         grew = n - self._seen if self._warm else 0
         was = self._seen
         self._seen = n
@@ -1311,6 +1325,40 @@ def _mesh_constrain(mesh):
     return cst
 
 
+def _mesh_paged_attention(mesh):
+    """`pa.paged_attention` for the ragged twins: called directly on the
+    single-chip path (``mesh=None``), and inside a `jax.shard_map` over
+    ``mp`` under the serving mesh.  Heads are already chip-local there
+    (`partition.kv_pages_spec`), so each chip attends over its own
+    head-slice of every page with no communication — but a Mosaic kernel
+    cannot be partitioned by GSPMD from sharding constraints alone, it
+    has to be told per chip, which is what the shard_map does.  Block
+    tables and lengths are replicated host state."""
+    def direct(q, k_pages, v_pages, block_tables, seq_lens, q_offsets,
+               k_scales=None, v_scales=None):
+        return pa.paged_attention(q, k_pages, v_pages, block_tables,
+                                  seq_lens, q_offsets=q_offsets,
+                                  k_scales=k_scales, v_scales=v_scales)
+
+    if mesh is None:
+        return direct
+    heads = PartitionSpec(None, None, "mp", None)  # q, out: [B, Q, H, D]
+    by_head = PartitionSpec("mp")   # pages [Hkv, P, page, D], scales [Hkv, P]
+    rep = PartitionSpec()
+
+    def sharded(q, k_pages, v_pages, block_tables, seq_lens, q_offsets,
+                k_scales=None, v_scales=None):
+        scales = () if k_scales is None else (k_scales, v_scales)
+        return jax.shard_map(
+            direct, mesh=mesh,
+            in_specs=(heads, by_head, by_head, rep, rep, rep)
+            + (by_head,) * len(scales),
+            out_specs=heads, check_vma=False,
+        )(q, k_pages, v_pages, block_tables, seq_lens, q_offsets, *scales)
+
+    return sharded
+
+
 def _gpt_ragged_step(params, k_pages, v_pages, block_tables, seq_lens,
                      tokens, write_caps, key, *, num_heads, head_dim,
                      eps, sampler, temperature, top_k, top_p,
@@ -1340,6 +1388,7 @@ def _gpt_ragged_step(params, k_pages, v_pages, block_tables, seq_lens,
     num_pages_total = k_pages.shape[2]
     page = k_pages.shape[3]
     cst = _mesh_constrain(mesh)
+    attend = _mesh_paged_attention(mesh)
 
     pos = seq_lens[:, None] + jnp.arange(qn, dtype=jnp.int32)[None, :]
     wpe_max = params["wpe"].shape[0] - 1
@@ -1363,9 +1412,8 @@ def _gpt_ragged_step(params, k_pages, v_pages, block_tables, seq_lens,
         v_pages = cst(
             v_pages.at[li, :, page_idx, slot, :].set(qkv[:, :, 2]),
             None, "mp", None, None, None)
-        attn = cst(pa.paged_attention(q, k_pages[li], v_pages[li],
-                                      block_tables, lens_now,
-                                      q_offsets=seq_lens),
+        attn = cst(attend(q, k_pages[li], v_pages[li], block_tables,
+                          lens_now, seq_lens),
                    None, None, "mp", None)
         # row-parallel out proj: replicating the residual forces the
         # cross-chip all-reduce exactly here (heads fuse head-major
@@ -1411,6 +1459,7 @@ def _gpt_ragged_step_q(params, k_pages, v_pages, k_scales, v_scales,
     num_pages_total = k_pages.shape[2]
     page = k_pages.shape[3]
     cst = _mesh_constrain(mesh)
+    attend = _mesh_paged_attention(mesh)
 
     pos = seq_lens[:, None] + jnp.arange(qn, dtype=jnp.int32)[None, :]
     wpe_max = params["wpe"].shape[0] - 1
@@ -1446,11 +1495,8 @@ def _gpt_ragged_step_q(params, k_pages, v_pages, k_scales, v_scales,
         v_pages = cst(v_pages, None, "mp", None, None, None)
         v_scales = cst(v_scales, None, "mp", None)
         refolds = refolds + rk + rv
-        attn = cst(pa.paged_attention(q, k_pages[li], v_pages[li],
-                                      block_tables, lens_now,
-                                      q_offsets=seq_lens,
-                                      k_scales=k_scales[li],
-                                      v_scales=v_scales[li]),
+        attn = cst(attend(q, k_pages[li], v_pages[li], block_tables,
+                          lens_now, seq_lens, k_scales[li], v_scales[li]),
                    None, None, "mp", None)
         # row-parallel out proj / fc2: the block's two all-reduces
         x = cst(x + _wmm(attn.reshape(b, qn, h), blk, "out_w")
@@ -1913,7 +1959,7 @@ class DecodeEngine:
         self._watchdog = None
         compile_cache = str(_flags.flag("compile_cache_dir"))
         if compile_cache:
-            from .durability import enable_compile_cache
+            from ..core.compile_cache import enable_compile_cache
 
             enable_compile_cache(compile_cache)
 

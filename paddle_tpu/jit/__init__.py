@@ -280,21 +280,34 @@ class TrainStep:
         donate = (1, 2) if self._donate else ()
         return jax.jit(pure, donate_argnums=donate)
 
-    def __call__(self, *batch) -> Tensor:
+    def _operands(self, batch, step, rng):
+        """The jitted step's operand tuple for ``batch`` (builds the
+        step function and the optimizer state on first use)."""
         if self._compiled is None:
             self._compiled = self._build()
         if self._opt_state is None:
             self._opt_state = self.optimizer.init_state(self._params)
-        self._step += 1
         parr = {k: self._params[k]._array for k in self._pnames}
         barr = {k: self._buffers[k]._array for k in self._bnames}
         batch_arrs = [b._array if isinstance(b, Tensor) else jnp.asarray(b)
                       for b in batch]
-        rng = framework.default_generator.next_key()
         lr = jnp.asarray(self.optimizer.get_lr(), jnp.float32)
-        loss, new_params, new_opt, new_bufs = self._compiled(
-            parr, self._opt_state, barr, lr, self._step, rng, tuple(batch_arrs)
-        )
+        return (parr, self._opt_state, barr, lr, step, rng,
+                tuple(batch_arrs))
+
+    def lower(self, *batch):
+        """Lower the step against ``batch`` without running it or moving
+        the step/RNG state: ``.compile()`` of the result gives the
+        executable's HLO text and memory analysis."""
+        ops = self._operands(batch, self._step + 1,
+                             framework.make_rng_key(0))
+        return self._compiled.lower(*ops)
+
+    def __call__(self, *batch) -> Tensor:
+        self._step += 1
+        ops = self._operands(batch, self._step,
+                             framework.default_generator.next_key())
+        loss, new_params, new_opt, new_bufs = self._compiled(*ops)
         with framework.no_grad_guard():
             for k in self._pnames:
                 self._params[k]._array = new_params[k]

@@ -62,15 +62,7 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
                                  dropout_p=0.0, is_causal=False, scale=None,
                                  training=True, name=None):
     """query/key/value: [batch, num_heads, seq, head_dim]."""
-    use_pallas = False
-    try:
-        q_arr = unwrap(query)
-        if q_arr.ndim == 4 and jax.default_backend() == "tpu":
-            use_pallas = True
-    except Exception:
-        pass
-
-    if use_pallas:
+    if unwrap(query).ndim == 4 and jax.default_backend() == "tpu":
         from ...ops.pallas.flash_attention import flash_attention_fwd
 
         def f(q, k, v, *m):

@@ -11,17 +11,21 @@ Four layers:
 * **Static cost profiles** — every serving executable passes through
   the `_JitTracker` chokepoint (inference.serving); on its FIRST
   invocation the tracker calls `note_executable`, which lowers the
-  SAME traced call (`jitted.lower(*args)` — tracing only, never a
-  second XLA compile, never a new executable) and reads the lowered
-  computation's HLO cost analysis: FLOPs and HBM bytes accessed.
+  SAME traced call (`jitted.lower(*args)`, never a new jit-cache
+  entry) and reads XLA's HLO cost analysis: FLOPs and HBM bytes
+  accessed.  The CPU backend analyses the lowering; the TPU backend
+  analyses compiled programs only, so there the lowering is compiled
+  first.  That is the executable's ONE compile, not a second: the jit
+  call that follows finds the same lowering in jit's in-memory cache
+  and runs the executable it already holds (backend compiles counted
+  equal with the observatory on and off, tests/test_costmodel.py).
   Profiles are keyed by the executable's **call signature** — the
   per-argument ``(shape, dtype, weak_type)`` tuple scheme the eager
   dispatch cache (core.dispatch) keys executables by — and stored in
   the process-global `_PROFILES` table under the module lock.  Peak
-  temp allocation additionally requires an XLA compile
-  (`lowered.compile().memory_analysis()`), so it is gated behind
-  ``FLAGS_cost_memory_analysis`` (default off: one extra compile per
-  unique executable is real money on TPU).  Backends whose HLO cost
+  temp allocation is read from the compiled program
+  (`lowered.compile().memory_analysis()`) behind
+  ``FLAGS_cost_memory_analysis`` (default off).  Backends whose HLO cost
   analysis is unavailable fall back to `analytical_gpt_cost`, a
   closed-form GPT FLOP/byte formula parameterized by
   batch/Q/kv-len/dims.
@@ -89,6 +93,7 @@ from ..analysis.sanitizer import TrackedLock as _TrackedLock
 __all__ = ["CostProfile", "CostModel", "enabled", "note_executable",
            "profile_signature", "analytical_gpt_cost", "profiles",
            "profile_by_key", "clear_profiles", "resolve_peaks",
+           "DEVICE_PEAKS", "device_peaks",
            "LEDGER_CATEGORIES"]
 
 # THE cost-observatory lock: the process-global profile table and every
@@ -124,29 +129,44 @@ _GAUGE_EVERY = 8
 # in tens of steps
 _EWMA_ALPHA = 0.25
 
-# Pinned CPU roofline "peaks" for the autodetect path: CPU MFU numbers
-# are meaningless as absolutes, but pinning them makes CPU CI gauges
-# deterministic and comparable run over run (tests assert presence and
-# sane ranges, never absolute truth).
-_CPU_PEAK_FLOPS = 5.0e10   # 50 GFLOP/s
-_CPU_PEAK_BYTES = 2.0e10   # 20 GB/s
-# Pinned interconnect "peak" for the collective-bytes roofline term
-# (sharded executables under FLAGS_serve_mesh).  One pinned default
-# rather than a per-device datasheet column: FLAGS_peak_ici_gbps
-# overrides for real hardware, and the pin keeps CPU CI gauges
-# deterministic (the same reason the FLOP/byte peaks pin).
-_CPU_PEAK_ICI = 1.0e10     # 10 GB/s
+# The ONE table of device peaks, keyed by ``jax.Device.device_kind``:
+# the cost observatory, bench.py and chip_smoke.py all read it through
+# `device_peaks`.  A TPU whose kind has no row here is an error — add the
+# row with its source; never fall back to another device's numbers.
+#
+# "TPU v5 lite" is what a v5e chip reports.  Source: Google Cloud
+# documentation, "TPU v5e" (system architecture): 197 TFLOP/s bf16,
+# 393 TOP/s int8, 16 GB HBM2e at 819 GB/s, 1,600 Gbit/s (200 GB/s)
+# of chip-to-chip interconnect.
+#
+# The "cpu" row is not a datasheet: CPU MFU numbers are meaningless as
+# absolutes, and pinning them makes CPU CI gauges deterministic and
+# comparable run over run (tests assert presence and sane ranges, never
+# absolute truth).  It serves ``platform == "cpu"`` and nothing else.
+DEVICE_PEAKS = {
+    "TPU v5 lite": {"flops_bf16": 197e12, "ops_int8": 393e12,
+                    "hbm_bytes_per_s": 819e9, "ici_bytes_per_s": 200e9},
+    "cpu": {"flops_bf16": 5.0e10, "ops_int8": 5.0e10,
+            "hbm_bytes_per_s": 2.0e10, "ici_bytes_per_s": 1.0e10},
+}
+_CPU_PEAK_ICI = DEVICE_PEAKS["cpu"]["ici_bytes_per_s"]
 
-# device_kind substring -> (peak FLOP/s dense bf16, peak HBM bytes/s).
-# Datasheet numbers; the flags override for anything unlisted.
-_DEVICE_PEAKS = (
-    ("v5 lite", 394e12, 819e9),   # TPU v5e
-    ("v5e", 394e12, 819e9),
-    ("v5p", 459e12, 2765e9),
-    ("v4", 275e12, 1228e9),
-    ("v3", 123e12, 900e9),
-    ("v2", 46e12, 700e9),
-)
+
+def device_peaks(device=None) -> Dict[str, float]:
+    """The `DEVICE_PEAKS` row of ``device`` (default: JAX's first)."""
+    if device is None:
+        import jax
+
+        device = jax.devices()[0]
+    if device.platform == "cpu":
+        return DEVICE_PEAKS["cpu"]
+    try:
+        return DEVICE_PEAKS[device.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no peaks on record for device_kind {device.device_kind!r} "
+            f"(platform {device.platform!r}): add a row with its source "
+            f"to observability.costmodel.DEVICE_PEAKS") from None
 
 
 # engines explicitly constructed with cost_model=True while the flag
@@ -183,35 +203,33 @@ def enabled() -> bool:
 
 def resolve_peaks() -> Dict[str, float]:
     """The roofline ceilings: ``FLAGS_peak_flops`` /
-    ``FLAGS_peak_hbm_gbps`` when positive, else autodetected from the
-    default device's kind (datasheet table above; CPU pins the fixed
-    test values so CI gauges are deterministic).  ``ici_bytes_per_s``
-    (``FLAGS_peak_ici_gbps``, else the pinned default) divides the
-    collective-bytes term of sharded executables."""
+    ``FLAGS_peak_hbm_gbps`` / ``FLAGS_peak_ici_gbps`` where positive,
+    else the default device's row of `DEVICE_PEAKS` (an unlisted TPU
+    kind raises).  ``ici_bytes_per_s`` divides the collective-bytes
+    term of sharded executables."""
     from ..core import flags as _flags
 
     flops = float(_flags.flag("peak_flops"))
     gbps = float(_flags.flag("peak_hbm_gbps"))
     ici = float(_flags.flag("peak_ici_gbps"))
-    ici_bps = ici * 1e9 if ici > 0 else _CPU_PEAK_ICI
-    if flops > 0 and gbps > 0:
-        return {"flops": flops, "bytes_per_s": gbps * 1e9,
-                "ici_bytes_per_s": ici_bps, "source": "flags"}
-    kind = ""
-    try:
+    if flops > 0 and gbps > 0 and ici > 0:
+        row, source = {}, "flags"  # nothing left to look up
+    else:
         import jax
 
-        kind = str(jax.devices()[0].device_kind).lower()
-    except Exception:  # pragma: no cover - no backend at all
-        pass
-    det_f, det_b, source = _CPU_PEAK_FLOPS, _CPU_PEAK_BYTES, "cpu-pinned"
-    for sub, pf, pb in _DEVICE_PEAKS:
-        if sub in kind:
-            det_f, det_b, source = pf, pb, f"autodetect:{kind}"
-            break
-    return {"flops": flops if flops > 0 else det_f,
-            "bytes_per_s": gbps * 1e9 if gbps > 0 else det_b,
-            "ici_bytes_per_s": ici_bps,
+        dev = jax.devices()[0]
+        row = device_peaks(dev)
+        if flops > 0 and gbps > 0:
+            source = "flags"
+        elif dev.platform == "cpu":
+            source = "cpu-pinned"
+        else:
+            source = f"autodetect:{dev.device_kind}"
+    return {"flops": flops if flops > 0 else row["flops_bf16"],
+            "bytes_per_s": gbps * 1e9 if gbps > 0
+            else row["hbm_bytes_per_s"],
+            "ici_bytes_per_s": ici * 1e9 if ici > 0
+            else row["ici_bytes_per_s"],
             "source": source}
 
 
@@ -220,8 +238,8 @@ class CostProfile:
     """Static cost of ONE compiled executable, extracted at compile
     time (or derived analytically): total FLOPs, total HBM bytes
     accessed (reads + writes as XLA's HLO cost analysis counts them),
-    and — when ``FLAGS_cost_memory_analysis`` armed the extra compile —
-    the executable's peak temp-buffer allocation.  When the profiling
+    and — when ``FLAGS_cost_memory_analysis`` is on — the executable's
+    peak temp-buffer allocation.  When the profiling
     plane (observability.profiling) is armed, ``hot_ops`` carries the
     top-K per-op FLOP/byte rows from the same traced computation — the
     table the vision/fusion work ranks candidates from."""
@@ -310,48 +328,42 @@ def _args_sharded(args) -> bool:
 
 
 def _extract_cost_analysis(fn, args) -> Optional[dict]:
-    """Lower the jitted callable against ``args`` and run XLA's HLO
-    cost analysis on the lowered module — tracing only, no compile, no
-    new executable (pinned: the jit's ``_cache_size`` is untouched).
-    None when the backend does not implement the analysis."""
+    """Lower the jitted callable against ``args`` and read XLA's HLO
+    cost analysis — no new jit-cache entry (pinned: ``_cache_size`` is
+    untouched).  The CPU backend analyses the lowering itself; the TPU
+    backend answers None there and analyses COMPILED programs only, so
+    on a chip the lowering is compiled here.  That compile is not a
+    second one: the jit call that follows gets the same lowering from
+    jit's in-memory cache and reuses its executable (backend compiles
+    counted equal with the observatory on and off).  A compile that
+    fails raises — the jit call would fail on the same program.  None
+    only when the backend gives no analysis in either form."""
     lowered = fn.lower(*args)
     ca = lowered.cost_analysis()
-    if isinstance(ca, (list, tuple)):  # older jax: one dict per module
-        ca = ca[0] if ca else None
-    if not isinstance(ca, dict):
-        return None
-    out = {"flops": float(ca.get("flops", 0.0)),
-           "bytes_accessed": float(ca.get("bytes accessed", 0.0))}
     from ..core import flags as _flags
 
     want_mem = bool(_flags.flag("cost_memory_analysis"))
     # Collective accounting needs the OPTIMIZED (post-SPMD-partitioner)
-    # HLO, which only exists after a real XLA compile of the lowered
-    # module (the same AOT twin memory_analysis uses).  Always-on for
-    # sharded executables — the interconnect term is first-class there,
-    # and single-chip engines never pay the extra compile.
+    # HLO, which only exists in the compiled program.  Always-on for
+    # sharded executables — the interconnect term is first-class there.
     want_coll = _args_sharded(args)
-    if want_mem or want_coll:
-        try:
-            compiled = lowered.compile()
-        except Exception:
-            compiled = None
-        if compiled is not None:
-            if want_mem:
-                try:
-                    ma = compiled.memory_analysis()
-                    out["temp_bytes"] = float(
-                        getattr(ma, "temp_size_in_bytes", 0.0))
-                except Exception:
-                    pass
-            if want_coll:
-                try:
-                    from ..parallel.partition import collective_bytes
+    compiled = None
+    if not isinstance(ca, dict) or want_mem or want_coll:
+        compiled = lowered.compile()
+    if not isinstance(ca, dict):
+        ca = compiled.cost_analysis()
+        if not isinstance(ca, dict):
+            return None
+    out = {"flops": float(ca.get("flops", 0.0)),
+           "bytes_accessed": float(ca.get("bytes accessed", 0.0))}
+    if want_mem:
+        out["temp_bytes"] = float(
+            compiled.memory_analysis().temp_size_in_bytes)
+    if want_coll:
+        from ..parallel.partition import collective_bytes
 
-                    out["collective_bytes"] = float(
-                        collective_bytes(compiled.as_text()))
-                except Exception:
-                    pass
+        out["collective_bytes"] = float(
+            collective_bytes(compiled.as_text()))
     return out
 
 
@@ -374,8 +386,9 @@ def note_executable(site: str, fn, args) -> Optional[tuple]:
     FIRST invocation (compile time — the call that follows pays the
     XLA compile) when the observatory is armed.  Extracts and stores
     the static profile under the call signature; returns the signature
-    key (the tracker memoizes it as ``cost_sig``).  Extraction failure
-    is never fatal — the engine falls back to the analytical formula."""
+    key (the tracker memoizes it as ``cost_sig``), or None where the
+    backend has no HLO cost analysis — the engine then predicts from
+    the analytical formula.  A failure to lower or compile raises."""
     key = profile_signature(site, args)
     with _lock:
         existing = _PROFILES.get(key)
@@ -389,10 +402,7 @@ def note_executable(site: str, fn, args) -> Optional[tuple]:
                 with _lock:
                     existing.hot_ops = hot
         return key
-    try:
-        ca = _extract_cost_analysis(fn, args)
-    except Exception:
-        ca = None
+    ca = _extract_cost_analysis(fn, args)
     if ca is None:
         return None  # backend without HLO cost analysis: analytical
     prof = CostProfile(site=site, flops=ca["flops"],
@@ -784,7 +794,7 @@ class CostModel:
         temporaries, anything this ledger forgot) — a growing residue
         is the drift alarm.  ``temp_scratch`` is the executables' peak
         XLA scratch from the profiles (populated when
-        ``FLAGS_cost_memory_analysis`` armed the extra compile);
+        ``FLAGS_cost_memory_analysis`` is on);
         scratch is XLA-owned, not a live array, so it reports beside
         the reconciliation, never inside it."""
         import jax

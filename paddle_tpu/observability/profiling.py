@@ -292,12 +292,7 @@ def hot_op_table(fn, args, top_k: int = HOT_OP_TOP_K) -> tuple:
     bytes, each carrying its fraction of the executable's totals, so
     the fusion/layout work reads 'where this program's work lives'
     straight off the table."""
-    try:
-        closed = fn.trace(*args).jaxpr
-    except AttributeError:  # older jax without AOT .trace
-        import jax
-
-        closed = jax.make_jaxpr(lambda *a: fn(*a))(*args)
+    closed = fn.trace(*args).jaxpr
     agg: Dict[str, list] = {}
     _walk_jaxpr(closed.jaxpr, agg)
     total_f = sum(r[0] for r in agg.values()) or 1.0

@@ -20,13 +20,8 @@ import os
 import jax
 import jax.numpy as jnp
 
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu  # noqa: F401
-
-    _HAS_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAS_PALLAS = False
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 # TPU float32 tiling wants the lane (last) dimension to be 128; the per-row
 # softmax statistics are stored broadcast across one lane tile.
@@ -212,11 +207,22 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_scr, l_scr, *,
         lse_ref[...] = jnp.broadcast_to(lse[:, None], (block_q, _LANES))
 
 
+def _out_struct(shape, dtype, *like):
+    """A kernel output's ShapeDtypeStruct, varying over the mesh axes its
+    inputs vary over: inside a `shard_map` with ``check_vma=True`` (the
+    hybrid train step, models/gpt_spmd.py) a `pallas_call` has to say so
+    itself.  Outside a shard_map the set is empty and changes nothing."""
+    vma = frozenset().union(*(jax.typeof(x).vma for x in like))
+    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
+
+
 def _pallas_forward(q, k, v, is_causal, scale, block_q, block_k):
     """Returns (out [B,H,Sq,D], lse [B*H, Sq] fp32)."""
     b, h, sq, d = q.shape
     sk = k.shape[-2]
     s = scale if scale is not None else 1.0 / math.sqrt(d)
+    out_shape = [_out_struct((b * h, sq, d), q.dtype, q, k, v),
+                 _out_struct((b * h, sq, _LANES), jnp.float32, q, k, v)]
 
     qr = q.reshape(b * h, sq, d)
     kr = k.reshape(b * h, sk, d)
@@ -238,10 +244,7 @@ def _pallas_forward(q, k, v, is_causal, scale, block_q, block_k):
                 pl.BlockSpec((None, block_q, _LANES),
                              lambda i, j: (i, j, 0)),
             ],
-            out_shape=[
-                jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
-                jax.ShapeDtypeStruct((b * h, sq, _LANES), jnp.float32),
-            ],
+            out_shape=out_shape,
         )(qr, kr, vr)
         return out.reshape(b, h, sq, d), lse[:, :, 0]
 
@@ -264,10 +267,7 @@ def _pallas_forward(q, k, v, is_causal, scale, block_q, block_k):
             pl.BlockSpec((None, block_q, _LANES),
                          lambda i, j, r: (i, j, 0)),
         ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
-            jax.ShapeDtypeStruct((b * h, sq, _LANES), jnp.float32),
-        ],
+        out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32),
                         pltpu.VMEM((block_q, _LANES), jnp.float32),
                         pltpu.VMEM((block_q, _LANES), jnp.float32)],
@@ -438,8 +438,8 @@ def _pallas_backward(q, k, v, out, lse, g, is_causal, scale, block_q,
             pl.BlockSpec((None, block_k, d), lambda i, j, r: (i, j, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b * h, sk, d), k.dtype),
-            jax.ShapeDtypeStruct((b * h, sk, d), v.dtype),
+            _out_struct((b * h, sk, d), k.dtype, q, k, v, g),
+            _out_struct((b * h, sk, d), v.dtype, q, k, v, g),
         ],
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
@@ -462,7 +462,7 @@ def _pallas_backward(q, k, v, out, lse, g, is_causal, scale, block_q,
         ],
         out_specs=pl.BlockSpec((None, block_q, d),
                                lambda i, j, r: (i, j, 0)),
-        out_shape=jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
+        out_shape=_out_struct((b * h, sq, d), q.dtype, q, k, v, g),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
     )(qr, kr, vr, dor, outr, lse_b)
 
@@ -519,7 +519,7 @@ def flash_attention_fwd(q, k, v, mask=None, is_causal=False, scale=None,
         picked = cached_blocks(q.shape[-2], k.shape[-2], q.shape[-1],
                                q.dtype, is_causal) or \
             pick_blocks(q.shape[-2], k.shape[-2])
-    if (not _HAS_PALLAS or mask is not None or picked is None
+    if (mask is not None or picked is None
             or (is_causal and q.shape[-2] != k.shape[-2])
             or jax.default_backend() != "tpu"):
         return _xla_reference(q, k, v, mask, is_causal, scale)
@@ -535,10 +535,7 @@ def flash_attention_fwd(q, k, v, mask=None, is_causal=False, scale=None,
 def _auto_threshold(is_causal: bool):
     from ...core import flags as _flags
 
-    try:
-        base = int(_flags.flag("pallas_attention_min_seq"))
-    except Exception:
-        base = 512
+    base = int(_flags.flag("pallas_attention_min_seq"))
     # the S=512 crossover was measured causal-only (the dead-block DMA
     # clamps do nothing for full attention); non-causal keeps the round-2
     # crossover of 1024
@@ -622,12 +619,9 @@ def pallas_attention_wanted(seq_len: int, is_causal: bool = True) -> bool:
     single-device kernel and the ring-attention blocks."""
     from ...core import flags as _flags
 
-    if not _HAS_PALLAS or jax.default_backend() != "tpu":
+    if jax.default_backend() != "tpu":
         return False
-    try:
-        pol = str(_flags.flag("use_pallas_attention"))
-    except Exception:
-        return False
+    pol = str(_flags.flag("use_pallas_attention"))
     if pol in ("1", "True", "true"):
         return True
     return pol == "auto" and seq_len >= _auto_threshold(is_causal)

@@ -19,12 +19,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-try:
-    from jax.experimental import pallas as pl
-
-    _HAS_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAS_PALLAS = False
+from jax.experimental import pallas as pl
 
 
 def _ln_kernel(x_ref, w_ref, b_ref, o_ref, *, eps):
@@ -75,8 +70,7 @@ def _use_pallas(d: int) -> bool:
 
     if not _flags.flag("use_pallas_layernorm"):
         return False
-    return (_HAS_PALLAS and jax.default_backend() == "tpu" and
-            d % 128 == 0)
+    return jax.default_backend() == "tpu" and d % 128 == 0
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))

@@ -40,9 +40,9 @@ Quantized KV pages (FLAGS_kv_quant=int8): pages may be stored as int8
 with per-page, per-head symmetric scales (``scale = absmax / 127``,
 the `quantization.int8` convention) in a parallel ``[Hkv, num_pages]``
 f32 array per pool.  Dequantization is FUSED into the K/V loads — the
-Pallas kernel scalar-prefetches the scale rows with the block tables
-and multiplies each streamed page tile by its page scale in-register
-after the DMA (the Tensix/TPP in-kernel-fusion framing: no separate
+Pallas kernel reads each streamed page's scale from a per-(sequence,
+head) SMEM row gathered through the block table before the call, and
+multiplies the page tile by it in-register after the DMA (the Tensix/TPP in-kernel-fusion framing: no separate
 dequant materialization pass ever exists), and the XLA reference
 dequantizes the gathered pages before the identical attention math so
 the two backends stay bit-identical to each other.  The write side is
@@ -61,13 +61,8 @@ import jax.numpy as jnp
 
 from . import flash_attention as fa
 
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAS_PALLAS = False
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 _LANES = 128
 # minimum query-group rows per compute tile: pad the GQA group dim up to
@@ -400,24 +395,27 @@ def _decode_kernel(bt_ref, sl_ref, qo_ref, q_ref, k_ref, v_ref, o_ref,
                       ).astype(o_ref.dtype)
 
 
-def _decode_kernel_q(bt_ref, sl_ref, qo_ref, ks_ref, vs_ref, q_ref,
-                     k_ref, v_ref, o_ref, acc, m_scr, l_scr, *, page,
-                     pages_max, scale, group, q_len):
+def _decode_kernel_q(bt_ref, sl_ref, qo_ref, q_ref, k_ref, v_ref, ks_ref,
+                     vs_ref, o_ref, acc, m_scr, l_scr, *, page, pages_max,
+                     scale, group, q_len):
     # Quantized twin of `_decode_kernel`: the K/V page tiles stream in
-    # as int8 and dequantize IN-REGISTER right after the DMA — the
-    # scale rows ride the scalar-prefetch channel with the block
-    # tables, so the per-page scale lookup is an SMEM read, never a
-    # second HBM stream.  Everything after the dequant multiply is the
-    # unquantized kernel verbatim (the f32 online-softmax state and
-    # masking are identical), which is what keeps the two paths'
-    # numerics aligned with the XLA reference's dequant-then-attend.
+    # as int8 and dequantize IN-REGISTER right after the DMA.  The
+    # scales arrive already gathered through the block table — one
+    # [pages_max] f32 row per (sequence, head), blocked into SMEM with
+    # the query tile — so the per-page lookup is an SMEM read at the
+    # streamed TABLE ENTRY and SMEM use is bounded by pages_max, not by
+    # the pool (pool-sized [Hkv, num_pages] tables on the scalar-
+    # prefetch channel overflowed the 1 MB of SMEM at 8192 pages x 12
+    # heads).  Everything after the dequant multiply is the unquantized
+    # kernel verbatim (the f32 online-softmax state and masking are
+    # identical), which is what keeps the two paths' numerics aligned
+    # with the XLA reference's dequant-then-attend.
     b = pl.program_id(0)
-    h = pl.program_id(1)
     p = pl.program_id(2)
     sl = sl_ref[b]
     qo = qo_ref[b]
     live = jnp.maximum((sl + page - 1) // page, 1)
-    pid = bt_ref[b, jnp.minimum(p, live - 1)]  # the streamed page's id
+    ent = jnp.minimum(p, live - 1)  # the streamed page's table entry
 
     @pl.when(p == 0)
     def _init():
@@ -433,9 +431,9 @@ def _decode_kernel_q(bt_ref, sl_ref, qo_ref, ks_ref, vs_ref, q_ref,
         # sub-f32 models (bf16) the cast is lossy, and skipping it here
         # would make the two backends attend over different K/V values
         # (a no-op for f32, where the tests pin bit-identical operands)
-        k = (k_ref[...].astype(jnp.float32) * ks_ref[h, pid]
+        k = (k_ref[...].astype(jnp.float32) * ks_ref[0, ent]
              ).astype(q_ref.dtype).astype(jnp.float32)
-        v = (v_ref[...].astype(jnp.float32) * vs_ref[h, pid]
+        v = (v_ref[...].astype(jnp.float32) * vs_ref[0, ent]
              ).astype(q_ref.dtype).astype(jnp.float32)
         rows = q_ref.shape[0]
         m = m_scr[...][:, 0]
@@ -499,7 +497,6 @@ def _pallas_paged_attention(q, k_pages, v_pages, block_tables, seq_lens,
     seq_lens = seq_lens.astype(jnp.int32)
     q_offsets = q_offsets.astype(jnp.int32)
     quant = k_scales is not None
-    n_prefetch = 5 if quant else 3
 
     def q_map(bi, h, p, *pref):
         return (bi, h, 0, 0)
@@ -511,14 +508,32 @@ def _pallas_paged_attention(q, k_pages, v_pages, block_tables, seq_lens,
         live = jnp.maximum((sl[bi] + page - 1) // page, 1)
         return (h, bt[bi, jnp.minimum(p, live - 1)], 0, 0)
 
+    in_specs = [
+        pl.BlockSpec((None, None, gp, d), q_map),
+        pl.BlockSpec((None, None, page, d), kv_map),
+        pl.BlockSpec((None, None, page, d), kv_map),
+    ]
+    operands = (block_tables, seq_lens, q_offsets, qg, k_pages, v_pages)
+    if quant:
+        # gather the page scales through the block table BEFORE the
+        # call: [B, Hkv, 1, pages_max] f32, entry j of row (b, h) being
+        # the scale of sequence b's j-th page — the same indirection
+        # the DMA maps use, done once in XLA.  Each (b, h) row rides
+        # into SMEM as a block beside the query tile, on the query's
+        # index map (the unit dim makes the block's last two dims the
+        # array's own, which the TPU lowering asks of a block that is
+        # no multiple of 8x128).
+        scale_spec = pl.BlockSpec((None, None, 1, pages_max), q_map,
+                                  memory_space=pltpu.SMEM)
+        in_specs += [scale_spec, scale_spec]
+        operands += tuple(
+            sc.astype(jnp.float32)[:, block_tables]
+            .transpose(1, 0, 2)[:, :, None, :]
+            for sc in (k_scales, v_scales))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=n_prefetch,
+        num_scalar_prefetch=3,
         grid=(b, hkv, pages_max),
-        in_specs=[
-            pl.BlockSpec((None, None, gp, d), q_map),
-            pl.BlockSpec((None, None, page, d), kv_map),
-            pl.BlockSpec((None, None, page, d), kv_map),
-        ],
+        in_specs=in_specs,
         out_specs=pl.BlockSpec((None, None, gp, d), q_map),
         scratch_shapes=[
             pltpu.VMEM((gp, d), jnp.float32),
@@ -527,19 +542,12 @@ def _pallas_paged_attention(q, k_pages, v_pages, block_tables, seq_lens,
         ],
     )
     kernel = _decode_kernel_q if quant else _decode_kernel
-    operands = (block_tables, seq_lens, q_offsets)
-    if quant:
-        # the scale rows ride the scalar-prefetch channel (SMEM) with
-        # the block tables: the kernel's per-page dequant lookup is
-        # ks[h, bt[b, p]], the same indirection the DMA maps use
-        operands = operands + (k_scales.astype(jnp.float32),
-                               v_scales.astype(jnp.float32))
     out = pl.pallas_call(
         functools.partial(kernel, page=page, pages_max=pages_max,
                           scale=s, group=g, q_len=qn),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, gp, d), q.dtype),
-    )(*operands, qg, k_pages, v_pages)
+    )(*operands)
     out = out[:, :, :rows, :].reshape(b, hkv, qn, g, d)
     out = out.transpose(0, 2, 1, 3, 4).reshape(b, qn, hq, d)
     return out[:, 0] if squeeze else out
@@ -614,10 +622,7 @@ def _paged_kernel_wanted() -> bool:
     # '0' still forces the reference for debugging
     from ...core import flags as _flags
 
-    if not _HAS_PALLAS or jax.default_backend() != "tpu":
+    if jax.default_backend() != "tpu":
         return False
-    try:
-        pol = str(_flags.flag("use_pallas_attention"))
-    except Exception:
-        return False
+    pol = str(_flags.flag("use_pallas_attention"))
     return pol in ("1", "True", "true", "auto")

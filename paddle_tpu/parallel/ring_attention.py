@@ -56,8 +56,15 @@ def _flash_or_skip(q, k, v, scale, causal, rank, src):
     b, h, sq = q.shape[0], q.shape[1], q.shape[2]
 
     def masked():
-        return (jnp.zeros(q.shape, jnp.float32),
-                jnp.full((b, h, sq), -jnp.inf, jnp.float32),
+        # constants do not vary over the mesh, the flash branches' out
+        # and lse do (as q does), and under the hybrid step's
+        # check_vma=True `cond` wants one type from all branches; the
+        # row sum is a constant in `_flash_block` too
+        vary = tuple(jax.typeof(q).vma)
+        return (lax.pcast(jnp.zeros(q.shape, jnp.float32), vary,
+                          to="varying"),
+                lax.pcast(jnp.full((b, h, sq), -jnp.inf, jnp.float32),
+                          vary, to="varying"),
                 jnp.zeros((b, h, sq), jnp.float32))
 
     return lax.cond(

@@ -126,6 +126,93 @@ class TestProfiles:
         finally:
             paddle.set_flags({"peak_flops": 0.0, "peak_hbm_gbps": 0.0})
 
+    def test_profile_comes_from_the_compiled_twin_where_lowering_has_none(
+            self):
+        """The TPU backend's `Lowered.cost_analysis()` is None (seen on
+        the chip in PR 21: every profile was the analytical fallback);
+        its `Compiled.cost_analysis()` is a dict."""
+        class Compiled:
+            def cost_analysis(self):
+                return {"flops": 12.0, "bytes accessed": 34.0}
+
+        class Lowered:
+            compiles = 0
+
+            def cost_analysis(self):
+                return None
+
+            def compile(self):
+                Lowered.compiles += 1
+                return Compiled()
+
+        class Fn:
+            def lower(self, *args):
+                return Lowered()
+
+        assert costmodel._extract_cost_analysis(Fn(), ()) == {
+            "flops": 12.0, "bytes_accessed": 34.0}
+        assert Lowered.compiles == 1
+
+    def test_compiled_profile_is_the_steps_one_compile(self, model):
+        """Where the profile is read from the COMPILED program (every
+        step on a TPU; forced here by FLAGS_cost_memory_analysis), the
+        jit call that follows reuses that executable: a serve asks the
+        backend for exactly as many compiles as with the observatory
+        disarmed."""
+        import jax.monitoring
+
+        asked = [0]
+
+        def on_duration(event, duration, **kw):
+            if event == "/jax/core/compile/backend_compile_duration":
+                asked[0] += 1
+
+        def serve():
+            eng = _engine(model)
+            before = asked[0]
+            eng.generate(_prompts(2), max_new_tokens=4)
+            return eng, asked[0] - before
+
+        forced = costmodel._forced_engines
+        costmodel._forced_engines = 0
+        jax.monitoring.register_event_duration_secs_listener(on_duration)
+        try:
+            paddle.set_flags({"cost_model": False})
+            serve()  # the eager helpers compile once per process
+            eng, disarmed = serve()
+            assert eng._decode_fn.cost_sig is None
+            paddle.set_flags({"cost_model": True,
+                              "cost_memory_analysis": True})
+            costmodel.clear_profiles()
+            eng, armed = serve()
+        finally:
+            paddle.set_flags({"cost_model": True,
+                              "cost_memory_analysis": False})
+            costmodel._forced_engines = forced
+            jax.monitoring.unregister_event_duration_listener(on_duration)
+        for tracker in (eng._decode_fn, eng._mixed_fn):
+            # the compiled path was taken
+            assert costmodel.profile_by_key(tracker.cost_sig).temp_bytes > 0
+        assert disarmed == 2  # the decode and the mixed executable
+        assert armed == disarmed
+
+    def test_one_peak_table_keyed_by_device_kind(self):
+        import types
+
+        v5e = types.SimpleNamespace(platform="tpu",
+                                    device_kind="TPU v5 lite")
+        row = costmodel.device_peaks(v5e)
+        assert row is costmodel.DEVICE_PEAKS["TPU v5 lite"]
+        assert row["flops_bf16"] == 197e12
+        assert row["hbm_bytes_per_s"] == 819e9
+        # a TPU that is not in the table is an error, never the CPU pin
+        other = types.SimpleNamespace(platform="tpu",
+                                      device_kind="TPU v9 imaginary")
+        with pytest.raises(ValueError, match="TPU v9 imaginary"):
+            costmodel.device_peaks(other)
+        cpu = types.SimpleNamespace(platform="cpu", device_kind="cpu")
+        assert costmodel.device_peaks(cpu) is costmodel.DEVICE_PEAKS["cpu"]
+
 
 # ---------------------------------------------------------------------------
 # calibrated prediction

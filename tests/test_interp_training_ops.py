@@ -187,14 +187,14 @@ class TestReviewRegressionsR4:
         """exp(psum(log)) would NaN on negatives; the sign/zero-safe
         reduction must not."""
         from jax.sharding import Mesh, PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from paddle_tpu.static.op_bridge import _psum_prod
 
         mesh = Mesh(np.array(jax.devices()[:2]), ("dp",))
         x = np.array([[-2.0, 0.0, 4.0], [3.0, 5.0, -1.0]], np.float32)
         f = shard_map(lambda v: _psum_prod(v, "dp"), mesh=mesh,
                       in_specs=P("dp"), out_specs=P("dp"),
-                      check_rep=False)
+                      check_vma=False)
         out = np.asarray(f(jnp.asarray(x)))
         np.testing.assert_allclose(out[0], [-6.0, 0.0, -4.0], rtol=1e-4)
 
@@ -424,7 +424,7 @@ class TestCollectiveOps:
     def _run_on_mesh(self, optype, x, attrs, n=2, extra_ins=None,
                      outs=("Out",), out_name="Out"):
         from jax.sharding import Mesh, PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         devs = np.array(jax.devices()[:n])
         mesh = Mesh(devs, ("dp",))
@@ -447,7 +447,7 @@ class TestCollectiveOps:
             return scope[out_name.lower()]
 
         f = shard_map(per_device, mesh=mesh, in_specs=P("dp"),
-                      out_specs=P("dp"), check_rep=False)
+                      out_specs=P("dp"), check_vma=False)
         return np.asarray(f(jnp.asarray(x)))
 
     def test_c_allreduce_sum(self):
@@ -489,7 +489,7 @@ class TestCollectiveOps:
         all-reduced via c_allreduce_sum + averaged, sgd step — dp=2 on
         the CPU mesh must match the fused single-process batch."""
         from jax.sharding import Mesh, PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         prog = static.Program()
         b = prog.global_block()
@@ -536,7 +536,7 @@ class TestCollectiveOps:
         stepped = shard_map(
             one_step, mesh=mesh,
             in_specs=(P("dp"), P("dp"), P()), out_specs=P(),
-            check_rep=False)
+            check_vma=False)
         w_dp = np.asarray(stepped(jnp.asarray(xv), jnp.asarray(yv),
                                   jnp.asarray(w0)))
 
@@ -554,7 +554,7 @@ class TestCollectiveOps:
         AFTER the single fused c_allreduce_sum, so wrong aliasing gives
         a numerically wrong step, not just a load failure."""
         from jax.sharding import Mesh, PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         startup = static.Program()
         sb = startup.global_block()
@@ -629,7 +629,7 @@ class TestCollectiveOps:
         stepped = shard_map(
             one_step, mesh=mesh,
             in_specs=(P("dp"), P("dp"), P(), P()),
-            out_specs=(P(), P()), check_rep=False)
+            out_specs=(P(), P()), check_vma=False)
         w1_dp, w2_dp = stepped(jnp.asarray(xv), jnp.asarray(yv),
                                jnp.asarray(w0["w1"]),
                                jnp.asarray(w0["w2"]))

@@ -148,7 +148,7 @@ class TestExpertParallel:
                                    jnp.asarray(w2), jnp.asarray(b2),
                                    k=2, capacity_factor=8.0)
 
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         def per_device(xv, gw, w1v, b1v, w2v, b2v):
             # tokens replicated over ep; experts sharded
@@ -159,7 +159,7 @@ class TestExpertParallel:
         fn = shard_map(
             per_device, mesh=mesh,
             in_specs=(P(), P(), P("ep"), P("ep"), P("ep"), P("ep")),
-            out_specs=(P(), P()), check_rep=False)
+            out_specs=(P(), P()), check_vma=False)
         got, aux = jax.jit(fn)(x, gate_w, w1, b1, w2, b2)
         np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                    rtol=2e-4, atol=2e-5)
@@ -176,7 +176,7 @@ class TestExpertParallel:
                 rng.randn(e, f, h).astype(np.float32) * 0.1,
                 np.zeros((e, h), np.float32))
 
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         def loss_fn(x, gw, w1, b1, w2, b2):
             def per_device(xv, gwv, w1v, b1v, w2v, b2v):
@@ -187,7 +187,7 @@ class TestExpertParallel:
             out, aux = shard_map(
                 per_device, mesh=mesh,
                 in_specs=(P(), P(), P("ep"), P("ep"), P("ep"), P("ep")),
-                out_specs=(P(), P()), check_rep=False)(x, gw, w1, b1, w2, b2)
+                out_specs=(P(), P()), check_vma=False)(x, gw, w1, b1, w2, b2)
             return (out ** 2).mean() + 0.01 * aux.mean()
 
         grads = jax.jit(jax.grad(loss_fn, argnums=(1, 2)))(*args)

@@ -11,8 +11,7 @@ import pytest
 
 from paddle_tpu.core import native
 
-pytestmark = pytest.mark.skipif(not native.native_available(),
-                                reason="native runtime not built")
+pytestmark = pytest.mark.usefixtures("native_runtime")
 
 
 class TestArena:
@@ -165,3 +164,74 @@ class TestFlagsStatsTracer:
         import json
         events = json.loads(j)["traceEvents"]
         assert any(e["name"] == "op:matmul" for e in events)
+
+
+class TestBuildOnce:
+    """`native._build` with the tools faked: what matters is that many
+    callers at once make one build, and that a failure is kept, shown
+    and not latched."""
+
+    @pytest.fixture
+    def fake_checkout(self, tmp_path, monkeypatch):
+        (tmp_path / "csrc").mkdir()
+        monkeypatch.setattr(native, "_REPO_ROOT", str(tmp_path))
+        monkeypatch.setattr(native, "_BUILD_DIR", str(tmp_path / "build"))
+        monkeypatch.setattr(native, "_LIB_CANDIDATES",
+                            (str(tmp_path / "build" / native._LIB_NAME),))
+        monkeypatch.setattr(native, "_build_error", None)
+        return tmp_path
+
+    def test_concurrent_callers_build_once(self, fake_checkout,
+                                           monkeypatch):
+        import os
+        import time
+
+        ninja_runs = []
+
+        def fake_run(cmd, timeout):
+            if cmd[0] == "ninja":
+                ninja_runs.append(cmd)
+                time.sleep(0.05)  # hold the lock while others queue up
+                with open(os.path.join(cmd[2], native._LIB_NAME), "w"):
+                    pass
+            else:
+                os.makedirs(cmd[cmd.index("-B") + 1])
+
+        monkeypatch.setattr(native, "_run", fake_run)
+        got, errs = [], []
+
+        def worker():
+            try:
+                got.append(native._build())
+            except Exception as e:  # noqa: BLE001 - reported below
+                errs.append(e)
+
+        threads = [threading.Thread(target=worker) for _ in range(8)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=30)
+        assert not any(t.is_alive() for t in threads)
+        assert not errs, errs
+        final = str(fake_checkout / "build" / native._LIB_NAME)
+        assert got == [final] * 8
+        assert len(ninja_runs) == 1
+        assert sorted(os.listdir(fake_checkout)) == [
+            ".native_build.lock", "build", "csrc"]
+
+    def test_failure_is_kept_shown_and_not_latched(self, fake_checkout,
+                                                   monkeypatch):
+        def failing_run(cmd, timeout):
+            raise RuntimeError("cmake exited 1:\nno compiler")
+
+        monkeypatch.setattr(native, "_run", failing_run)
+        with pytest.warns(RuntimeWarning, match="no compiler"):
+            assert native._find_or_build() is None
+        assert "no compiler" in native.build_error()
+        # nothing of the failed attempt is left behind ...
+        assert not (fake_checkout / "build").exists()
+        # ... and a library that another process finishes later is found
+        (fake_checkout / "build").mkdir()
+        lib = fake_checkout / "build" / native._LIB_NAME
+        lib.write_bytes(b"")
+        assert native._find_or_build() == str(lib)

@@ -403,8 +403,7 @@ class TestMergedChromeTrace:
         pre = next(e for e in evs if e["name"] == "prefill")
         assert pre["pid"] == meta["requests"] and pre["tid"] == 7
 
-    @pytest.mark.skipif(not native.native_available(),
-                        reason="native runtime unavailable")
+    @pytest.mark.usefixtures("native_runtime")
     def test_host_events_merge_on_host_track(self):
         profiler.start_profiler()
         with profiler.RecordEvent("host_evt"):

@@ -81,7 +81,6 @@ class TestFlashAttention:
             raise AssertionError("Pallas path taken for cross-length causal")
 
         monkeypatch.setattr(FA, "_flash_diff", boom)
-        monkeypatch.setattr(FA, "_HAS_PALLAS", True)
         monkeypatch.setattr(FA.jax, "default_backend", lambda: "tpu")
         monkeypatch.setattr(FA, "pallas_attention_wanted",
                             lambda s, c=True: True)

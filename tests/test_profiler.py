@@ -10,8 +10,7 @@ from paddle_tpu import profiler
 from paddle_tpu.core import native
 
 
-needs_native = pytest.mark.skipif(not native.native_available(),
-                                  reason="native runtime unavailable")
+needs_native = pytest.mark.usefixtures("native_runtime")
 
 
 @needs_native

@@ -12,13 +12,9 @@ import time
 import numpy as np
 import pytest
 
-from paddle_tpu.core import native
+from paddle_tpu.distributed.ps import Communicator, PSClient, PSServer
 
-pytestmark = pytest.mark.skipif(not native.native_available(),
-                                reason="native runtime unavailable")
-
-
-from paddle_tpu.distributed.ps import Communicator, PSClient, PSServer  # noqa: E402
+pytestmark = pytest.mark.usefixtures("native_runtime")
 
 
 @pytest.fixture

@@ -48,3 +48,30 @@ def test_flash_ring_matches_composed(flash_ring_interpret, causal):
     for got, want in zip(vjp(g), vjp_ref(g)):
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    atol=2e-2)
+
+
+def test_flash_blocks_trace_inside_the_hybrid_step(monkeypatch):
+    """`gpt_spmd` is a shard_map with ``check_vma=True``; with the flash
+    blocks on (as on a TPU) the kernel's outputs must say over which mesh
+    axes they vary and every branch of the ring's `cond` must agree — both
+    failed at trace time the first time the step met the kernel (PR 21).
+    Traced only (`eval_shape`): Pallas' interpret mode does not track
+    varying axes, and the TPU compile of this step is in
+    tests/test_tpu_compile.py.  dp=2 x sp=2 x mp=2, so the ring has a
+    masked block too."""
+    from paddle_tpu.distributed.topology import build_mesh
+    from paddle_tpu.models import gpt_spmd
+    from paddle_tpu.models.gpt import GPTConfig
+
+    monkeypatch.setattr(RA, "_use_flash_blocks", lambda q, s: True)
+    cfg = GPTConfig(vocab_size=64, hidden_size=128, num_layers=1,
+                    num_heads=2, max_seq_len=1024)
+    step = gpt_spmd.build_spmd_train_step(
+        cfg, build_mesh(dp=2, pp=1, sp=2, mp=2))
+    params = jax.eval_shape(
+        lambda: gpt_spmd.init_params(cfg, jax.random.PRNGKey(0)))
+    tokens = jax.ShapeDtypeStruct((2, 1024), jnp.int32)
+    loss, new_params = jax.eval_shape(step, params, tokens, tokens)
+    assert loss.shape == () and loss.dtype == jnp.float32
+    assert {k: v.shape for k, v in new_params.items()} == \
+        {k: v.shape for k, v in params.items()}

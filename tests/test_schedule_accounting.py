@@ -73,7 +73,7 @@ class TestRingAttentionAccounting:
         count."""
         from paddle_tpu.parallel.ring_attention import \
             ring_attention_local
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         n = 8
         mesh = Mesh(np.array(jax.devices()[:n]), ("sp",))
@@ -84,7 +84,7 @@ class TestRingAttentionAccounting:
                 lambda a, b, c: jnp.reshape(
                     ring_attention_local(a, b, c, "sp").sum(), (1,)),
                 mesh=mesh, in_specs=(P(None, None, "sp"),) * 3,
-                out_specs=P("sp"), check_rep=False)
+                out_specs=P("sp"), check_vma=False)
             return per(qq, kk, vv).sum()
 
         hlo_f = jax.jit(global_loss).lower(q, q, q).compile().as_text()
@@ -100,7 +100,7 @@ class TestRingAttentionAccounting:
         (the pre-round-4 schedule) produces MORE calls than the pinned
         count — proving the counter counts what it claims."""
         from jax import lax
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         n = 4
         mesh = Mesh(np.array(jax.devices()[:n]), ("sp",))
@@ -121,7 +121,7 @@ class TestRingAttentionAccounting:
             return cur
 
         shard_map(body, mesh=mesh, in_specs=P("sp"), out_specs=P("sp"),
-                  check_rep=False)(x)
+                  check_vma=False)(x)
         assert len(calls) == n  # > n - 1: the exact-count assert trips
 
 

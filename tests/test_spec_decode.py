@@ -308,14 +308,29 @@ class TestGreedyParity:
         assert st["verify_time_s"] > 0 and st["draft_time_s"] > 0
 
     def test_eos_inside_verify_window_truncates(self):
-        # fixture chosen so the greedy chain emits a NEW token mid-
-        # stream ([56, 56, 41, ...]): eos=41 first lands inside a
-        # verify window and the accepted tail after it must be dropped
+        # the fixture is searched for, not assumed: a prompt whose
+        # greedy chain emits a NEW token mid-stream (index >= 2, e.g.
+        # [42, 42, 42, 59, ...]), so that eos first lands inside a
+        # verify window, after accepted repeats, and the accepted tail
+        # after it must be dropped.  Tiny random models mostly repeat
+        # one token, and which prompts do not changes with the JAX
+        # version — hence the search and the assertion on its result.
         m = _tiny_gpt(seed=8)
-        rng = np.random.RandomState(3)
-        p = rng.randint(0, 64, (5,)).astype(np.int32)
-        ref = _engine(m).generate([p], max_new_tokens=8)[0]
-        j = next(i for i in range(1, 8) if ref[i] not in ref[:i])
+        plain = _engine(m)
+        found = None
+        for prompt_seed in range(16):
+            p = np.random.RandomState(prompt_seed).randint(
+                0, 64, (5,)).astype(np.int32)
+            ref = plain.generate([p], max_new_tokens=8)[0]
+            j = next((i for i in range(2, 8) if ref[i] not in ref[:i]),
+                     None)
+            if j is not None:
+                found = (p, ref, j)
+                break
+        assert found is not None, (
+            "no prompt seed in range(16) gives a greedy chain with a new "
+            "token at index >= 2 under this JAX: widen the search")
+        p, ref, j = found
         eos, want = ref[j], ref[:j + 1]
         eng = _engine(m, spec_decode_k=4, eos_token_id=int(eos))
         toks, reasons = eng.generate([p], max_new_tokens=8,

@@ -36,10 +36,21 @@ Usage:
 ``--smoke`` (or env BENCH_SMOKE=1) shrinks to 2 replicas and tiny
 shapes so CI can assert the script end-to-end (tests/test_tooling.py).
 The ``--child`` mode is internal (replicas re-exec this script).
+
+Processes and the chip: this harness kills and restarts whole processes,
+so it is a CPU program on both sides.  The parent (which computes the
+greedy oracle with JAX) and every child pin ``JAX_PLATFORMS=cpu`` before
+JAX is imported; neither side ever holds a chip, and on a machine that
+has one the chip stays free.  Replicas ON chips are one process driving
+one device each (a chip belongs to one process at a time), which is not
+what this tool measures.
 """
 import argparse
 import json
 import os
+
+os.environ["JAX_PLATFORMS"] = "cpu"  # parent and children alike: see above
+
 import signal
 import subprocess
 import sys
@@ -81,11 +92,14 @@ def _engine(model, args, **kw):
 # child: one replica process (edge + ops plane + journal)
 # ---------------------------------------------------------------------------
 def _child_replica(args):
+    from paddle_tpu.core.compile_cache import enable_compile_cache
     from paddle_tpu.fleet import EdgeServer
     from paddle_tpu.observability import opsserver
 
-    paddle.set_flags({"journal_fsync": "always",
-                      "compile_cache_dir": args.compile_cache or ""})
+    paddle.set_flags({"journal_fsync": "always"})
+    # tiny models, identical configs: the replicas share the one
+    # persistent compile cache, so replicas 2..n skip the XLA compile
+    enable_compile_cache()
     model = _build_model(args)
     jdir = os.path.join(args.dir, args.name)
     eng = _engine(model, args, journal_dir=jdir)
@@ -114,16 +128,13 @@ class _Replica:
 def _spawn_fleet(args, tmp, n):
     """Start ``n`` replica children; returns them once every edge has
     printed its ports."""
-    env = {**os.environ, "JAX_PLATFORMS": "cpu"}
-    # tiny models, identical configs: share one persistent compile
-    # cache so replicas 2..n skip the XLA compile entirely
+    env = dict(os.environ)  # carries JAX_PLATFORMS=cpu (module top)
     flags = env.get("XLA_FLAGS", "")
     if "xla_backend_optimization_level" not in flags:
         env["XLA_FLAGS"] = (
             flags + " --xla_backend_optimization_level=0").strip()
     base = [sys.executable, os.path.abspath(__file__),
-            "--child", "replica", "--dir", tmp,
-            "--compile-cache", os.path.join(tmp, "xla_cache")]
+            "--child", "replica", "--dir", tmp]
     for k in ("slots", "prompt", "new", "chunk", "page_size",
               "layers", "hidden", "heads", "vocab"):
         base += [f"--{k.replace('_', '-')}", str(getattr(args, k))]
@@ -358,7 +369,6 @@ def main():
     ap.add_argument("--child", choices=("replica",))
     ap.add_argument("--name", default="r0")
     ap.add_argument("--dir", default=None)
-    ap.add_argument("--compile-cache", default=None)
     ap.add_argument("--replicas", type=int, default=3)
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--prompt", type=int, default=32)

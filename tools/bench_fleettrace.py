@@ -34,6 +34,11 @@ Usage:
 ``--smoke`` (or env BENCH_SMOKE=1) shrinks to 2 replicas and tiny
 shapes so CI can assert the script end-to-end (tests/test_tooling.py).
 The ``--child`` mode is internal (replicas re-exec this script).
+
+Processes and the chip: as tools/bench_fleet.py says of itself — a CPU
+program on both sides.  Its module top, imported below before anything
+imports JAX, pins this parent and (through the inherited environment)
+every child.
 """
 import argparse
 import json
@@ -49,23 +54,25 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
+import bench_fleet as bf  # noqa: E402  (FIRST: its top pins JAX to the
+#                         # CPU.  Shared harness: model/engine/router
+#                         # builders, fleet teardown, percentile)
 import numpy as np  # noqa: E402
 
 import paddle_tpu as paddle  # noqa: E402
-import bench_fleet as bf  # noqa: E402  (shared harness: model/engine/
-#                         # router builders, fleet teardown, percentile)
 
 
 # ---------------------------------------------------------------------------
 # child: one replica process (edge + ops plane + journal + trace flag)
 # ---------------------------------------------------------------------------
 def _child_replica(args):
+    from paddle_tpu.core.compile_cache import enable_compile_cache
     from paddle_tpu.fleet import EdgeServer
     from paddle_tpu.observability import opsserver
 
     paddle.set_flags({"journal_fsync": "always",
-                      "compile_cache_dir": args.compile_cache or "",
                       "fleet_trace": bool(args.fleet_trace)})
+    enable_compile_cache()
     model = bf._build_model(args)
     jdir = os.path.join(args.dir, args.name)
     eng = bf._engine(model, args, journal_dir=jdir)
@@ -81,15 +88,14 @@ def _child_replica(args):
 def _spawn_fleet(args, tmp, n, fleet_trace):
     """bench_fleet's spawner, re-execing THIS script so the children
     carry the fleet_trace flag."""
-    env = {**os.environ, "JAX_PLATFORMS": "cpu"}
+    env = dict(os.environ)  # carries JAX_PLATFORMS=cpu (bench_fleet's top)
     flags = env.get("XLA_FLAGS", "")
     if "xla_backend_optimization_level" not in flags:
         env["XLA_FLAGS"] = (
             flags + " --xla_backend_optimization_level=0").strip()
     base = [sys.executable, os.path.abspath(__file__),
             "--child", "replica", "--dir", tmp,
-            "--fleet-trace", str(int(fleet_trace)),
-            "--compile-cache", os.path.join(tmp, "xla_cache")]
+            "--fleet-trace", str(int(fleet_trace))]
     for k in ("slots", "prompt", "new", "chunk", "page_size",
               "layers", "hidden", "heads", "vocab"):
         base += [f"--{k.replace('_', '-')}", str(getattr(args, k))]
@@ -280,7 +286,6 @@ def main():
     ap.add_argument("--child", choices=("replica",))
     ap.add_argument("--name", default="r0")
     ap.add_argument("--dir", default=None)
-    ap.add_argument("--compile-cache", default=None)
     ap.add_argument("--fleet-trace", type=int, default=0,
                     help="(child) serve with FLAGS_fleet_trace on")
     ap.add_argument("--replicas", type=int, default=3)

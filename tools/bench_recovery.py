@@ -23,9 +23,9 @@ Two legs, both asserted (the durable-serving acceptance bar):
   tokens** (the two lives' streamed tokens concatenate to EXACTLY the
   uninterrupted reference — the journal watermark gates ``_emit``),
   and **bit-identical greedy outputs** vs the uninterrupted run.
-  JAX's persistent compilation cache (``FLAGS_compile_cache_dir``)
-  warms the restore's executables when available; its effect is
-  reported, not asserted.
+  JAX's persistent compilation cache (`core.compile_cache`) warms the
+  restore's executables when available; its effect is reported, not
+  asserted.
 
 Emits BENCH_recovery.json.
 
@@ -35,10 +35,18 @@ Usage:
 ``--smoke`` (or env BENCH_SMOKE=1) shrinks shapes so CI can assert the
 script end-to-end (tests/test_tooling.py).  The ``--child`` modes are
 internal (the cross-process leg re-execs this script).
+
+Processes and the chip: a CPU program on both sides, pinned like
+tools/bench_fleet.py (see the statement there).  On a chip the serve child
+and the restore child would have to own it one after the other, with the
+parent off JAX.
 """
 import argparse
 import json
 import os
+
+os.environ["JAX_PLATFORMS"] = "cpu"  # before JAX; children inherit it
+
 import signal
 import subprocess
 import sys
@@ -50,6 +58,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 import numpy as np  # noqa: E402
 
 import paddle_tpu as paddle  # noqa: E402
+from paddle_tpu.core.compile_cache import enable_compile_cache  # noqa: E402
 from paddle_tpu.models.gpt import GPT, GPTConfig  # noqa: E402
 
 
@@ -162,8 +171,8 @@ def _child_serve(args):
     boundary — no cleanup runs, the journal and snapshot on disk are
     all that survives."""
     paddle.set_flags({"journal_fsync": "always",
-                      "snapshot_interval_steps": args.snap_every,
-                      "compile_cache_dir": args.compile_cache or ""})
+                      "snapshot_interval_steps": args.snap_every})
+    enable_compile_cache()
     model = _build_model(args)
     eng = _engine(model, args, journal_dir=args.dir)
     stream = os.path.join(args.dir, "stream.log")
@@ -180,8 +189,8 @@ def _child_restore(args):
     report what happened."""
     from paddle_tpu.inference import durability
 
-    paddle.set_flags({"journal_fsync": "always",
-                      "compile_cache_dir": args.compile_cache or ""})
+    paddle.set_flags({"journal_fsync": "always"})
+    enable_compile_cache()
     model = _build_model(args)
     t0 = time.perf_counter()
     eng, rmap = durability.restore_from_dir(args.dir, model)
@@ -207,10 +216,8 @@ def _child_restore(args):
 
 
 def _cross_process_leg(args, reference, tmp):
-    child_env = {**os.environ, "JAX_PLATFORMS": "cpu"}
-    base = [sys.executable, os.path.abspath(__file__),
-            "--dir", tmp, "--compile-cache",
-            os.path.join(tmp, "xla_cache")]
+    child_env = dict(os.environ)  # carries JAX_PLATFORMS=cpu (module top)
+    base = [sys.executable, os.path.abspath(__file__), "--dir", tmp]
     for k in ("slots", "requests", "prompt", "new", "chunk",
               "page_size", "layers", "hidden", "heads", "vocab",
               "kill_after", "snap_every"):
@@ -281,7 +288,6 @@ def main():
         "BENCH_recovery.json"))
     ap.add_argument("--child", choices=("serve", "restore"))
     ap.add_argument("--dir", default=None)
-    ap.add_argument("--compile-cache", default=None)
     ap.add_argument("--slots", type=int, default=2)
     ap.add_argument("--requests", type=int, default=4)
     ap.add_argument("--prompt", type=int, default=24)

@@ -63,16 +63,12 @@ def _scalars(obj: dict) -> dict:
 def headline(data) -> dict:
     """One artifact's headline numbers: the ``summary`` dict when the
     bench emits one (every serving bench since PR 6), else the
-    top-level scalars (the kernel/int8/roundup shapes)."""
+    top-level scalars (the kernel/int8 shapes)."""
     if not isinstance(data, dict):
         return {}
     summary = data.get("summary")
     if isinstance(summary, dict) and summary:
         return _scalars(summary)
-    # roundup artifacts (BENCH_r0N) carry their numbers under "parsed"
-    parsed = data.get("parsed")
-    if isinstance(parsed, dict) and parsed:
-        return _scalars(parsed)
     return _scalars(data)
 
 
