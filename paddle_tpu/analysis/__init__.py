@@ -191,6 +191,9 @@ REPO_ENGINE_RULE = EngineRule(
             "Scheduler.", "FIFOScheduler.", "SLOScheduler.",
             "ServingFrontend._apply_control", "ServingFrontend._drive",
             "ServingFrontend._recover_engine",
+            # the driver's one step call, with the clock pair around it
+            # that books the time between two steps
+            "ServingFrontend._step",
         ),
         # the flight recorder READS engine state (batch composition,
         # pool occupancy, SLO burn) from inside the step — sanctioned

@@ -70,9 +70,11 @@ See docs/SERVING.md for the user-facing API walk-through.
 from __future__ import annotations
 
 import asyncio
+import time
 from typing import Dict, List, Optional
 
 from .. import observability as _obs
+from ..observability import flight as _flight
 
 __all__ = ["Scheduler", "FIFOScheduler", "SLOScheduler", "make_scheduler",
            "TokenStream", "ServingFrontend"]
@@ -468,6 +470,10 @@ class ServingFrontend:
         self._loop: Optional[asyncio.AbstractEventLoop] = None
         self._closing = False
         self._closed = False
+        # when the last engine step returned (on the thread that ran
+        # it); None once the driver has waited for work or for a
+        # consumer, so `between_steps_s` holds only back-to-back steps
+        self._t_stepped: Optional[float] = None
         # ops plane: a frontend-wrapped engine serves the stream-aware
         # debug_dump from /statusz instead of the bare engine statusz
         from ..observability import opsserver as _opsserver
@@ -782,6 +788,7 @@ class ServingFrontend:
         if self._recoveries >= limit:
             return False
         self._recoveries += 1
+        self._t_stepped = None  # a rebuild is no time between steps
         self.engine = resilience.recover(self.engine, snapshot=snapshot,
                                          fault=fault)
         # follow the engine generation in the ops registry: /statusz
@@ -792,19 +799,45 @@ class ServingFrontend:
         _opsserver.register_frontend(self)
         return True
 
+    def _step(self):
+        """One engine step on whichever thread runs it.  The wall since
+        the previous step returned there — the hops to the event loop
+        and back, `_flush_finished`, `_apply_control` — is the
+        frontend's time between steps (``between_steps_s``), unless the
+        driver waited in between."""
+        last = self._t_stepped
+        if last is not None:
+            from .serving import _stats_add
+
+            _stats_add(between_steps_s=time.perf_counter() - last)
+        try:
+            return self.engine.step()
+        finally:
+            self._t_stepped = time.perf_counter()
+
+    def _span(self, name: str):
+        """The event loop's own work between two steps, as a
+        ``frontend.<name>`` span in the profiler's trace; the executor
+        hop around it is what stays unattributed there."""
+        return _flight.annotation("frontend." + name,
+                                  engine=self.engine._engine_id)
+
     async def _drive(self):
         from .errors import StepFault
 
         try:
             while True:
-                self._apply_control()
-                self._flush_finished()  # control may cancel/expire
+                with self._span("control"):
+                    self._apply_control()
+                with self._span("flush"):
+                    self._flush_finished()  # control may cancel/expire
                 if not self._has_work():
                     if self._closing and not self._control:
                         break
                     self._wake.clear()
                     if self._control:
                         continue
+                    self._t_stepped = None
                     await self._wake.wait()
                     continue
                 if not self._closing and not self._stream_space():
@@ -815,6 +848,7 @@ class ServingFrontend:
                     # the buffers may overshoot the cap there.
                     self._drained.clear()
                     if not self._stream_space():
+                        self._t_stepped = None
                         await self._drained.wait()
                     continue
                 # hung-step watchdog (FLAGS_step_timeout_ms): once the
@@ -837,7 +871,7 @@ class ServingFrontend:
                     if arm_abandon:
                         pre_sig = wd.sig()
                         loop = asyncio.get_running_loop()
-                        fut = loop.run_in_executor(None, self.engine.step)
+                        fut = loop.run_in_executor(None, self._step)
                         # the abandoned thread's late raise must not
                         # surface as "exception never retrieved"
                         fut.add_done_callback(
@@ -875,16 +909,17 @@ class ServingFrontend:
                                 raise e
                     elif self._step_in_thread:
                         await asyncio.get_running_loop() \
-                            .run_in_executor(None, self.engine.step)
+                            .run_in_executor(None, self._step)
                     else:
-                        self.engine.step()
+                        self._step()
                 except StepFault as e:
                     if self._recover_engine(e):
                         continue
                     raise
-                self._flush_finished()
-                self._notify_drained()  # queue may have drained: wake
-                # submitters
+                with self._span("flush"):
+                    self._flush_finished()
+                    self._notify_drained()  # queue may have drained:
+                    # wake submitters
         except StepFault as e:
             # an UNRECOVERED fatal step fault (the recovery budget is
             # spent — a recovered one was contained above): mark the

@@ -63,7 +63,6 @@ parity contract tests/test_paged_decode.py pins.
 """
 from __future__ import annotations
 
-import contextlib
 import functools
 import hashlib
 import heapq
@@ -101,14 +100,9 @@ from .. import observability as _obs
 from ..analysis import sanitizer as _san
 from ..observability import LOCK as _TELEMETRY_LOCK
 from ..observability import costmodel as _costmodel
+from ..observability import flight as _flight
 
 _STATS = {k: _decode_stat_zero(k) for k in DECODE_STAT_COUNTERS}
-
-# reusable no-op context for the flight recorder's phase timers when
-# the recorder is off (nullcontext is stateless, so ONE instance
-# serves every engine and thread)
-_NULL_CTX = contextlib.nullcontext()
-
 
 def _stats_add(**deltas):
     """Apply counter deltas atomically (one lock round per engine step,
@@ -1686,6 +1680,11 @@ class DecodeEngine:
         self._eos = eos_token_id
         self._key = jax.random.PRNGKey(seed)
         self._step_no = 0
+        # what the profiler spans of the current `step()` call carry as
+        # ``step=``, and the wall of that call which ``decode_time_s``
+        # / ``prefill_time_s`` already hold (dispatch to fetched tokens)
+        self._span_step = 0
+        self._batch_s = 0.0
         self._prefill_no = 0
         self._queue: "deque[Request]" = deque()
         self._decode_fn = None  # shapes are fixed: ONE jitted step
@@ -2116,17 +2115,20 @@ class DecodeEngine:
 
     def _phase(self, name: str):
         """Context manager timing a LEAF flight-recorder phase (device
-        dispatch, fetch, cache ops) — a reusable no-op when the
+        dispatch, fetch, cache ops) and opening its ``engine.<name>``
+        span in the profiler's trace — the span alone when the
         recorder is off, so call sites read `with self._phase("x"):`
         without repeating the None check."""
         fr = self._flight
-        return fr.phase(name) if fr is not None else _NULL_CTX
+        return fr.phase(name) if fr is not None else \
+            _flight.engine_span(self, name)
 
     def _excl_phase(self, name: str):
         """Like `_phase` for COMPOSITE host phases (admit/draft/emit):
         recorded exclusive of the leaf phases nested inside them."""
         fr = self._flight
-        return fr.exclusive_phase(name) if fr is not None else _NULL_CTX
+        return fr.exclusive_phase(name) if fr is not None else \
+            _flight.engine_span(self, name)
 
     def _fold_weight_quant(self) -> None:
         """Fold this engine's matmul weights to int8 storage
@@ -2727,8 +2729,9 @@ class DecodeEngine:
                 self._flight.event("resume", request=req.request_id)
             return
         if req.t_enqueue_ns is not None:
-            _obs.REQUEST_QUEUE_WAIT.observe(
-                (req.t_admit_ns - req.t_enqueue_ns) / 1e9)
+            wait_s = (req.t_admit_ns - req.t_enqueue_ns) / 1e9
+            _stats_add(admissions=1, queue_wait_s=wait_s)
+            _obs.REQUEST_QUEUE_WAIT.observe(wait_s)
             _obs.record_span("requests", "queued", req.t_enqueue_ns,
                              req.t_admit_ns - req.t_enqueue_ns,
                              tid=req.request_id,
@@ -2883,7 +2886,9 @@ class DecodeEngine:
         # prefill count and TTFT stamp wait for the NaN-sentinel check
         # below — a quarantined prefill emitted nothing (mirrors the
         # chunked path, where _on_first_token checks before stamping)
-        _stats_add(prefill_time_s=time.perf_counter() - t0)
+        dt = time.perf_counter() - t0
+        self._batch_s += dt
+        _stats_add(prefill_time_s=dt)
         _obs.record_span("engine", "prefill", t0_ns,
                          _obs.now_ns() - t0_ns,
                          tid=self._engine_id,
@@ -2988,6 +2993,8 @@ class DecodeEngine:
                     ttft_s * 1e3 > req.slo_ttft_ms:
                 self._slo_violation(req, "ttft")
         if req.t_admit_ns is not None:
+            _stats_add(first_tokens=1, first_token_wait_s=(
+                req.t_first_token_ns - req.t_admit_ns) / 1e9)
             _obs.record_span("requests", "prefill", req.t_admit_ns,
                              req.t_first_token_ns - req.t_admit_ns,
                              tid=req.request_id,
@@ -3370,8 +3377,6 @@ class DecodeEngine:
         host side.  ``decode_rows=False`` (the speculative path) feeds
         ONLY prompt chunks — decoding slots advance through the spec
         round that follows in the same engine step."""
-        from ..profiler import RecordEvent
-
         slots, qmax = self._slots, self._q_max
         # ragged mode widens the grid to Q_r >= Q_max so the ONE
         # executable's token shape also fits verify windows (K+1);
@@ -3435,57 +3440,56 @@ class DecodeEngine:
         self._flush_fresh_scales()
         t0 = time.perf_counter()
         t0_ns = _obs.now_ns()
-        with RecordEvent("serving.mixed_step"):
-            with self._phase(phase_name):
-                if self._ragged:
-                    # the unified executable takes no sample_idx /
-                    # sample_mask operands — every position draws a
-                    # target and the host selects each slot's span-end
-                    # row after the fetch below
-                    if self._kv_quant:
-                        (self._k_pages, self._v_pages, self._k_scales,
-                         self._v_scales, toks) = fn(
-                            self._params, self._k_pages, self._v_pages,
-                            self._k_scales, self._v_scales,
-                            self._dev(self._bt),
-                            self._dev(self._lens),
-                            self._dev(tokens), self._dev(caps),
-                            self._dev(key))
-                    else:
-                        self._k_pages, self._v_pages, toks = fn(
-                            self._params, self._k_pages, self._v_pages,
-                            self._dev(self._bt),
-                            self._dev(self._lens),
-                            self._dev(tokens), self._dev(caps),
-                            self._dev(key))
-                elif self._kv_quant:
+        with self._phase(phase_name):
+            if self._ragged:
+                # the unified executable takes no sample_idx /
+                # sample_mask operands — every position draws a
+                # target and the host selects each slot's span-end
+                # row after the fetch below
+                if self._kv_quant:
                     (self._k_pages, self._v_pages, self._k_scales,
                      self._v_scales, toks) = fn(
                         self._params, self._k_pages, self._v_pages,
                         self._k_scales, self._v_scales,
-                        jnp.asarray(self._bt), jnp.asarray(self._lens),
-                        jnp.asarray(tokens), jnp.asarray(caps),
-                        jnp.asarray(sample_idx),
-                        jnp.asarray(sample_mask), key)
+                        self._dev(self._bt),
+                        self._dev(self._lens),
+                        self._dev(tokens), self._dev(caps),
+                        self._dev(key))
                 else:
                     self._k_pages, self._v_pages, toks = fn(
                         self._params, self._k_pages, self._v_pages,
-                        jnp.asarray(self._bt), jnp.asarray(self._lens),
-                        jnp.asarray(tokens), jnp.asarray(caps),
-                        jnp.asarray(sample_idx),
-                        jnp.asarray(sample_mask), key)
-                if self._profiling is not None:
-                    # sampled device-sync probe (see _step_inner):
-                    # attributed to the DISPATCHED executable (ragged
-                    # or mixed) regardless of the flight phase this
-                    # step ran under — a chunkless full step runs the
-                    # program under the "decode" phase, and scoring it
-                    # against the decode profile would poison the
-                    # calibration
-                    self._profiling.probe(
-                        "ragged" if self._ragged else "mixed",
-                        toks, t0, t0_ns)
-            toks = self._host_fetch(toks)
+                        self._dev(self._bt),
+                        self._dev(self._lens),
+                        self._dev(tokens), self._dev(caps),
+                        self._dev(key))
+            elif self._kv_quant:
+                (self._k_pages, self._v_pages, self._k_scales,
+                 self._v_scales, toks) = fn(
+                    self._params, self._k_pages, self._v_pages,
+                    self._k_scales, self._v_scales,
+                    jnp.asarray(self._bt), jnp.asarray(self._lens),
+                    jnp.asarray(tokens), jnp.asarray(caps),
+                    jnp.asarray(sample_idx),
+                    jnp.asarray(sample_mask), key)
+            else:
+                self._k_pages, self._v_pages, toks = fn(
+                    self._params, self._k_pages, self._v_pages,
+                    jnp.asarray(self._bt), jnp.asarray(self._lens),
+                    jnp.asarray(tokens), jnp.asarray(caps),
+                    jnp.asarray(sample_idx),
+                    jnp.asarray(sample_mask), key)
+            if self._profiling is not None:
+                # sampled device-sync probe (see _step_inner):
+                # attributed to the DISPATCHED executable (ragged
+                # or mixed) regardless of the flight phase this
+                # step ran under — a chunkless full step runs the
+                # program under the "decode" phase, and scoring it
+                # against the decode profile would poison the
+                # calibration
+                self._profiling.probe(
+                    "ragged" if self._ragged else "mixed",
+                    toks, t0, t0_ns)
+        toks = self._host_fetch(toks)
         if self._kv_quant:
             self._note_refolds(int(toks[-1, 0] if self._ragged
                                    else toks[-1]))
@@ -3499,6 +3503,7 @@ class DecodeEngine:
             toks = np.where(sample_mask,
                             toks[np.arange(slots), sample_idx], 0)
         dt = time.perf_counter() - t0
+        self._batch_s += dt
         if self._fault is not None:
             toks = self._resilience.corrupt_tokens(
                 toks, [s for s in range(slots) if sample_mask[s]])
@@ -3513,14 +3518,14 @@ class DecodeEngine:
         if decode_rows:
             # a full mixed step IS this engine-step's decode step
             _stats_add(mixed_steps=1, prefill_chunks=len(chunk_of),
-                       steps=1, decode_time_s=dt,
+                       steps=1, decode_time_s=dt, mixed_time_s=dt,
                        occupancy_sum=n_active / slots,
                        kv_util_sum=self.pool.utilization())
         else:
             # chunk-only (speculative path): the spec round that follows
             # accounts the engine step; this wall is prefill work
             _stats_add(mixed_steps=1, prefill_chunks=len(chunk_of),
-                       prefill_time_s=dt)
+                       prefill_time_s=dt, mixed_time_s=dt)
         for c in chunk_of.values():
             _obs.PREFILL_CHUNK_TOKENS.observe(c)
         self._observe_step(t0_ns, dt, n_active, "mixed_step",
@@ -3645,13 +3650,14 @@ class DecodeEngine:
         san = _san.active()
         if san is not None:
             san.count_host_sync()
-        fr = self._flight
-        if fr is None:
-            return np.asarray(x)
-        t0 = time.perf_counter()
-        out = np.asarray(x)
-        fr.add_phase("fetch", time.perf_counter() - t0)
-        return out
+        with _flight.engine_span(self, "fetch"):
+            fr = self._flight
+            if fr is None:
+                return np.asarray(x)
+            t0 = time.perf_counter()
+            out = np.asarray(x)
+            fr.add_phase("fetch", time.perf_counter() - t0)
+            return out
 
     # -- live introspection ---------------------------------------------------
     def _snapshot_queue(self) -> List[Request]:
@@ -3873,6 +3879,11 @@ class DecodeEngine:
             self._debug_check_pool()
         elif self._pool_debug:
             self._debug_check_pool()
+        # every profiler span of this call carries the step number its
+        # dispatch will take (an idle pass shares the next step's)
+        self._span_step = self._step_no + 1
+        self._batch_s = 0.0
+        t_step = time.perf_counter()
         fr = self._flight
         if fr is not None:
             fr.begin_step()
@@ -3925,7 +3936,8 @@ class DecodeEngine:
             try:
                 out = self._resilience.run_step()
                 if self._durability is not None:
-                    self._durability.on_step_boundary()
+                    with _flight.engine_span(self, "step_tail"):
+                        self._durability.on_step_boundary()
             finally:
                 # the armed window closes on EVERY exit — /readyz's
                 # overdue probe must never read a completed (or
@@ -3962,28 +3974,42 @@ class DecodeEngine:
             if fr is not None and not self._abandoned:
                 fr.note_fault(e)
             raise
-        if self._profiling is not None:
-            # stamp the step's probe onto the open record (and retire
-            # one captured step) BEFORE the record seals
-            self._profiling.note_step_end(fr)
-        if fr is not None:
-            rec = fr.end_step()
-            if self._cost is not None and rec is not None:
-                # score the sealed record's prediction against its
-                # measured wall: EWMA calibration + error gauge +
-                # roofline / periodic ledger gauges (the calibration
-                # update site — engine thread, reads the record)
-                self._cost.observe(rec)
-            if self._profiling is not None and rec is not None:
-                # device/host split, measured MFU, and the predicted-
-                # vs-measured drift the mfu_regression rule watches
-                self._profiling.observe(rec)
-        if self._alerts is not None:
-            # between-steps alert cadence (FLAGS_alert_interval_steps):
-            # the engine thread walks the rule table AFTER the step's
-            # record sealed, so every signal it reads is step-boundary
-            # consistent and the hot path gained no locks
-            self._alerts.maybe_step()
+        # "step_tail" is a profiler span only — everything `step` does
+        # after the batch that no flight phase times (flight.PHASES is
+        # the label set of paddle_step_phase_seconds and stays as is)
+        with _flight.engine_span(self, "step_tail"):
+            if self._profiling is not None:
+                # stamp the step's probe onto the open record (and
+                # retire one captured step) BEFORE the record seals
+                self._profiling.note_step_end(fr)
+            if fr is not None:
+                rec = fr.end_step()
+                if self._cost is not None and rec is not None:
+                    # score the sealed record's prediction against its
+                    # measured wall: EWMA calibration + error gauge +
+                    # roofline / periodic ledger gauges (the
+                    # calibration update site — engine thread, reads
+                    # the record)
+                    self._cost.observe(rec)
+                if self._profiling is not None and rec is not None:
+                    # device/host split, measured MFU, and the
+                    # predicted-vs-measured drift the mfu_regression
+                    # rule watches
+                    self._profiling.observe(rec)
+            if self._alerts is not None:
+                # between-steps alert cadence
+                # (FLAGS_alert_interval_steps): the engine thread walks
+                # the rule table AFTER the step's record sealed, so
+                # every signal it reads is step-boundary consistent and
+                # the hot path gained no locks
+                self._alerts.maybe_step()
+        # the engine's own host time this step: its wall less the
+        # dispatch-to-fetched walls the step counters already hold
+        # (admit, cache, batch assembly, emit, tail), so that with
+        # ``decode_time_s`` it adds up to the step's wall and to no
+        # more — only steps that ran a batch get here
+        _stats_add(host_in_step_s=time.perf_counter() - t_step
+                   - self._batch_s)
         return out
 
     def _step_inner(self) -> bool:
@@ -3992,8 +4018,6 @@ class DecodeEngine:
         call it from outside the ladder).  Dispatches to the
         speculative round, the mixed prefill+decode step, or the
         classic decode step exactly as `step` historically did."""
-        from ..profiler import RecordEvent
-
         if self._fault is not None:
             # "slow_step" site: a deterministic injected stall (the
             # latency-fault class — SLO metrics see it, nothing raises)
@@ -4037,33 +4061,33 @@ class DecodeEngine:
         self._flush_fresh_scales()
         t0 = time.perf_counter()
         t0_ns = _obs.now_ns()
-        with RecordEvent("serving.decode_step"):
-            with self._phase("decode"):
-                if self._kv_quant:
-                    (self._k_pages, self._v_pages, self._k_scales,
-                     self._v_scales, toks) = fn(
-                        self._params, self._k_pages, self._v_pages,
-                        self._k_scales, self._v_scales,
-                        jnp.asarray(self._bt), jnp.asarray(self._lens),
-                        jnp.asarray(self._last),
-                        jnp.asarray(self._active), key)
-                else:
-                    self._k_pages, self._v_pages, toks = fn(
-                        self._params, self._k_pages, self._v_pages,
-                        jnp.asarray(self._bt), jnp.asarray(self._lens),
-                        jnp.asarray(self._last),
-                        jnp.asarray(self._active), key)
-                if self._profiling is not None:
-                    # sampled device-sync probe: block on the step's
-                    # output INSIDE the phase (the phase wall absorbs
-                    # the wait) so dispatch-start -> ready is the
-                    # executable's measured device seconds
-                    self._profiling.probe("decode", toks, t0, t0_ns)
-            toks = self._host_fetch(toks)
+        with self._phase("decode"):
+            if self._kv_quant:
+                (self._k_pages, self._v_pages, self._k_scales,
+                 self._v_scales, toks) = fn(
+                    self._params, self._k_pages, self._v_pages,
+                    self._k_scales, self._v_scales,
+                    jnp.asarray(self._bt), jnp.asarray(self._lens),
+                    jnp.asarray(self._last),
+                    jnp.asarray(self._active), key)
+            else:
+                self._k_pages, self._v_pages, toks = fn(
+                    self._params, self._k_pages, self._v_pages,
+                    jnp.asarray(self._bt), jnp.asarray(self._lens),
+                    jnp.asarray(self._last),
+                    jnp.asarray(self._active), key)
+            if self._profiling is not None:
+                # sampled device-sync probe: block on the step's
+                # output INSIDE the phase (the phase wall absorbs
+                # the wait) so dispatch-start -> ready is the
+                # executable's measured device seconds
+                self._profiling.probe("decode", toks, t0, t0_ns)
+        toks = self._host_fetch(toks)
         if self._kv_quant:
             self._note_refolds(int(toks[-1]))
             toks = toks[:-1]
         dt = time.perf_counter() - t0
+        self._batch_s += dt
         if self._fault is not None:
             toks = self._resilience.corrupt_tokens(
                 toks, [s for s in range(self._slots) if self._active[s]])

@@ -819,8 +819,6 @@ class SpeculativeDecoder:
     def step(self) -> bool:
         """One propose->verify->accept round over every active slot.
         Called by `DecodeEngine.step` after admission."""
-        from ..profiler import RecordEvent
-
         eng = self.engine
         slots = eng._slots
 
@@ -934,30 +932,29 @@ class SpeculativeDecoder:
             eng._key, _fold_counter(eng._step_no, RNG_DECODE_DOMAIN))
         t0 = time.perf_counter()
         tv_ns = _obs.now_ns()
-        with RecordEvent("serving.spec_verify_step"):
-            with eng._phase("verify"):
-                if eng._kv_quant:
-                    (eng._k_pages, eng._v_pages, eng._k_scales,
-                     eng._v_scales, targets) = fn(
-                        eng._params, eng._k_pages, eng._v_pages,
-                        eng._k_scales, eng._v_scales,
-                        eng._dev(eng._bt), eng._dev(eng._lens),
-                        eng._dev(tokens), eng._dev(caps),
-                        eng._dev(key))
-                else:
-                    eng._k_pages, eng._v_pages, targets = fn(
-                        eng._params, eng._k_pages, eng._v_pages,
-                        eng._dev(eng._bt), eng._dev(eng._lens),
-                        eng._dev(tokens), eng._dev(caps),
-                        eng._dev(key))
-                if eng._profiling is not None:
-                    # sampled device-sync probe (observability.
-                    # profiling): the verify executable's measured
-                    # device seconds, blocked inside the phase
-                    eng._profiling.probe(
-                        "ragged" if eng._ragged else "verify",
-                        targets, t0, tv_ns)
-            targets = eng._host_fetch(targets)
+        with eng._phase("verify"):
+            if eng._kv_quant:
+                (eng._k_pages, eng._v_pages, eng._k_scales,
+                 eng._v_scales, targets) = fn(
+                    eng._params, eng._k_pages, eng._v_pages,
+                    eng._k_scales, eng._v_scales,
+                    eng._dev(eng._bt), eng._dev(eng._lens),
+                    eng._dev(tokens), eng._dev(caps),
+                    eng._dev(key))
+            else:
+                eng._k_pages, eng._v_pages, targets = fn(
+                    eng._params, eng._k_pages, eng._v_pages,
+                    eng._dev(eng._bt), eng._dev(eng._lens),
+                    eng._dev(tokens), eng._dev(caps),
+                    eng._dev(key))
+            if eng._profiling is not None:
+                # sampled device-sync probe (observability.
+                # profiling): the verify executable's measured
+                # device seconds, blocked inside the phase
+                eng._profiling.probe(
+                    "ragged" if eng._ragged else "verify",
+                    targets, t0, tv_ns)
+        targets = eng._host_fetch(targets)
         if eng._kv_quant:
             eng._note_refolds(int(targets[slots, 0]))
             targets = targets[:slots]
@@ -1023,6 +1020,7 @@ class SpeculativeDecoder:
                 if reason:
                     eng._finish(s, reason)
 
+        eng._batch_s += t_draft + t_verify
         _stats_add(spec_steps=1, spec_slot_steps=n_verify, steps=1,
                    spec_proposed=proposed_total,
                    spec_accepted=accepted_total,

@@ -67,7 +67,8 @@ from typing import List, Optional
 from .metrics import _state
 from ..analysis.sanitizer import TrackedLock as _TrackedLock
 
-__all__ = ["PHASES", "BURN_KINDS", "FlightRecorder"]
+__all__ = ["PHASES", "BURN_KINDS", "FlightRecorder", "annotation",
+           "engine_span"]
 
 # step-phase attribution vocabulary (the paddle_step_phase_seconds
 # label set); see the module docstring for the disjointness contract
@@ -102,21 +103,54 @@ def _obs():
     return _obs_mod
 
 
+_annotation_cls = None
+
+
+def annotation(name: str, **args):
+    """A host span in the profiler's OWN trace
+    (`jax.profiler.TraceAnnotation`): on the same clock as the device
+    operations, so an idle gap of the chip can be attributed to it.
+    Costs under a microsecond while no profile is being taken; ``args``
+    ride as the event's stats and leave its name clean.  jax is
+    resolved lazily, as everywhere in this package."""
+    global _annotation_cls
+    if _annotation_cls is None:
+        from jax.profiler import TraceAnnotation
+
+        _annotation_cls = TraceAnnotation
+    return _annotation_cls(name, **args)
+
+
+def engine_span(engine, name: str):
+    """The ``engine.<name>`` span of ``engine``'s current step — THE
+    seam between a flight-recorder phase and the profiler's trace:
+    every phase timer below opens one beside its `perf_counter` pair,
+    and `DecodeEngine._phase` hands it out alone when the recorder is
+    off.  A step's spans share ``step=`` (stamped at the top of
+    `DecodeEngine.step`)."""
+    return annotation("engine." + name, step=engine._span_step,
+                      engine=engine._engine_id)
+
+
 class _Phase:
     """Plain timed phase: the wall between enter and exit lands on one
-    phase of the open record."""
+    phase of the open record, and is an ``engine.<phase>`` span in a
+    running profile."""
 
-    __slots__ = ("fr", "name", "_t0")
+    __slots__ = ("fr", "name", "_t0", "_span")
 
     def __init__(self, fr, name):
         self.fr, self.name = fr, name
 
     def __enter__(self):
+        self._span = engine_span(self.fr.engine, self.name)
+        self._span.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         self.fr.add_phase(self.name, time.perf_counter() - self._t0)
+        self._span.__exit__(*exc)
         return False
 
 
@@ -124,14 +158,18 @@ class _ExclusivePhase:
     """Composite host phase: records wall MINUS whatever other phases
     were added inside it, so e.g. ``admit`` never double-counts a
     legacy prefill's device dispatch and ``draft`` never double-counts
-    the drafter's blocking fetches."""
+    the drafter's blocking fetches.  Its profiler span is NOT
+    exclusive: it holds the leaf spans, and the trace reducer lets the
+    innermost span win."""
 
-    __slots__ = ("fr", "name", "_t0", "_base")
+    __slots__ = ("fr", "name", "_t0", "_base", "_span")
 
     def __init__(self, fr, name):
         self.fr, self.name = fr, name
 
     def __enter__(self):
+        self._span = engine_span(self.fr.engine, self.name)
+        self._span.__enter__()
         self._t0 = time.perf_counter()
         self._base = self.fr._phase_sum()
         return self
@@ -140,6 +178,7 @@ class _ExclusivePhase:
         wall = time.perf_counter() - self._t0
         inner = self.fr._phase_sum() - self._base
         self.fr.add_phase(self.name, max(0.0, wall - inner))
+        self._span.__exit__(*exc)
         return False
 
 

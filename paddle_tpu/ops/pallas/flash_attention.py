@@ -245,6 +245,7 @@ def _pallas_forward(q, k, v, is_causal, scale, block_q, block_k):
                              lambda i, j: (i, j, 0)),
             ],
             out_shape=out_shape,
+            name="flash_attention_fwd",
         )(qr, kr, vr)
         return out.reshape(b, h, sq, d), lse[:, :, 0]
 
@@ -271,6 +272,7 @@ def _pallas_forward(q, k, v, is_causal, scale, block_q, block_k):
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32),
                         pltpu.VMEM((block_q, _LANES), jnp.float32),
                         pltpu.VMEM((block_q, _LANES), jnp.float32)],
+        name="flash_attention_fwd",
     )(qr, kr, vr)
     return out.reshape(b, h, sq, d), lse[:, :, 0]
 
@@ -443,6 +445,7 @@ def _pallas_backward(q, k, v, out, lse, g, is_causal, scale, block_q,
         ],
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
+        name="flash_attention_bwd_dkv",
     )(qr, kr, vr, dor, outr, lse_b)
 
     kv_idx = (_causal_kv_clamp(block_q, block_k) if is_causal
@@ -464,6 +467,7 @@ def _pallas_backward(q, k, v, out, lse, g, is_causal, scale, block_q,
                                lambda i, j, r: (i, j, 0)),
         out_shape=_out_struct((b * h, sq, d), q.dtype, q, k, v, g),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        name="flash_attention_bwd_dq",
     )(qr, kr, vr, dor, outr, lse_b)
 
     return (dq.reshape(b, h, sq, d), dk.reshape(b, h, sk, d),

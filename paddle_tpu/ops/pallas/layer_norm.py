@@ -51,6 +51,7 @@ def _fwd_pallas(x2d, w, b, eps, block_rows=256):
         ],
         out_specs=pl.BlockSpec((rows, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, d), x2d.dtype),
+        name="layer_norm",
     )(x2d, w, b)
 
 

@@ -547,6 +547,7 @@ def _pallas_paged_attention(q, k_pages, v_pages, block_tables, seq_lens,
                           scale=s, group=g, q_len=qn),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, gp, d), q.dtype),
+        name="paged_attention",
     )(*operands)
     out = out[:, :, :rows, :].reshape(b, hkv, qn, g, d)
     out = out.transpose(0, 2, 1, 3, 4).reshape(b, qn, hq, d)

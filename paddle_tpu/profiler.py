@@ -127,6 +127,20 @@ DECODE_STAT_COUNTERS = (
     "decode_retraces", "prefill_retraces", "mixed_retraces",
     "verify_retraces", "draft_retraces", "kv_quant_retraces",
     "ragged_retraces",
+    # where a request's first second goes, and what the host does while
+    # the chip waits (read by the benchmark's serve metrics; the same
+    # sites open the ``engine.*`` / ``frontend.*`` profiler spans):
+    # enqueue -> first admission and admission -> first token, summed
+    # over the requests that got there; the dispatch-to-fetched wall of
+    # mixed steps alone (``decode_time_s`` / ``prefill_time_s`` hold
+    # them blended with plain decode steps); `DecodeEngine.step`'s wall
+    # outside the dispatch-to-fetched walls those two sums hold, on
+    # steps that ran a batch; and the frontend's wall from one step's
+    # return to the next step's call when it did not wait in between
+    # (so ``decode_time_s + prefill_time_s + host_in_step_s +
+    # between_steps_s`` is the wall a busy engine spent, counted once)
+    "queue_wait_s", "admissions", "first_token_wait_s", "first_tokens",
+    "mixed_time_s", "host_in_step_s", "between_steps_s",
 )
 DECODE_STAT_DERIVED = ("avg_step_ms", "batch_occupancy",
                        "kv_block_utilization",

@@ -484,7 +484,7 @@ class TestEngineInstrumentation:
             assert needle in txt, needle
 
     def test_merged_trace_has_all_three_tracks(self):
-        profiler.start_profiler()  # host tracer on -> decode RecordEvents
+        profiler.start_profiler()  # host tracer on
         eng = _tiny_engine()
         eng.generate([np.arange(6, dtype=np.int32)], max_new_tokens=4)
         native.tracer_disable()
@@ -494,8 +494,14 @@ class TestEngineInstrumentation:
         assert {"engine", "requests"} <= tracks
         if native.native_available():
             assert "host" in tracks
-            assert any(e.get("name") == "serving.decode_step"
+        # the decode dispatch is timed at ONE seam: the flight record's
+        # phase, which a running profile shows as the engine.decode
+        # span (tests/test_trace_seam.py) — the serve loop puts no
+        # RecordEvent of its own around it
+        assert not any(str(e.get("name", "")).startswith("serving.")
                        for e in data["traceEvents"])
+        assert any("decode" in r["phases"]
+                   for r in eng._flight.records() if r["kind"] == "step")
         names = {e["name"] for e in data["traceEvents"]
                  if e.get("ph") == "X"}
         assert {"prefill", "decode_step", "queued", "decode"} <= names

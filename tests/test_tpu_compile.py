@@ -12,6 +12,7 @@ library, and every xdist worker imports every test file.
 """
 import functools
 import os
+import re
 
 import numpy as np
 import pytest
@@ -88,8 +89,16 @@ def _compile(fn, *args, **jit_kw):
     return jax.jit(fn, **jit_kw).lower(*args).compile()
 
 
-def _has_kernel(compiled):
-    return chip_smoke.KERNEL in compiled.as_text()
+def _has_kernel(compiled, name=None):
+    """A Pallas call is in the program; with ``name``, as an instruction
+    of that name, which is what a device trace shows the kernel as
+    (under autodiff JAX wraps it: ``jvp_<name>_``,
+    ``transpose_jvp_<name>__``)."""
+    text = compiled.as_text()
+    if name is None:
+        return chip_smoke.KERNEL in text
+    return re.search(rf"%(\w+?_)?{name}_*(\.\d+)? = "
+                     rf"[^\n]*{chip_smoke.KERNEL}", text) is not None
 
 
 # ---------------------------------------------------------------------------
@@ -112,8 +121,12 @@ def test_flash_fwd_bwd_at_the_train_shape(one_chip, dtype, batch):
             jnp.float32).sum()
 
     compiled = _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)), x, x, x)
-    # forward, dk/dv and dq kernels
+    # forward, dk/dv and dq kernels, each under its own name (what a
+    # device trace shows them as)
     assert compiled.as_text().count(chip_smoke.KERNEL) >= 3
+    for name in ("flash_attention_fwd", "flash_attention_bwd_dkv",
+                 "flash_attention_bwd_dq"):
+        assert _has_kernel(compiled, name), name
 
 
 def test_layer_norm_768(one_chip):
@@ -121,7 +134,7 @@ def test_layer_norm_768(one_chip):
     x = jax.ShapeDtypeStruct((16 * 1024, h), jnp.bfloat16, sharding=one_chip)
     w = jax.ShapeDtypeStruct((h,), jnp.float32, sharding=one_chip)
     assert _has_kernel(_compile(
-        functools.partial(LN._fwd_pallas, eps=1e-5), x, w, w))
+        functools.partial(LN._fwd_pallas, eps=1e-5), x, w, w), "layer_norm")
 
 
 # ---------------------------------------------------------------------------
@@ -155,7 +168,7 @@ def test_paged_kernel_at_the_smoke_pool(one_chip, kv_dtype, qn):
     q_dtype = jnp.float32 if kv_dtype == jnp.int8 else kv_dtype
     args = _paged_args(one_chip, chip_smoke.SERVE["slots"],
                        chip_smoke.SERVE["num_pages"], qn, q_dtype, kv_dtype)
-    assert _has_kernel(_compile(_paged, *args))
+    assert _has_kernel(_compile(_paged, *args), "paged_attention")
 
 
 @pytest.mark.parametrize("qn", [1, Q_MAX])
