@@ -45,10 +45,10 @@ SEED = 0
 GPT_BASE = dict(vocab_size=50304, hidden_size=768, num_layers=12,
                 num_heads=12, max_seq_len=1024)
 TRAIN = dict(batch=16, seq=1024, steps=4)
-# The pool is sized so that the step executables fit the chip, not so that
-# it fills it: a step's temp memory is about four times the pool today
-# (ROADMAP Queue 1), so 16 sequences of 1024 tokens — 256 pages of 64, 1.2 GB
-# of f32 K/V — is what leaves the compiler room on 16 GB.
+# The pool the benchmark's serve cell holds: 16 sequences of 1024 tokens,
+# 256 pages of 64, 1.2 GB of f32 K/V (2.4 GB as it lies on the chip, where a
+# 64-wide row fills half of a 128-lane row: `pa.kv_pool_width`).  A step's
+# temp is a tenth of that since the K/V write is made in place (PR 27).
 SERVE = dict(slots=16, num_pages=256, prompt_lens=(192, 301, 420, 256),
              new_tokens=32, ragged_prompt_lens=(210, 333), warm_lens=(70, 9))
 FOUR = dict(train_batch=8, train_steps=3, loss_rtol=1e-3,  # tests/test_gpt.py

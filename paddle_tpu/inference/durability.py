@@ -549,8 +549,12 @@ class DurabilityManager:
         items = sorted(eng.pool._page_hash.items())  # (page, hash)
         ids = np.asarray([p for p, _ in items], np.int32)
         arrays = {
-            "k": np.asarray(jax.device_get(eng._k_pages[:, :, ids])),
-            "v": np.asarray(jax.device_get(eng._v_pages[:, :, ids])),
+            # the K/V lanes of a row (a float pool's rows are wider:
+            # `pa.kv_pool_width`)
+            "k": np.asarray(jax.device_get(
+                eng._k_pages[:, :, ids, :, :eng._head_dim])),
+            "v": np.asarray(jax.device_get(
+                eng._v_pages[:, :, ids, :, :eng._head_dim])),
         }
         if eng._kv_quant:
             arrays["ks"] = np.asarray(
@@ -654,9 +658,9 @@ def _install_kv_sidecar(journal_dir: str, snap: SnapshotWire,
     # reset must not zero
     ids = [eng.pool.alloc_page() for _ in range(n)]
     idx = jnp.asarray(np.asarray(ids, np.int32))
-    eng._k_pages = eng._k_pages.at[:, :, idx].set(
+    eng._k_pages = eng._k_pages.at[:, :, idx, :, :eng._head_dim].set(
         jnp.asarray(k[:, :, :n]))
-    eng._v_pages = eng._v_pages.at[:, :, idx].set(
+    eng._v_pages = eng._v_pages.at[:, :, idx, :, :eng._head_dim].set(
         jnp.asarray(v[:, :, :n]))
     if eng._kv_quant:
         eng._k_scales = eng._k_scales.at[:, :, idx].set(

@@ -908,24 +908,24 @@ def _gpt_prefill(params, ids, true_len, bt_row, k_pages, v_pages, key, *,
                  num_heads, head_dim, eps, sampler, temperature, top_k,
                  top_p):
     """Prompt pass for ONE request: full causal attention over the
-    (bucket-padded) prompt, K/V scattered into the request's pages,
-    first token sampled from the last valid position's logits.
+    (bucket-padded) prompt, its ``true_len`` K/V rows written into the
+    request's pages (`pa.paged_kv_write`; padding rows are not), first
+    token sampled from the last valid position's logits.
 
     ids: [1, S_pad] int32; true_len: scalar int32; bt_row: [pages_max]
-    int32; k_pages/v_pages: [L, Hkv, num_pages, page, D] (donated).
+    int32; k_pages/v_pages: [L, Hkv, num_pages, page, W] (donated; rows
+    W = `pa.kv_pool_width` of D wide).
     """
     from ..nn.functional.attention import _sdpa_reference
 
     s_pad = ids.shape[1]
     h = num_heads * head_dim
-    num_pages_total = k_pages.shape[2]
-    page = k_pages.shape[3]
     pos = jnp.arange(s_pad, dtype=jnp.int32)
     x = params["wte"][ids[0]] + params["wpe"][pos]  # [S, h]
 
-    valid = pos < true_len
-    page_idx = jnp.where(valid, bt_row[pos // page], num_pages_total)
-    slot = pos % page
+    # the one-request form of the batched write: a run of true_len rows
+    # from position 0 (rows past it are bucket padding and are not written)
+    bt, start, cap = bt_row[None], jnp.zeros((1,), jnp.int32), true_len[None]
 
     for li, blk in enumerate(params["blocks"]):
         y = _ln(x, blk["ln1_w"], blk["ln1_b"], eps)
@@ -934,13 +934,10 @@ def _gpt_prefill(params, ids, true_len, bt_row, k_pages, v_pages, key, *,
         q = qkv[:, 0].transpose(1, 0, 2)[None]  # [1, H, S, D]
         k = qkv[:, 1].transpose(1, 0, 2)[None]
         v = qkv[:, 2].transpose(1, 0, 2)[None]
-        # out-of-bounds page index (padded rows) -> scatter drops the
-        # row.  The int layer index joins the advanced-index group, so
-        # the result dims lead: slice shape is [S, Hkv, D]
-        k_pages = k_pages.at[li, :, page_idx, slot, :].set(
-            k[0].transpose(1, 0, 2))
-        v_pages = v_pages.at[li, :, page_idx, slot, :].set(
-            v[0].transpose(1, 0, 2))
+        k_pages = pa.paged_kv_write(k_pages, li, qkv[None, :, 1], bt,
+                                    start, cap)
+        v_pages = pa.paged_kv_write(v_pages, li, qkv[None, :, 2], bt,
+                                    start, cap)
         attn = _sdpa_reference(q, k, v, None, 0.0, None, True)[0]
         attn = attn.transpose(1, 0, 2).reshape(s_pad, h)
         x = x + _wmm(attn, blk, "out_w") + blk["out_b"]
@@ -963,32 +960,31 @@ def _gpt_decode_step(params, k_pages, v_pages, block_tables, seq_lens,
                      sampler, temperature, top_k, top_p):
     """One batched decode step over every slot: write the incoming
     token's K/V into its page, ragged paged attention over the pool,
-    sample the next token.  Donated k_pages/v_pages make the cache
-    update in place; inactive slots write nowhere (OOB page index) and
-    read length 0."""
+    sample the next token.  The pools ([L, Hkv, P, page, W], rows
+    `pa.kv_pool_width` wide) are donated and `pa.paged_kv_write`
+    rewrites one page a live slot where it lies: on the chip nothing
+    pool-sized moves but the per-layer slice the kernel takes
+    (`pa.kv_layer`; tests/test_tpu_compile.py).  Inactive slots write
+    nothing (cap 0) and read length 0."""
     b = tokens.shape[0]
     h = num_heads * head_dim
-    num_pages_total = k_pages.shape[2]
-    page = k_pages.shape[3]
 
     pos = seq_lens  # the incoming token's position
     x = params["wte"][tokens] + params["wpe"][pos]  # [B, h]
-    page_idx = jnp.where(
-        active, block_tables[jnp.arange(b), pos // page], num_pages_total)
-    slot = pos % page
-    lens_now = seq_lens + active.astype(jnp.int32)
+    caps = active.astype(jnp.int32)  # one row a live slot, none otherwise
+    lens_now = seq_lens + caps
 
     for li, blk in enumerate(params["blocks"]):
         y = _ln(x, blk["ln1_w"], blk["ln1_b"], eps)
         qkv = _wmm(y, blk, "qkv_w") + blk["qkv_b"]
         qkv = qkv.reshape(b, 3, num_heads, head_dim)
         q, k, v = qkv[:, 0], qkv[:, 1], qkv[:, 2]  # [B, H, D]
-        # slice shape [B, Hkv, D] (int layer index joins the advanced
-        # group — batch dims lead); inactive rows have an OOB page index
-        # and are dropped by the scatter
-        k_pages = k_pages.at[li, :, page_idx, slot, :].set(k)
-        v_pages = v_pages.at[li, :, page_idx, slot, :].set(v)
-        attn = pa.paged_attention(q, k_pages[li], v_pages[li],
+        k_pages = pa.paged_kv_write(k_pages, li, k[:, None], block_tables,
+                                    seq_lens, caps)
+        v_pages = pa.paged_kv_write(v_pages, li, v[:, None], block_tables,
+                                    seq_lens, caps)
+        attn = pa.paged_attention(q, pa.kv_layer(k_pages, li, head_dim),
+                                  pa.kv_layer(v_pages, li, head_dim),
                                   block_tables, lens_now)
         x = x + _wmm(attn.reshape(b, h), blk, "out_w") + blk["out_b"]
         y = _ln(x, blk["ln2_w"], blk["ln2_b"], eps)
@@ -1012,8 +1008,9 @@ def _gpt_mixed_step(params, k_pages, v_pages, block_tables, seq_lens,
     contribute a prompt chunk (rows 0..cap-1 of their ``tokens`` row),
     decoding slots contribute their last sampled token (cap 1), stalled
     or inactive slots contribute nothing (cap 0).  K/V for every
-    contributed row is scattered into the slot's already-reserved pages
-    (write-capped, so padding rows are dropped), attention runs through
+    contributed row is written into the slot's already-reserved pages
+    (`pa.paged_kv_write`: a page at a time, rows past the cap keep what
+    the page held), attention runs through
     the ragged multi-query paged kernel with per-sequence causal
     offsets (``q_offsets = seq_lens``: each chunk starts at the slot's
     current KV length), and ONE token per slot is sampled from the row
@@ -1022,7 +1019,8 @@ def _gpt_mixed_step(params, k_pages, v_pages, block_tables, seq_lens,
     zeroes the draw for slots still mid-prefill.
 
     tokens: [B, Q_max] int32; write_caps/sample_idx: [B] int32;
-    sample_mask: [B] bool; k_pages/v_pages donated (in-place update).
+    sample_mask: [B] bool; k_pages/v_pages donated (the update is in
+    place, on the chip too: see `_gpt_decode_step`).
     Returns (k_pages, v_pages, sampled [B] int32).
 
     The shapes are fixed per engine, so this compiles ONCE — the pow-2
@@ -1031,15 +1029,11 @@ def _gpt_mixed_step(params, k_pages, v_pages, block_tables, seq_lens,
     """
     b, qn = tokens.shape
     h = num_heads * head_dim
-    num_pages_total = k_pages.shape[2]
-    page = k_pages.shape[3]
 
     offs = jnp.arange(qn, dtype=jnp.int32)
     pos = seq_lens[:, None] + offs[None, :]              # [B, Q]
     wpe_max = params["wpe"].shape[0] - 1
     x = params["wte"][tokens] + params["wpe"][jnp.minimum(pos, wpe_max)]
-    page_idx, slot = pa.paged_write_indices(
-        block_tables, seq_lens, write_caps, qn, num_pages_total, page)
     lens_now = seq_lens + write_caps
 
     for li, blk in enumerate(params["blocks"]):
@@ -1047,12 +1041,12 @@ def _gpt_mixed_step(params, k_pages, v_pages, block_tables, seq_lens,
         qkv = _wmm(y, blk, "qkv_w") + blk["qkv_b"]
         qkv = qkv.reshape(b, qn, 3, num_heads, head_dim)
         q = qkv[:, :, 0]                                 # [B, Q, H, D]
-        # slice shape [B, Q, Hkv, D] (the int layer index joins the
-        # advanced group — batch dims lead); capped rows have an OOB
-        # page index and are dropped by the scatter
-        k_pages = k_pages.at[li, :, page_idx, slot, :].set(qkv[:, :, 1])
-        v_pages = v_pages.at[li, :, page_idx, slot, :].set(qkv[:, :, 2])
-        attn = pa.paged_attention(q, k_pages[li], v_pages[li],
+        k_pages = pa.paged_kv_write(k_pages, li, qkv[:, :, 1],
+                                    block_tables, seq_lens, write_caps)
+        v_pages = pa.paged_kv_write(v_pages, li, qkv[:, :, 2],
+                                    block_tables, seq_lens, write_caps)
+        attn = pa.paged_attention(q, pa.kv_layer(k_pages, li, head_dim),
+                                  pa.kv_layer(v_pages, li, head_dim),
                                   block_tables, lens_now,
                                   q_offsets=seq_lens)
         x = x + _wmm(attn.reshape(b, qn, h), blk, "out_w") \
@@ -1359,7 +1353,7 @@ def _gpt_ragged_step(params, k_pages, v_pages, block_tables, seq_lens,
                      mesh=None):
     """The unified ragged step: score up to Q_r incoming tokens per
     slot in ONE pass — write rows ``i < write_caps[b]`` into the slot's
-    already-reserved pages (capped rows are dropped by the scatter),
+    already-reserved pages (`pa.paged_kv_write` leaves capped rows out),
     run ragged multi-query paged attention with per-sequence causal
     offsets, and draw a target token at EVERY position with the
     engine's own `sample_logits`.
@@ -1379,34 +1373,33 @@ def _gpt_ragged_step(params, k_pages, v_pages, block_tables, seq_lens,
     ``prefill_q_max`` / K to the traffic when decode dominates."""
     b, qn = tokens.shape
     h = num_heads * head_dim
-    num_pages_total = k_pages.shape[2]
-    page = k_pages.shape[3]
     cst = _mesh_constrain(mesh)
     attend = _mesh_paged_attention(mesh)
 
     pos = seq_lens[:, None] + jnp.arange(qn, dtype=jnp.int32)[None, :]
     wpe_max = params["wpe"].shape[0] - 1
     x = params["wte"][tokens] + params["wpe"][jnp.minimum(pos, wpe_max)]
-    page_idx, slot = pa.paged_write_indices(
-        block_tables, seq_lens, write_caps, qn, num_pages_total, page)
     lens_now = seq_lens + write_caps
 
     for li, blk in enumerate(params["blocks"]):
         y = _ln(x.reshape(b * qn, h), blk["ln1_w"], blk["ln1_b"], eps)
         qkv = _wmm(y, blk, "qkv_w") + blk["qkv_b"]
-        # head axis sharded over 'mp' from here: the KV scatter and the
+        # head axis sharded over 'mp' from here: the K/V write and the
         # paged-attention gather stay chip-local (each chip owns its
         # head-slice of every page)
         qkv = cst(qkv.reshape(b, qn, 3, num_heads, head_dim),
                   None, None, None, "mp", None)
         q = qkv[:, :, 0]                                 # [B, Q, H, D]
         k_pages = cst(
-            k_pages.at[li, :, page_idx, slot, :].set(qkv[:, :, 1]),
+            pa.paged_kv_write(k_pages, li, qkv[:, :, 1], block_tables,
+                              seq_lens, write_caps),
             None, "mp", None, None, None)
         v_pages = cst(
-            v_pages.at[li, :, page_idx, slot, :].set(qkv[:, :, 2]),
+            pa.paged_kv_write(v_pages, li, qkv[:, :, 2], block_tables,
+                              seq_lens, write_caps),
             None, "mp", None, None, None)
-        attn = cst(attend(q, k_pages[li], v_pages[li], block_tables,
+        attn = cst(attend(q, pa.kv_layer(k_pages, li, head_dim),
+                          pa.kv_layer(v_pages, li, head_dim), block_tables,
                           lens_now, seq_lens),
                    None, None, "mp", None)
         # row-parallel out proj: replicating the residual forces the
@@ -1640,8 +1633,10 @@ class DecodeEngine:
         self._pages_per_seq = -(-self._max_seq_len // self._page)
         n_pages = int(num_pages or self._slots * self._pages_per_seq)
         self.pool = KVBlockPool(n_pages)
+        # a float pool's rows are whole 128-lane rows: how the kernel
+        # reads them on the chip, said in the shape
         shape = (self._num_layers, self._num_heads, n_pages, self._page,
-                 self._head_dim)
+                 pa.kv_pool_width(self._head_dim, storage_dtype))
         self._k_pages = jnp.zeros(shape, storage_dtype)
         self._v_pages = jnp.zeros(shape, storage_dtype)
         # per-page, per-head dequant scales (quantized mode only):

@@ -91,9 +91,9 @@ def _gpt_spec_verify(params, k_pages, v_pages, block_tables, seq_lens,
                      tokens, write_caps, key, *, num_heads, head_dim,
                      eps, sampler, temperature, top_k, top_p):
     """Score Q = K+1 incoming tokens per slot in ONE pass: write their
-    K/V into the slots' already-reserved pages (write-capped per
-    sequence so rows past a request's token budget are dropped by the
-    scatter), run ragged multi-query paged attention with per-sequence
+    K/V into the slots' already-reserved pages (`pa.paged_kv_write`,
+    write-capped per sequence so rows past a request's token budget
+    are left out), run ragged multi-query paged attention with per-sequence
     causal offsets, and draw a target token at every position with the
     engine's own `sample_logits`.
 
@@ -107,14 +107,10 @@ def _gpt_spec_verify(params, k_pages, v_pages, block_tables, seq_lens,
     """
     b, qn = tokens.shape
     h = num_heads * head_dim
-    num_pages_total = k_pages.shape[2]
-    page = k_pages.shape[3]
 
     pos = seq_lens[:, None] + jnp.arange(qn, dtype=jnp.int32)[None, :]
     wpe_max = params["wpe"].shape[0] - 1
     x = params["wte"][tokens] + params["wpe"][jnp.minimum(pos, wpe_max)]
-    page_idx, slot = pa.paged_write_indices(
-        block_tables, seq_lens, write_caps, qn, num_pages_total, page)
     lens_now = seq_lens + write_caps
 
     for li, blk in enumerate(params["blocks"]):
@@ -122,12 +118,12 @@ def _gpt_spec_verify(params, k_pages, v_pages, block_tables, seq_lens,
         qkv = _wmm(y, blk, "qkv_w") + blk["qkv_b"]
         qkv = qkv.reshape(b, qn, 3, num_heads, head_dim)
         q = qkv[:, :, 0]                                 # [B, Q, H, D]
-        # slice shape [B, Q, Hkv, D] (the int layer index joins the
-        # advanced group — batch dims lead); capped rows have an OOB
-        # page index and are dropped by the scatter
-        k_pages = k_pages.at[li, :, page_idx, slot, :].set(qkv[:, :, 1])
-        v_pages = v_pages.at[li, :, page_idx, slot, :].set(qkv[:, :, 2])
-        attn = pa.paged_attention(q, k_pages[li], v_pages[li],
+        k_pages = pa.paged_kv_write(k_pages, li, qkv[:, :, 1],
+                                    block_tables, seq_lens, write_caps)
+        v_pages = pa.paged_kv_write(v_pages, li, qkv[:, :, 2],
+                                    block_tables, seq_lens, write_caps)
+        attn = pa.paged_attention(q, pa.kv_layer(k_pages, li, head_dim),
+                                  pa.kv_layer(v_pages, li, head_dim),
                                   block_tables, lens_now,
                                   q_offsets=seq_lens)
         x = x + _wmm(attn.reshape(b, qn, h), blk, "out_w") \
@@ -407,14 +403,14 @@ class DraftModelDrafter(Drafter):
             _obs.WEIGHT_QUANT_SAVED_BYTES.inc(
                 saved, engine=engine._engine_id)
         n_layers = len(self._params["blocks"])
-        shape = (n_layers, self._num_heads, engine.pool.num_pages,
-                 engine._page, self._head_dim)
         # the draft cache quantizes WITH the engine (same page ids,
         # same storage dtype, its own scale arrays): the density win
         # covers both pools, and the drafter's executables follow the
         # same packed-output/donation conventions as the engine's
         self._quant = bool(engine._kv_quant)
         dtype = engine._k_pages.dtype
+        shape = (n_layers, self._num_heads, engine.pool.num_pages,
+                 engine._page, pa.kv_pool_width(self._head_dim, dtype))
         self._k_pages = jnp.zeros(shape, dtype)
         self._v_pages = jnp.zeros(shape, dtype)
         self._k_scales = self._v_scales = None

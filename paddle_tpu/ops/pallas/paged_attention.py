@@ -45,7 +45,9 @@ head) SMEM row gathered through the block table before the call, and
 multiplies the page tile by it in-register after the DMA (the Tensix/TPP in-kernel-fusion framing: no separate
 dequant materialization pass ever exists), and the XLA reference
 dequantizes the gathered pages before the identical attention math so
-the two backends stay bit-identical to each other.  The write side is
+the two backends stay bit-identical to each other.  The write side of a
+float pool is `paged_kv_write` (a page at a time, where the pool lies;
+its rows are `kv_pool_width` wide); that of an int8 pool is
 `paged_quant_write`: the serving step executables quantize every
 scattered K/V chunk in-graph (per-head absmax folded into the running
 page scale, existing page rows re-quantized when the scale grows —
@@ -125,8 +127,9 @@ def default_page_size(max_len, d, dtype=jnp.float32):
 
 
 # ---------------------------------------------------------------------------
-# Write-capped K/V scatter coordinates (shared by the serving engine's
-# mixed prefill+decode step and the speculative verify step)
+# Write-capped K/V row coordinates: the int8 twins' row scatter
+# (`paged_quant_write`) and the oracle of tests/test_paged_kv_write.py.
+# Float pools are written a page at a time, by `paged_kv_write` below.
 # ---------------------------------------------------------------------------
 def paged_write_indices(block_tables, seq_lens, write_caps, qn,
                         num_pages_total, page):
@@ -193,6 +196,91 @@ def paged_write_spans(block_tables, seq_lens, write_caps, qn,
         valid, block_tables[jnp.arange(b)[:, None], bt_idx],
         num_pages_total)
     return span.reshape(-1)
+
+
+def kv_pool_width(head_dim, dtype=jnp.float32):
+    """The minor dimension of a page pool of ``dtype``: for a float pool
+    ``head_dim`` rounded up to whole 128-lane rows (an int8 pool keeps
+    ``head_dim``: `paged_quant_write` is another algorithm, ROADMAP
+    Queue 3).  On the chip a 64-wide row fills half of a
+    128-lane tile whatever its shape says, in the layout the attention
+    kernel reads; saying so in the shape makes that layout the one the
+    pool is stored in between steps.  (Left at 64 the runtime stores the
+    pool with another dimension minor — the pages — and every step
+    executable re-lays the whole pool out on the way in and on the way
+    out; naming the layout instead of the shape does not survive the
+    persistent compile cache: PERF.md section 6, PR 27.)  Lanes
+    ``head_dim..`` hold zeros and are never read: `kv_layer` cuts them
+    off for the kernel."""
+    if jnp.dtype(dtype) == jnp.int8:
+        return head_dim
+    return -(-head_dim // _LANES) * _LANES
+
+
+def kv_layer(pool, li, head_dim):
+    """Layer ``li`` of a page pool as the attention kernel takes it:
+    ``[Hkv, P, page, head_dim]``."""
+    return pool[li, :, :, :, :head_dim]
+
+
+@jax.jit
+def paged_kv_write(pool, li, rows, block_tables, seq_lens, write_caps):
+    """In-place write of new K or V rows into layer ``li`` of a float
+    page pool: row ``i < write_caps[b]`` of sequence ``b`` lands at
+    position ``seq_lens[b] + i`` of its pages; every other row of the
+    pool keeps its bytes.
+
+    pool: [L, Hkv, P, page, W] (donated by the caller's jit; W =
+    `kv_pool_width` of D); rows: [B, Q, Hkv, D]; block_tables:
+    [B, pages_max] int32; seq_lens, write_caps: [B] int32, caps in
+    [0, Q] (0 = the slot writes nothing).
+
+    One page at a time: for each sequence and each page its run can
+    touch (`paged_write_spans`: at most ``n_span``), read the page
+    ``[Hkv, page, W]``, select the new rows in by a row mask, write the
+    page back with `lax.dynamic_update_slice`.  The update's window is a
+    whole page in the pool's own dimension order, so the pool is updated
+    where it lies — a row scatter's window is ``[Hkv, D]``, and the TPU
+    compiler re-laid the whole pool out around it (heads next to ``D``),
+    twice a layer.
+
+    `dynamic_update_slice` clamps where a scatter drops: a span entry
+    of ``P`` (inactive slot, page past the run's end) reads and writes
+    back the pool's last page unchanged, and rows past the cap keep
+    what the page held.
+
+    Jitted with ``li`` an operand: a step body's 2 x L calls trace and
+    lower the loop once and call it 2 x L times (unrolled into the
+    step's text it doubled the text, and the seconds a start spends
+    lowering it)."""
+    _, hkv, num_pages, page, width = pool.shape
+    b, qn, _, d = rows.shape
+    n_span = (qn + page - 2) // page + 1
+    spans = paged_write_spans(block_tables, seq_lens, write_caps, qn,
+                              num_pages, page)            # [B * n_span]
+    # head-major and as wide as the pool's rows, a page of padding
+    # either side: the page-aligned window of a run that starts
+    # mid-page stays in bounds
+    rows = jnp.pad(rows.transpose(0, 2, 1, 3).astype(pool.dtype),
+                   ((0, 0), (0, 0), (page, page), (0, width - d)))
+    offs = jnp.arange(page, dtype=jnp.int32)
+
+    def write_page(i, pool):
+        bi, j = i // n_span, i % n_span
+        pid = spans[i]
+        # the run's row that sits at this page's row 0 (negative: the
+        # page starts before the run does)
+        row0 = (seq_lens[bi] // page + j) * page - seq_lens[bi]
+        new = jax.lax.dynamic_slice(
+            rows, (bi, 0, row0 + page, 0), (1, hkv, page, width))
+        fresh = (pid < num_pages) & (row0 + offs >= 0) & \
+            (row0 + offs < write_caps[bi])
+        at = (li, 0, jnp.minimum(pid, num_pages - 1), 0, 0)
+        old = jax.lax.dynamic_slice(pool, at, (1, hkv, 1, page, width))
+        return jax.lax.dynamic_update_slice(
+            pool, jnp.where(fresh[:, None], new[:, :, None], old), at)
+
+    return jax.lax.fori_loop(0, b * n_span, write_page, pool)
 
 
 def paged_quant_write(pages, scales, li, vals, page_idx, slot,

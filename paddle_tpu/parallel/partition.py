@@ -170,7 +170,7 @@ def gpt_serving_rules() -> List[Tuple[str, P]]:
 
 
 def kv_pages_spec() -> P:
-    """KV page pool [L, H, n_pages, page, D]: sharded on the head axis
+    """KV page pool [L, H, n_pages, page, W]: sharded on the head axis
     — each chip holds its head-slice of EVERY page, so page ids stay
     logical and the allocator/block tables stay host-global.  Trailing
     replicated axes are TRIMMED (``P(None, 'mp')``, not the 5-element

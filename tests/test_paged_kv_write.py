@@ -1,0 +1,134 @@
+"""`pa.paged_kv_write` against the row scatter it replaced.
+
+The scatter (`pool.at[li, :, page_idx, slot, :D].set(rows)`, coordinates
+from `paged_write_indices`) is the oracle: after the write the whole pool
+is bit-equal to it, and every page the run does not name is bit-untouched.
+`dynamic_update_slice` clamps where the scatter drops, so the cases lean on
+the clamp: inactive slots, caps of 0, sentinels, and a slot whose last page
+is the pool's last page.
+"""
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from paddle_tpu.ops.pallas import paged_attention as pa
+
+L, HKV, P, PAGE, D = 3, 2, 12, 16, 8
+W = pa.kv_pool_width(D)  # the pool's rows: whole 128-lane rows
+LI = 1
+
+
+def _scatter_oracle(pool, li, rows, block_tables, seq_lens, write_caps):
+    b, qn = rows.shape[:2]
+    page_idx, slot = pa.paged_write_indices(
+        block_tables, seq_lens, write_caps, qn, pool.shape[2],
+        pool.shape[3])
+    return pool.at[li, :, page_idx, slot, :rows.shape[-1]].set(rows)
+
+
+def _case(name):
+    """(block_tables, seq_lens, write_caps, qn) of a named case; page ids
+    are distinct across slots, as the allocator hands them out."""
+    bt = np.array([[0, 1, 2, 3], [4, 5, 6, 7], [8, 9, 10, 11]], np.int32)
+    if name == "decode":                  # one row a slot
+        return bt, [5, 16, 47], [1, 1, 1], 1
+    if name == "decode_inactive":         # the middle slot sits out
+        return bt, [5, 0, 63], [1, 0, 1], 1
+    if name == "decode_all_inactive":
+        return bt, [0, 0, 0], [0, 0, 0], 1
+    if name == "decode_page_start":       # first row of a fresh page
+        return bt, [16, 32, 48], [1, 1, 1], 1
+    if name == "chunk_mid_page_crossing":  # 16 rows from row 9: two pages
+        return bt, [9, 25, 41], [16, 16, 16], 16
+    if name == "chunk_aligned":
+        return bt, [0, 16, 32], [16, 16, 16], 16
+    if name == "chunk_caps_zero_and_partial":
+        return bt, [9, 20, 30], [0, 5, 16], 16
+    if name == "chunk_one_row_among_many":  # a decode row in a mixed step
+        return bt, [9, 31, 15], [1, 1, 1], 16
+    if name == "long_run_three_pages":    # 24 rows from row 10: 3 pages
+        return bt, [10, 30, 3], [24, 24, 24], 24
+    if name == "last_page_of_the_pool":   # the clamp trap: page P-1 live
+        return bt, [50, 0, 60], [4, 0, 4], 16
+    if name == "sentinel_block_table":    # unassigned entries read P
+        bt = bt.copy()
+        bt[:, 2:] = P
+        return bt, [9, 20, 30], [16, 12, 2], 16
+    if name == "past_the_horizon":        # capped rows beyond pages_max
+        return bt, [60, 63, 56], [4, 1, 8], 16
+    if name == "prefill_one_request":     # B=1 from position 0, padded
+        return bt[:1], [0], [37], 64
+    raise KeyError(name)
+
+
+CASES = ["decode", "decode_inactive", "decode_all_inactive",
+         "decode_page_start", "chunk_mid_page_crossing", "chunk_aligned",
+         "chunk_caps_zero_and_partial", "chunk_one_row_among_many",
+         "long_run_three_pages", "last_page_of_the_pool",
+         "sentinel_block_table", "past_the_horizon", "prefill_one_request"]
+
+
+def _operands(name, seed=0):
+    bt, lens, caps, qn = _case(name)
+    rng = np.random.default_rng(seed)
+    pool = rng.standard_normal((L, HKV, P, PAGE, W)).astype(np.float32)
+    pool[..., D:] = 0.0   # lanes past D hold zeros and stay zeros
+    rows = rng.standard_normal((len(lens), qn, HKV, D)).astype(np.float32)
+    return (jnp.asarray(pool), jnp.asarray(rows), jnp.asarray(bt),
+            jnp.asarray(lens, jnp.int32), jnp.asarray(caps, jnp.int32))
+
+
+@pytest.mark.parametrize("name", CASES)
+def test_bit_equal_to_the_scatter(name):
+    pool, rows, bt, lens, caps = _operands(name)
+    want = _scatter_oracle(pool, LI, rows, bt, lens, caps)
+    got = jax.jit(pa.paged_kv_write, static_argnums=1)(
+        pool, LI, rows, bt, lens, caps)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("name", CASES)
+def test_pages_not_named_are_untouched(name):
+    pool, rows, bt, lens, caps = _operands(name, seed=1)
+    got = np.asarray(pa.paged_kv_write(pool, LI, rows, bt, lens, caps))
+    before = np.asarray(pool)
+    page = PAGE
+    named = set()
+    for b, (n0, cap) in enumerate(zip(np.asarray(lens), np.asarray(caps))):
+        for pos in range(int(n0), int(n0) + int(cap)):
+            named.add(int(np.asarray(bt)[b, pos // page]))
+    others = [p for p in range(P) if p not in named]
+    np.testing.assert_array_equal(got[:, :, others], before[:, :, others])
+    # and no other layer moved at all
+    rest = [li for li in range(L) if li != LI]
+    np.testing.assert_array_equal(got[rest], before[rest])
+    # something was written where a cap is positive
+    if int(np.asarray(caps).sum()):
+        assert not np.array_equal(got[LI], before[LI])
+
+
+def test_rows_are_cast_to_the_pool_dtype():
+    pool, rows, bt, lens, caps = _operands("chunk_mid_page_crossing")
+    pool16 = pool.astype(jnp.bfloat16)
+    got = pa.paged_kv_write(pool16, LI, rows, bt, lens, caps)
+    want = _scatter_oracle(pool16, LI, rows.astype(jnp.bfloat16), bt, lens,
+                           caps)
+    assert got.dtype == jnp.bfloat16
+    np.testing.assert_array_equal(np.asarray(got.astype(jnp.float32)),
+                                  np.asarray(want.astype(jnp.float32)))
+
+
+@pytest.mark.parametrize("head_dim, width",
+                         [(8, 128), (64, 128), (128, 128), (160, 256)])
+def test_pool_rows_are_whole_lane_rows(head_dim, width):
+    assert pa.kv_pool_width(head_dim) == width
+    assert pa.kv_pool_width(head_dim, jnp.bfloat16) == width
+    assert pa.kv_pool_width(head_dim, jnp.int8) == head_dim
+    pool = jnp.arange(2 * 3 * 4 * 16 * width, dtype=jnp.float32).reshape(
+        2, 3, 4, 16, width)
+    layer = pa.kv_layer(pool, 1, head_dim)
+    assert layer.shape == (3, 4, 16, head_dim)
+    np.testing.assert_array_equal(np.asarray(layer),
+                                  np.asarray(pool)[1, ..., :head_dim])

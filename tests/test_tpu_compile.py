@@ -186,11 +186,12 @@ def test_int8_kernel_at_the_pools_int8_exists_for(one_chip, num_pages, qn):
 # ---------------------------------------------------------------------------
 # the programs across chips: kernels inside a partitioned program
 # ---------------------------------------------------------------------------
-def _engine_param_shapes(cfg, mesh):
+def _engine_param_shapes(cfg, mesh=None, one_chip=None):
     """ShapeDtypeStructs shaped like `serving._extract_gpt_params`, sharded
-    by the serving partition rules.  (Written out, not taken from a real
-    `GPT`: building one here would cost seconds and draw random numbers
-    while `as_on_tpu` is answering for the backend.)"""
+    by the serving partition rules over ``mesh``, or all on ``one_chip``.
+    (Written out, not taken from a real `GPT`: building one here would cost
+    seconds and draw random numbers while `as_on_tpu` is answering for the
+    backend.)"""
     h, f = cfg.hidden_size, cfg.intermediate_size
     block = {"ln1_w": (h,), "ln1_b": (h,), "ln2_w": (h,), "ln2_b": (h,),
              "qkv_w": (h, 3 * h), "qkv_b": (3 * h,), "out_w": (h, h),
@@ -200,6 +201,11 @@ def _engine_param_shapes(cfg, mesh):
               "lnf_w": (h,), "lnf_b": (h,),
               "blocks": [dict(block) for _ in range(cfg.num_layers)]}
     is_shape = lambda x: isinstance(x, tuple)  # noqa: E731
+    if mesh is None:
+        return jax.tree_util.tree_map(
+            lambda s: jax.ShapeDtypeStruct(s, jnp.float32,
+                                           sharding=one_chip),
+            shapes, is_leaf=is_shape)
     specs = partition.match_partition_rules(
         partition.gpt_serving_rules(),
         jax.tree_util.tree_map(lambda s: np.zeros(s, np.float32), shapes,
@@ -208,6 +214,108 @@ def _engine_param_shapes(cfg, mesh):
         lambda s, spec: jax.ShapeDtypeStruct(
             s, jnp.float32, sharding=NamedSharding(mesh, spec)),
         shapes, specs, is_leaf=is_shape)
+
+
+# ---------------------------------------------------------------------------
+# the serve cell's two step executables: K/V written where the pool lies
+# ---------------------------------------------------------------------------
+CELL = dict(slots=16, num_pages=256)  # benchmarks/workloads/gpt2s-serve-chat
+# opcodes that may hold a layer of the pool or more: the pool passing
+# through, the page write, the per-layer slice for the kernel
+_POOL_OPS = {"parameter", "get-tuple-element", "tuple", "while", "bitcast",
+             "slice", "dynamic-slice", "dynamic-update-slice"}
+
+
+def _serve_step(one_chip, which, num_pages):
+    """`_gpt_decode_step` / `_gpt_mixed_step` compiled as the engine runs
+    them: full width and depth, the pools as the engine makes them
+    (rows `PA.kv_pool_width` wide), donated."""
+    cfg = GPTConfig(use_parallel_layers=False, **BASE)
+    page = PA.default_page_size(MAX_LEN, HEAD_DIM, jnp.float32)
+    slots = CELL["slots"]
+
+    def S(shape, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    pool = S((cfg.num_layers, HEADS, num_pages, page,
+              PA.kv_pool_width(HEAD_DIM)), jnp.float32)
+    head = (_engine_param_shapes(cfg, one_chip=one_chip), pool, pool,
+            S((slots, MAX_LEN // page)), S((slots,)))
+    if which == "decode":
+        fn = serving._gpt_decode_step
+        tail = (S((slots,)), S((slots,), jnp.bool_))
+    else:
+        fn = serving._gpt_mixed_step
+        tail = (S((slots, Q_MAX)), S((slots,)), S((slots,)),
+                S((slots,), jnp.bool_))
+    step = functools.partial(
+        fn, num_heads=HEADS, head_dim=HEAD_DIM, eps=1e-5, sampler="greedy",
+        temperature=1.0, top_k=0, top_p=1.0)
+    return _compile(step, *head, *tail, S((2,), jnp.uint32),
+                    donate_argnums=(1, 2))
+
+
+def _pool_sized_faults(text, num_pages, layer_elems):
+    """Instructions of a compiled step that hold a layer of the pool or
+    more (whole layers' worth of elements, the pages among the dimensions,
+    in whatever order) and are not the pool passing through, the in-place
+    page write or the per-layer slice: a copy, a transpose, a fusion of
+    another kind, or any array in another layout than its dimension
+    order."""
+    faults = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = f32\[([\d,]+)\]"
+                     r"\{([\d,]+)[^}]*\} ([\w\-]+)\(", line)
+        if m is None:
+            continue
+        name, dims, layout, op = m.groups()
+        dims = [int(d) for d in dims.split(",")]
+        if num_pages not in dims or int(np.prod(dims)) % layer_elems:
+            continue
+        in_order = layout == ",".join(
+            str(i) for i in reversed(range(len(dims))))
+        if op == "fusion":  # named for what it fuses, or traced to it
+            ok = "slice" in name or "dynamic_update_slice" in line
+        else:
+            ok = op in _POOL_OPS
+        if not (ok and in_order):
+            faults.append(line.strip()[:160])
+    return faults
+
+
+@pytest.mark.parametrize("which", ["decode", "mixed"])
+def test_serve_step_writes_kv_where_the_pool_lies(one_chip, as_on_tpu, which):
+    """The cell's shapes: 12 x 768, 16 slots, 256 pages of 64, `Q_MAX` 64.
+    With the row scatter into 64-wide rows the compiler re-laid the
+    1.21 GB pool out around every write, and on the way in and out of the
+    step (temp 7.37 GB on the chip, PR 24); with whole pages written into
+    lane-wide rows nothing pool-sized is left but the write and the
+    per-layer slice."""
+    from benchmarks.kernels import paged_attention as bench_pa
+
+    compiled = _serve_step(one_chip, which, CELL["num_pages"])
+    text = compiled.as_text()
+    # the kernel, under its name, with the operand the benchmark's
+    # `classify` finds it by: one layer of the pool, [12, 256, 64, 64]
+    assert _has_kernel(compiled, "paged_attention")
+    assert any(bench_pa.classify(line, HEADS, CELL["num_pages"], HEAD_DIM)
+               for line in text.splitlines())
+    page = PA.default_page_size(MAX_LEN, HEAD_DIM, jnp.float32)
+    layer = HEADS * CELL["num_pages"] * page * HEAD_DIM
+    assert _pool_sized_faults(text, CELL["num_pages"], layer) == []
+    kv_bytes = 2 * BASE["num_layers"] * layer * 4    # 1.21 GB of K/V
+    assert compiled.memory_analysis().temp_size_in_bytes < kv_bytes
+
+
+def test_decode_step_at_four_times_the_pool(one_chip, as_on_tpu):
+    """1024 pages: 4.8 GB of f32 K/V, 9.7 GB as the kernel reads it and
+    as the pool holds it (a 64-wide row fills half of a 128-lane row).
+    PR 21's refusal was at 2048 pages, which is 19.3 GB in the kernel's
+    layout: more than the chip has, whatever the step does.  Here the
+    step adds a tenth of the pool."""
+    compiled = _serve_step(one_chip, "decode", 1024)
+    ma = compiled.memory_analysis()
+    assert ma.temp_size_in_bytes < ma.argument_size_in_bytes // 4
 
 
 def test_sharded_serving_step_holds_the_kernel(topo, as_on_tpu):
@@ -225,7 +333,8 @@ def test_sharded_serving_step_holds_the_kernel(topo, as_on_tpu):
                                     sharding=NamedSharding(mesh, P()))
 
     pages = jax.ShapeDtypeStruct(
-        (cfg.num_layers, HEADS, num_pages, page, HEAD_DIM), jnp.float32,
+        (cfg.num_layers, HEADS, num_pages, page,
+         PA.kv_pool_width(HEAD_DIM)), jnp.float32,
         sharding=NamedSharding(mesh, partition.kv_pages_spec()))
     step = functools.partial(
         serving._gpt_ragged_step, num_heads=HEADS, head_dim=HEAD_DIM,
