@@ -10,6 +10,29 @@ recomputing P per tile from the saved logsumexp — no S^2 tensor ever hits
 HBM), matching the memory behaviour the flash-attention algorithm promises.
 Falls back to the XLA composed form when shapes don't tile or a dense mask
 is supplied.
+
+Precision: every matrix product takes its operands in the dtype the caller
+passed and accumulates in float32 — bf16 q, k, v, dO (the train cells under
+autocast) give bf16 MXU products, float32 operands float32 ones
+(`vmatmul.f32`), from the same code.  P is rounded to v's dtype before P V
+and P^T dO, dS to q's before dS^T Q and dS K (what `_composed_attention`
+does with its probabilities); the scale multiplies the float32 logits and
+the float32 dk/dq accumulators, never an operand.  The softmax itself
+(logits, mask, running max and sum, exp, delta, dS, log-sum-exp) is
+float32: the v5e has no bf16 vector or transcendental unit.
+
+Where the time goes (v5e, the compiler's bundle dumps and the chip, PR 30;
+PERF.md section 6): the matrix unit, which takes one LHS row a cycle
+whatever the operand dtype, and heads of 64 fill half of its 128 x 128 when
+they are a product's contraction or its output width.  So the kernels (a)
+do not compute what the mask removes: loops inside each kernel walk a grid
+step's tile in `_SUB_Q` x `_SUB_K` sub-tiles and skip those above the
+causal diagonal, so that one grid step can span the whole sequence; and
+(b) put the 64 on the unit's streaming side wherever a product allows it:
+O^T = V^T P^T, dV^T = dO^T P, dK^T = Q^T dS, dQ^T = K^T dS^T, accumulated
+transposed and turned back once a grid step (the forward builds its scores
+transposed for it, which also makes its statistics rows).  Q K^T and
+dO V^T contract over the 64 and stay half full.
 """
 from __future__ import annotations
 
@@ -61,64 +84,114 @@ def _xla_reference(q, k, v, mask, is_causal, scale):
 # ---------------------------------------------------------------------------
 
 
-def _fwd_kernel_resident(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k,
-                         seq_k, scale, causal, block_q):
-    # grid: (batch*heads, num_q_blocks); whole K/V for the head resident
-    # in VMEM, looped over in block_k slices.  Fastest form (no acc
-    # scratch traffic, K block count can be clipped under the causal
-    # mask), used while 2*seq_k*d fits the VMEM budget; the streaming
-    # kernel below takes over beyond it.
-    q = q_ref[...].astype(jnp.float32) * scale  # [block_q, d]
-    m = jnp.full((block_q,), -1e30, jnp.float32)
-    l = jnp.zeros((block_q,), jnp.float32)
-    acc = jnp.zeros((block_q, q.shape[-1]), jnp.float32)
-
-    qi = pl.program_id(1)
-    q_pos = qi * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, 1), 0)[:, 0]
-
-    num_k = seq_k // block_k
-    if causal:
-        # Only K blocks intersecting the lower triangle contribute.
-        num_k = jnp.minimum(num_k,
-                            ((qi + 1) * block_q + block_k - 1) // block_k)
-
-    def body(j, carry):
-        m, l, acc = carry
-        k_blk = k_ref[pl.dslice(j * block_k, block_k), :].astype(jnp.float32)
-        v_blk = v_ref[pl.dslice(j * block_k, block_k), :].astype(jnp.float32)
-        logits = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # [block_q, block_k]
-        if causal:
-            k_pos = j * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (1, block_k), 1)[0]
-            mask = q_pos[:, None] >= k_pos[None, :]
-            logits = jnp.where(mask, logits, -1e30)
-        m_blk = jnp.max(logits, axis=-1)
-        m_new = jnp.maximum(m, m_blk)
-        p = jnp.exp(logits - m_new[:, None])
-        alpha = jnp.exp(m - m_new)
-        l = l * alpha + jnp.sum(p, axis=-1)
-        acc = acc * alpha[:, None] + jax.lax.dot_general(
-            p, v_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        return m_new, l, acc
-
-    m, l, acc = jax.lax.fori_loop(0, num_k, body, (m, l, acc))
-    o_ref[...] = (acc / jnp.maximum(l, 1e-30)[:, None]).astype(o_ref.dtype)
-    lse = m + jnp.log(jnp.maximum(l, 1e-30))
-    lse_ref[...] = jnp.broadcast_to(lse[:, None], (block_q, _LANES))
+_NT = (((1,), (1,)), ((), ()))   # a @ b.T
+_TN = (((0,), (0,)), ((), ()))   # a.T @ b
+_TT = (((0,), (1,)), ((), ()))   # a.T @ b.T
 
 
-# Causal dead-block fetch clamps, shared by the streaming forward and both
-# backward kernels.  A tile wholly above the causal diagonal contributes
-# nothing: compute there is pl.when-gated off in the kernels, and these
-# index maps additionally skip the DMA by clamping the streamed block
-# index to the live range (Pallas skips re-fetch when the index repeats).
-# Keep the formulas in sync with the kernels' `live` predicates.
+def _dot(a, b, dims):
+    """One MXU product: the operands as they are, a float32 result."""
+    return jax.lax.dot_general(a, b, dims,
+                               preferred_element_type=jnp.float32)
+
+
+# The (block_q, block_k) tile a grid step holds in VMEM is worked through
+# in [_SUB_Q, _SUB_K] sub-tiles by loops inside the kernel, so the causal
+# skip engages per sub-tile at no grid-step cost (~0.35 us a step, and the
+# accumulators' init and flush) and the blocks can be as large as the
+# sequence.  512 x 512 keeps the matrix unit busiest: smaller sub-tiles
+# skip more (10 of 16 at 256 against 3 of 4) and lose more than that to
+# the phases of a sub-tile not overlapping (per 256 x 256 of scores, in
+# bundles of the compiler's schedule: forward 289 / dk,dv 409 / dq 320 at
+# 512, 427 / 579 / 522 at 256; on the chip the three kernels take 2.14 ms
+# at 512 and 3.20 ms at 256, 16 x 12 heads of 1024: PR 30, PERF.md
+# section 6).
+_SUB_Q = 512
+_SUB_K = 512
+
+
+def _mask_causal(logits, q_first, k_first, q_axis):
+    """A sub-tile's logits with the keys after each query masked out; the
+    queries run along ``q_axis`` from position ``q_first``, the keys along
+    the other axis from ``k_first``.  (Masking only the sub-tiles that
+    cross the diagonal was tried: the matrix unit holds these kernels, not
+    the vector unit, and a second, unmasked loop body bought 3-5% of a
+    third of the sub-tiles for twice the program: PERF.md section 6,
+    PR 30.)"""
+    def positions(first, axis):
+        shape = [1, 1]
+        shape[axis] = logits.shape[axis]
+        return first + jax.lax.broadcasted_iota(jnp.int32, shape, axis)
+
+    return jnp.where(positions(q_first, q_axis)
+                     >= positions(k_first, 1 - q_axis), logits, -1e30)
+
+
+def _loop(lo, hi, fn):
+    """``fn(i)`` for i in [lo, hi): a loop with no carry (the kernels'
+    state lives in VMEM refs)."""
+    def body(i, carry):
+        fn(i)
+        return carry
+
+    jax.lax.fori_loop(lo, hi, body, 0)
+
+
+def _for_live_subtiles(causal, qi, kj, block_q, block_k, tile,
+                       k_outer=False):
+    """Work through grid tile (q block ``qi``, K block ``kj``) in
+    sub-tiles: ``tile(rows, cols, q_first, k_first)`` gets a sub-tile's
+    index into the q-side and K-side refs and its first query / key
+    position.  Full attention runs them all; under the causal mask all but
+    those wholly above the diagonal (last q_pos < first k_pos).  The q
+    sub-blocks are the outer loop, or with ``k_outer`` the K sub-blocks
+    (the dk/dv kernel, whose accumulators belong to a K sub-block)."""
+    # a sub-tile's edge: _SUB_* where it divides the block, else the block
+    sub_q = _SUB_Q if block_q % _SUB_Q == 0 else block_q
+    sub_k = _SUB_K if block_k % _SUB_K == 0 else block_k
+    nq, nk = block_q // sub_q, block_k // sub_k
+    q0, k0 = qi * block_q, kj * block_k
+
+    def index(i, n, total):
+        # the whole block, statically, where it is one sub-block
+        return slice(None) if n == total else pl.ds(
+            pl.multiple_of(i * n, n), n)
+
+    def run(r, c):
+        tile(index(r, sub_q, block_q), index(c, sub_k, block_k),
+             q0 + r * sub_q, k0 + c * sub_k)
+
+    if k_outer:
+        def per_k(c):
+            first = 0
+            if causal:      # the first q sub-block whose last row sees c
+                first = jnp.minimum(jnp.maximum(
+                    k0 + c * sub_k - q0 + sub_q, sub_q) // sub_q - 1, nq)
+            _loop(first, nq, lambda r: run(r, c))
+        _loop(0, nk, per_k)
+    else:
+        def per_q(r):
+            live = nk
+            if causal:      # the K sub-blocks r's last row sees
+                live = jnp.minimum(jnp.maximum(
+                    q0 + (r + 1) * sub_q - 1 - k0 + sub_k, 0) // sub_k, nk)
+            _loop(0, live, lambda c: run(r, c))
+        _loop(0, nq, per_q)
+
+
+def _lanes(x, n):
+    """A lane-replicated [rows, 128] statistic as [rows, n]: whole vregs
+    repeated where n is a multiple of 128 (no lane broadcast)."""
+    if n % _LANES:
+        return x[:, :1]
+    return x if n == _LANES else pltpu.repeat(x, n // _LANES, axis=1)
+
+
+# Causal dead-block fetch clamps, shared by the forward and both backward
+# kernels.  A tile wholly above the causal diagonal contributes nothing:
+# `_for_live_subtiles` finds no live sub-tile there, and these index maps
+# additionally skip the DMA by clamping the streamed block index to the
+# live range (Pallas skips re-fetch when the index repeats).
 def _stream_idx(i, j, r):
     return (i, r, 0)
 
@@ -140,20 +213,26 @@ def _causal_q_clamp(block_q, block_k):
 
 
 # VMEM budget for holding a head's full K+V resident in the forward
-# kernel (the scoped limit on this toolchain is 16MB; leave room for the
-# q/o blocks and pipelining buffers).  Measured: resident beats streaming
-# by 5-20% where it fits (S<=8192 at d=64), so both kernels are kept.
+# kernel: one K block spans the sequence, so a (batch*head, q block) pair
+# is one grid step however long the sequence (the scoped limit on this
+# toolchain is 16MB; leave room for the q/o blocks and pipelining
+# buffers).  Beyond it K/V stream in block_k pieces.
 _RESIDENT_KV_BYTES = 6 * 1024 * 1024
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_scr, l_scr, *,
-                block_k, seq_k, scale, causal, block_q):
-    # grid (bh, num_q, num_k): K/V blocks STREAM through VMEM (k is the
-    # fastest grid dim) while the (bh, q)-pinned output block and the f32
-    # scratch accumulators (acc / running max / running sum) stay resident
-    # — constant VMEM at any sequence length, same scheme as the backward
-    # kernels (the earlier all-of-K/V-resident form hit the 16MB scoped
-    # VMEM limit around S=16k at d=128 bf16; advisor round-2 finding).
+                block_q, block_k, seq_k, scale, causal):
+    # grid (bh, num_q, num_k): K/V blocks stream through VMEM (k is the
+    # fastest grid dim; one block when K/V are resident) while the
+    # (bh, q)-pinned output block and the f32 scratch accumulators stay
+    # put — constant VMEM at any sequence length, same scheme as the
+    # backward kernels.  The scores are built TRANSPOSED, [keys, queries]:
+    # the running max and sum are then rows (lanes are queries; a
+    # reduction runs down the sublanes on the vector unit, a rescale is a
+    # sublane broadcast), and O^T = V^T P^T puts the head's 64 on the
+    # matrix unit's streaming side, where it wastes nothing, with P^T the
+    # stationary operand as it lies.  acc is O^T [d, block_q]; m and l are
+    # [8, block_q], sublanes equal.  The flush transposes back.
     qi = pl.program_id(1)
     kj = pl.program_id(2)
     num_k = seq_k // block_k
@@ -164,47 +243,29 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_scr, l_scr, *,
         m_scr[...] = jnp.full_like(m_scr, -1e30)
         l_scr[...] = jnp.zeros_like(l_scr)
 
-    # causal: a K block entirely above the diagonal contributes nothing
-    live = ((qi + 1) * block_q - 1 >= kj * block_k) if causal else True
-
-    @pl.when(live)
-    def _compute():
-        q = q_ref[...].astype(jnp.float32) * scale  # [block_q, d]
-        k_blk = k_ref[...].astype(jnp.float32)      # [block_k, d]
-        v_blk = v_ref[...].astype(jnp.float32)
-        m = m_scr[...][:, 0]
-        l = l_scr[...][:, 0]
-        logits = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # [block_q, block_k]
+    def tile(rows, cols, q_first, k_first):
+        v_sub = v_ref[cols, :]                      # [sub_k, d]
+        logits = _dot(k_ref[cols, :], q_ref[rows, :], _NT) * scale
         if causal:
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, 1), 0)[:, 0]
-            k_pos = kj * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (1, block_k), 1)[0]
-            mask = q_pos[:, None] >= k_pos[None, :]
-            logits = jnp.where(mask, logits, -1e30)
-        m_blk = jnp.max(logits, axis=-1)
-        m_new = jnp.maximum(m, m_blk)
-        p = jnp.exp(logits - m_new[:, None])
+            logits = _mask_causal(logits, q_first, k_first, q_axis=1)
+        m = m_scr[:, rows]                          # [8, sub_q]
+        m_new = jnp.maximum(m, jnp.max(logits, axis=0, keepdims=True))
+        p = jnp.exp(logits - m_new[:1, :])
         alpha = jnp.exp(m - m_new)
-        l_new = l * alpha + jnp.sum(p, axis=-1)
-        acc[...] = acc[...] * alpha[:, None] + jax.lax.dot_general(
-            p, v_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m_scr[...] = jnp.broadcast_to(m_new[:, None], m_scr.shape)
-        l_scr[...] = jnp.broadcast_to(l_new[:, None], l_scr.shape)
+        l_scr[:, rows] = l_scr[:, rows] * alpha + jnp.sum(
+            p, axis=0, keepdims=True)
+        m_scr[:, rows] = m_new
+        acc[:, rows] = acc[:, rows] * alpha[:1, :] + _dot(
+            v_sub, p.astype(v_sub.dtype), _TN)
+
+    _for_live_subtiles(causal, qi, kj, block_q, block_k, tile)
 
     @pl.when(kj == num_k - 1)
     def _flush():
-        m = m_scr[...][:, 0]
-        l = l_scr[...][:, 0]
-        o_ref[...] = (acc[...] / jnp.maximum(l, 1e-30)[:, None]
-                      ).astype(o_ref.dtype)
-        lse = m + jnp.log(jnp.maximum(l, 1e-30))
-        lse_ref[...] = jnp.broadcast_to(lse[:, None], (block_q, _LANES))
+        l = jnp.maximum(l_scr[...], 1e-30)          # [8, block_q]
+        o_ref[...] = (acc[...] / l[:1, :]).T.astype(o_ref.dtype)
+        lse = m_scr[...] + jnp.log(l)
+        lse_ref[...] = jnp.broadcast_to(lse[:1, :], (_LANES, block_q)).T
 
 
 def _out_struct(shape, dtype, *like):
@@ -216,6 +277,10 @@ def _out_struct(shape, dtype, *like):
     return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
 
 
+# The two entry points below are jitted so that a model's layers share one
+# traced and lowered copy of each kernel: tracing the kernel bodies at
+# every call site cost a train step's set-up 2-4 s (24 layers; PR 30).
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6))
 def _pallas_forward(q, k, v, is_causal, scale, block_q, block_k):
     """Returns (out [B,H,Sq,D], lse [B*H, Sq] fp32)."""
     b, h, sq, d = q.shape
@@ -229,26 +294,7 @@ def _pallas_forward(q, k, v, is_causal, scale, block_q, block_k):
     vr = v.reshape(b * h, sk, d)
 
     if 2 * sk * d * q.dtype.itemsize <= _RESIDENT_KV_BYTES:
-        out, lse = pl.pallas_call(
-            functools.partial(
-                _fwd_kernel_resident, block_k=block_k, seq_k=sk, scale=s,
-                causal=is_causal, block_q=block_q),
-            grid=(b * h, sq // block_q),
-            in_specs=[
-                pl.BlockSpec((None, block_q, d), lambda i, j: (i, j, 0)),
-                pl.BlockSpec((None, sk, d), lambda i, j: (i, 0, 0)),
-                pl.BlockSpec((None, sk, d), lambda i, j: (i, 0, 0)),
-            ],
-            out_specs=[
-                pl.BlockSpec((None, block_q, d), lambda i, j: (i, j, 0)),
-                pl.BlockSpec((None, block_q, _LANES),
-                             lambda i, j: (i, j, 0)),
-            ],
-            out_shape=out_shape,
-            name="flash_attention_fwd",
-        )(qr, kr, vr)
-        return out.reshape(b, h, sq, d), lse[:, :, 0]
-
+        block_k = sk    # K/V resident: the kernel's loops do the skipping
     kernel = functools.partial(
         _fwd_kernel, block_k=block_k, seq_k=sk, scale=s, causal=is_causal,
         block_q=block_q,
@@ -269,9 +315,9 @@ def _pallas_forward(q, k, v, is_causal, scale, block_q, block_k):
                          lambda i, j, r: (i, j, 0)),
         ],
         out_shape=out_shape,
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32),
-                        pltpu.VMEM((block_q, _LANES), jnp.float32),
-                        pltpu.VMEM((block_q, _LANES), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((d, block_q), jnp.float32),
+                        pltpu.VMEM((8, block_q), jnp.float32),
+                        pltpu.VMEM((8, block_q), jnp.float32)],
         name="flash_attention_fwd",
     )(qr, kr, vr)
     return out.reshape(b, h, sq, d), lse[:, :, 0]
@@ -284,9 +330,13 @@ def _pallas_forward(q, k, v, is_causal, scale, block_q, block_k):
 # Standard flash-attention backward split into two kernels so each output
 # tile has a single writer:
 #   dkv kernel: grid over K blocks, loops over Q blocks, accumulates
-#               dV = P^T dO and dK = dS^T (Q*scale)
+#               dV^T = dO^T P and dK^T = scale * Q^T dS
 #   dq  kernel: grid over Q blocks, loops over K blocks, accumulates
-#               dQ = scale * dS K
+#               dQ^T = scale * K^T dS^T
+# (transposed, [d, rows]: the head's 64 is then the matrix unit's
+# streaming side and P / dS its stationary operand, half the passes of
+# P^T dO with 64 of 128 output columns used; the flush transposes back and
+# applies the scale to the float32 accumulator)
 # with P recomputed per tile from the saved logsumexp and
 # dS = P * (dP - delta).  delta = rowsum(dO * O) is computed in-kernel
 # from the saved O (cheap VPU reduce) rather than precomputed — passing O
@@ -295,6 +345,21 @@ def _pallas_forward(q, k, v, is_causal, scale, block_q, block_k):
 # (upstream jax's flash kernel convention); the compact
 # (sq//128, 128)-packed alternative needs a cross-lane reshape in-kernel,
 # which Mosaic fails to lower.
+
+
+def _p_and_ds(q_sub, k_sub, v_sub, do_sub, o_sub, lse, scale, q_first,
+              k_first, causal):
+    """One sub-tile of the backward pass, float32: the probabilities
+    rebuilt from the saved log-sum-exp (``lse``: lane-replicated
+    [rows, 128]) and dS = P * (dP - delta)."""
+    logits = _dot(q_sub, k_sub, _NT) * scale
+    if causal:
+        logits = _mask_causal(logits, q_first, k_first, q_axis=0)
+    p = jnp.exp(logits - _lanes(lse, k_sub.shape[0]))
+    delta = jnp.sum(do_sub.astype(jnp.float32) * o_sub.astype(jnp.float32),
+                    axis=-1, keepdims=True)
+    dp = _dot(do_sub, v_sub, _NT)
+    return p, p * (dp - delta)
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
@@ -313,49 +378,21 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
         acc_dk[...] = jnp.zeros_like(acc_dk)
         acc_dv[...] = jnp.zeros_like(acc_dv)
 
-    # causal: a tile entirely above the diagonal contributes nothing —
-    # skip its matmuls (max q_pos < min k_pos)
-    live = ((qj + 1) * block_q - 1 >= ki * block_k) if causal else True
+    def tile(rows, cols, q_first, k_first):
+        q_sub = q_ref[rows, :]                          # [sub_q, d]
+        do_sub = do_ref[rows, :]
+        p, ds = _p_and_ds(
+            q_sub, k_ref[cols, :], v_ref[cols, :], do_sub, o_ref[rows, :],
+            lse_ref[rows, :], scale, q_first, k_first, causal)
+        acc_dv[:, cols] += _dot(do_sub, p.astype(do_sub.dtype), _TN)
+        acc_dk[:, cols] += _dot(q_sub, ds.astype(q_sub.dtype), _TN)
 
-    @pl.when(live)
-    def _compute():
-        k_blk = k_ref[...].astype(jnp.float32)          # [block_k, d]
-        v_blk = v_ref[...].astype(jnp.float32)
-        k_pos = ki * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (1, block_k), 1)[0]
-        q_blk = q_ref[...].astype(jnp.float32) * scale  # [block_q, d]
-        do_blk = do_ref[...].astype(jnp.float32)
-        o_blk = o_ref[...].astype(jnp.float32)
-        lse = lse_ref[...][:, 0]
-        delta = jnp.sum(do_blk * o_blk, axis=-1)
-        logits = jax.lax.dot_general(
-            q_blk, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # [block_q, block_k]
-        if causal:
-            q_pos = qj * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, 1), 0)[:, 0]
-            mask = q_pos[:, None] >= k_pos[None, :]
-            logits = jnp.where(mask, logits, -1e30)
-        p = jnp.exp(logits - lse[:, None])           # [block_q, block_k]
-        acc_dv[...] += jax.lax.dot_general(
-            p, do_blk, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        dp = jax.lax.dot_general(
-            do_blk, v_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = p * (dp - delta[:, None])
-        acc_dk[...] += jax.lax.dot_general(
-            ds, q_blk, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+    _for_live_subtiles(causal, qj, ki, block_q, block_k, tile, k_outer=True)
 
     @pl.when(qj == num_q - 1)
     def _flush():
-        dk_ref[...] = acc_dk[...].astype(dk_ref.dtype)
-        dv_ref[...] = acc_dv[...].astype(dv_ref.dtype)
+        dk_ref[...] = (acc_dk[...].T * scale).astype(dk_ref.dtype)
+        dv_ref[...] = acc_dv[...].T.astype(dv_ref.dtype)
 
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
@@ -371,43 +408,22 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
     def _init():
         acc_dq[...] = jnp.zeros_like(acc_dq)
 
-    live = ((qi + 1) * block_q - 1 >= kj * block_k) if causal else True
+    def tile(rows, cols, q_first, k_first):
+        k_sub = k_ref[cols, :]                          # [sub_k, d]
+        _, ds = _p_and_ds(
+            q_ref[rows, :], k_sub, v_ref[cols, :], do_ref[rows, :],
+            o_ref[rows, :], lse_ref[rows, :], scale, q_first, k_first,
+            causal)
+        acc_dq[:, rows] += _dot(k_sub, ds.astype(k_sub.dtype), _TT)
 
-    @pl.when(live)
-    def _compute():
-        q_blk = q_ref[...].astype(jnp.float32) * scale   # [block_q, d]
-        do_blk = do_ref[...].astype(jnp.float32)
-        lse = lse_ref[...][:, 0]
-        delta = jnp.sum(do_blk * o_ref[...].astype(jnp.float32), axis=-1)
-        q_pos = qi * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, 1), 0)[:, 0]
-        k_blk = k_ref[...].astype(jnp.float32)
-        v_blk = v_ref[...].astype(jnp.float32)
-        logits = jax.lax.dot_general(
-            q_blk, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        if causal:
-            k_pos = kj * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (1, block_k), 1)[0]
-            mask = q_pos[:, None] >= k_pos[None, :]
-            logits = jnp.where(mask, logits, -1e30)
-        p = jnp.exp(logits - lse[:, None])
-        dp = jax.lax.dot_general(
-            do_blk, v_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = p * (dp - delta[:, None])
-        acc_dq[...] += jax.lax.dot_general(
-            ds, k_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+    _for_live_subtiles(causal, qi, kj, block_q, block_k, tile)
 
     @pl.when(kj == num_k - 1)
     def _flush():
-        dq_ref[...] = (acc_dq[...] * scale).astype(dq_ref.dtype)
+        dq_ref[...] = (acc_dq[...].T * scale).astype(dq_ref.dtype)
 
 
+@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9))
 def _pallas_backward(q, k, v, out, lse, g, is_causal, scale, block_q,
                      block_k):
     b, h, sq, d = q.shape
@@ -443,8 +459,8 @@ def _pallas_backward(q, k, v, out, lse, g, is_causal, scale, block_q,
             _out_struct((b * h, sk, d), k.dtype, q, k, v, g),
             _out_struct((b * h, sk, d), v.dtype, q, k, v, g),
         ],
-        scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                        pltpu.VMEM((block_k, d), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((d, block_k), jnp.float32),
+                        pltpu.VMEM((d, block_k), jnp.float32)],
         name="flash_attention_bwd_dkv",
     )(qr, kr, vr, dor, outr, lse_b)
 
@@ -466,7 +482,7 @@ def _pallas_backward(q, k, v, out, lse, g, is_causal, scale, block_q,
         out_specs=pl.BlockSpec((None, block_q, d),
                                lambda i, j, r: (i, j, 0)),
         out_shape=_out_struct((b * h, sq, d), q.dtype, q, k, v, g),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((d, block_q), jnp.float32)],
         name="flash_attention_bwd_dq",
     )(qr, kr, vr, dor, outr, lse_b)
 
@@ -504,11 +520,11 @@ def flash_attention_fwd(q, k, v, mask=None, is_causal=False, scale=None,
     """q,k,v: [B,H,S,D].  Uses the Pallas kernels when mask is None and shapes
     tile; otherwise the XLA composed reference.  Fully differentiable with a
     Pallas backward (dq/dk/dv kernels recomputing P from the saved
-    logsumexp).  Block sizes: explicit arguments win; otherwise the
-    per-shape measured winners from flash_autotune_cache.json (written
-    by tools/bench_kernels.py — deep-K blocks like 512x1024 win past
-    S=1024), falling back to 512x512 shrunk by `pick_blocks` for
-    sequences they don't divide.
+    logsumexp).  Block sizes (what one grid step holds; the kernels work
+    through it in sub-tiles): explicit arguments win; otherwise the
+    per-shape measured winners from flash_autotune_cache.json, falling
+    back to 512x512 shrunk by `pick_blocks` for sequences they don't
+    divide.
 
     Causal cross-length attention (seq_q != seq_k) always takes the XLA
     reference: its causal mask is bottom-right aligned (tril offset
@@ -560,12 +576,14 @@ def pick_blocks(seq_q: int, seq_k: int, block_q: int = 512,
     return block_q, block_k
 
 
-# -- measured block-size cache (round-5 VERDICT #6) -------------------------
-# tools/bench_kernels.py sweeps (block_q, block_k) per
-# (seq_q, seq_k, d, dtype, causal) on the live chip and commits the
-# winners here; the entry point prefers a cached winner over the
-# divisibility default when the caller left the blocks at their
-# defaults.  Re-run the bench after kernel changes.
+# -- measured block-size cache ----------------------------------------------
+# (block_q, block_k) per (seq_q, seq_k, d, dtype, causal), measured on the
+# chip as the device time of the three kernels through `_flash_diff`
+# (PR 30's sweep, PERF.md section 6; tools/bench_kernels.py times the same
+# call by the wall clock and writes the file too).  The entry point
+# prefers a cached winner over the divisibility default when the caller
+# left the blocks at their defaults.  A cache measured on other kernels is
+# not a measurement: measure again after a change to the kernels.
 _AUTOTUNE_FILE = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                               "flash_autotune_cache.json")
 _AUTOTUNE: dict = {}

@@ -109,6 +109,156 @@ class TestFlashAttention:
                                        atol=2e-2)
 
 
+    # ---- operands in their own dtype: bf16 products, f32 accumulation ----
+    # Tolerances for bf16 operands against `_composed_attention` on the
+    # SAME bf16 inputs (which rounds its probabilities to bf16 too): both
+    # sides round their results to bf16, whose ulp is 2**-8 relative —
+    # 0.016 at the outputs' magnitude (up to ~4 under the causal mask's
+    # first rows), 0.03 at the gradients' (up to ~5).  Measured over three
+    # seeds, every block pair below: out <= 0.016, gradients <= 0.031.
+    BF16_OUT_ATOL = 2e-2
+    BF16_GRAD_ATOL = 5e-2
+
+    @staticmethod
+    def _f32(x):
+        return np.asarray(x.astype(jnp.float32))
+
+    @pytest.mark.parametrize("forward", ["resident", "streaming"])
+    @pytest.mark.parametrize("causal", [False, True])
+    def test_bf16_forward_matches_composed(self, interpret_pallas,
+                                           monkeypatch, causal, forward):
+        if forward == "streaming":
+            monkeypatch.setattr(FA, "_RESIDENT_KV_BYTES", 0)
+        q, k, v, _ = self._inputs(4, dtype=jnp.bfloat16)
+        out, lse = FA._pallas_forward(q, k, v, causal, None, 128, 64)
+        ref, ref_lse = FA._composed_attention(q, k, v, None, causal, None,
+                                              want_lse=True)
+        assert out.dtype == jnp.bfloat16 and lse.dtype == jnp.float32
+        np.testing.assert_allclose(self._f32(out), self._f32(ref),
+                                   atol=self.BF16_OUT_ATOL)
+        # the kernel's log-sum-exp comes from float32 logits; the
+        # composed form rounds its logits to bf16 first (measured: 3e-3)
+        np.testing.assert_allclose(np.asarray(lse).reshape(ref_lse.shape),
+                                   np.asarray(ref_lse), atol=2e-2)
+
+    @pytest.mark.parametrize("forward", ["resident", "streaming"])
+    @pytest.mark.parametrize("causal", [False, True])
+    def test_bf16_backward_matches_composed(self, interpret_pallas,
+                                            monkeypatch, causal, forward):
+        if forward == "streaming":
+            monkeypatch.setattr(FA, "_RESIDENT_KV_BYTES", 0)
+        q, k, v, g = self._inputs(5, dtype=jnp.bfloat16)
+        out_p, vjp_p = jax.vjp(
+            lambda a, b, c: FA._flash_diff(a, b, c, causal, None, 128, 128),
+            q, k, v)
+        out_x, vjp_x = jax.vjp(
+            lambda a, b, c: FA._composed_attention(a, b, c, None, causal,
+                                                   None), q, k, v)
+        np.testing.assert_allclose(self._f32(out_p), self._f32(out_x),
+                                   atol=self.BF16_OUT_ATOL)
+        for got, want in zip(vjp_p(g), vjp_x(g)):
+            assert got.dtype == jnp.bfloat16
+            np.testing.assert_allclose(self._f32(got), self._f32(want),
+                                       atol=self.BF16_GRAD_ATOL)
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    @pytest.mark.parametrize("sub", [(512, 512), (64, 128), (128, 64),
+                                     (64, 64)])
+    @pytest.mark.parametrize("blocks", [(64, 128), (256, 128), (128, 256),
+                                        (256, 256)])
+    def test_causal_tiles_below_on_and_above_the_diagonal(
+            self, interpret_pallas, monkeypatch, blocks, sub, dtype):
+        # a grid step's tile is worked through in sub-tiles by loops in
+        # the kernel, and one wholly above the diagonal is skipped.  Every
+        # ratio of block to sub-tile and of rows to columns has to find
+        # the live ones (S=256: up to 4 x 4 sub-tiles, inside one grid
+        # step or across several), in all three kernels; (512, 512) is the
+        # shipped sub-tile, larger than these blocks: one a grid step
+        monkeypatch.setattr(FA, "_SUB_Q", sub[0])
+        monkeypatch.setattr(FA, "_SUB_K", sub[1])
+        q, k, v, g = self._inputs(6, dtype=dtype)
+        f32 = dtype == jnp.float32
+        want_o, vjp_x = jax.vjp(
+            lambda a, b, c: FA._composed_attention(a, b, c, None, True,
+                                                   None), q, k, v)
+        for resident_bytes in (FA._RESIDENT_KV_BYTES, 0):
+            monkeypatch.setattr(FA, "_RESIDENT_KV_BYTES", resident_bytes)
+            got_o, vjp_p = jax.vjp(
+                lambda a, b, c: FA._flash_diff(a, b, c, True, None,
+                                               *blocks), q, k, v)
+            np.testing.assert_allclose(
+                self._f32(got_o), self._f32(want_o),
+                atol=2e-3 if f32 else self.BF16_OUT_ATOL)
+        for got, want in zip(vjp_p(g), vjp_x(g)):
+            np.testing.assert_allclose(
+                self._f32(got), self._f32(want),
+                atol=2e-2 if f32 else self.BF16_GRAD_ATOL)
+
+    @pytest.mark.parametrize("sub", [(64, 128), (128, 64)])
+    def test_full_attention_sub_tiles(self, interpret_pallas, monkeypatch,
+                                      sub):
+        # no mask: every sub-tile of every tile runs, cross-length too
+        monkeypatch.setattr(FA, "_SUB_Q", sub[0])
+        monkeypatch.setattr(FA, "_SUB_K", sub[1])
+        q, _, _, g = self._inputs(8, S=128)
+        _, k, v, _ = self._inputs(8, S=256)
+        out_p, vjp_p = jax.vjp(
+            lambda a, b, c: FA._flash_diff(a, b, c, False, None, 128, 256),
+            q, k, v)
+        out_x, vjp_x = jax.vjp(
+            lambda a, b, c: FA._composed_attention(a, b, c, None, False,
+                                                   None), q, k, v)
+        np.testing.assert_allclose(np.asarray(out_p), np.asarray(out_x),
+                                   atol=2e-3)
+        for got, want in zip(vjp_p(g), vjp_x(g)):
+            np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                       atol=2e-2)
+
+    @pytest.mark.parametrize("forward", ["resident", "streaming"])
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    def test_matrix_products_take_the_operands_dtype(self, monkeypatch,
+                                                     dtype, forward):
+        """Every `dot_general` of the traced kernel bodies (forward, dk/dv
+        and dq) takes both operands in the caller's dtype — bf16 in, bf16
+        products; float32 in, float32 products — and gives float32."""
+        if forward == "streaming":
+            monkeypatch.setattr(FA, "_RESIDENT_KV_BYTES", 0)
+        q, k, v, g = self._inputs(7, dtype=dtype)
+
+        def fwd_bwd(q, k, v, g):
+            out, vjp = jax.vjp(
+                lambda a, b, c: FA._flash_diff(a, b, c, True, None, 128,
+                                               128), q, k, v)
+            return out, vjp(g)
+
+        kernels = {}
+        for eqn in _walk(jax.make_jaxpr(fwd_bwd)(q, k, v, g).jaxpr):
+            if eqn.primitive.name == "pallas_call":
+                dots = [e for e in _walk(eqn.params["jaxpr"])
+                        if e.primitive.name == "dot_general"]
+                kernels[eqn.params["name"]] = dots
+        # products a sub-tile: 2 forward, 4 for dk/dv, 3 for dq
+        assert {n: len(d) for n, d in kernels.items()} == {
+            "flash_attention_fwd": 2, "flash_attention_bwd_dkv": 4,
+            "flash_attention_bwd_dq": 3}
+        for name, dots in kernels.items():
+            for e in dots:
+                assert [x.aval.dtype for x in e.invars] == [dtype, dtype], \
+                    (name, e)
+                assert e.outvars[0].aval.dtype == jnp.float32, (name, e)
+
+
+def _walk(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs nested in it."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for val in eqn.params.values():
+            for sub in (val if isinstance(val, (list, tuple)) else (val,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _walk(sub)
+
+
 class TestFusedLayerNorm:
     def test_forward_matches_xla(self, interpret_pallas):
         rng = np.random.RandomState(0)

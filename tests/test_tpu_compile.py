@@ -36,6 +36,9 @@ HEADS = BASE["num_heads"]
 HEAD_DIM = BASE["hidden_size"] // HEADS
 MAX_LEN = BASE["max_seq_len"]
 Q_MAX = 64  # FLAGS_prefill_chunk_tokens: the engine's default prefill_q_max
+# flash_autotune_cache.json's entry for the train cells' attention shape
+# (1024 x 1024, heads of 64, bf16, causal): PR 30's sweep on the chip
+TRAIN_BLOCKS = (1024, 1024)
 
 
 @pytest.fixture(scope="module")
@@ -92,8 +95,8 @@ def _compile(fn, *args, **jit_kw):
 def _has_kernel(compiled, name=None):
     """A Pallas call is in the program; with ``name``, as an instruction
     of that name, which is what a device trace shows the kernel as
-    (under autodiff JAX wraps it: ``jvp_<name>_``,
-    ``transpose_jvp_<name>__``)."""
+    (a kernel called under autodiff outside a jitted function of its own
+    is wrapped: ``jvp_<name>_``, ``transpose_jvp_<name>__``)."""
     text = compiled.as_text()
     if name is None:
         return chip_smoke.KERNEL in text
@@ -112,7 +115,7 @@ def test_flash_fwd_bwd_at_the_train_shape(one_chip, dtype, batch):
     blocks = FA.cached_blocks(seq, seq, HEAD_DIM, dtype, True) or \
         FA.pick_blocks(seq, seq)
     if dtype == jnp.bfloat16:
-        assert blocks == (512, 1024)  # flash_autotune_cache.json
+        assert blocks == TRAIN_BLOCKS  # flash_autotune_cache.json
     x = jax.ShapeDtypeStruct((batch, HEADS, seq, HEAD_DIM), dtype,
                              sharding=one_chip)
 
@@ -127,6 +130,24 @@ def test_flash_fwd_bwd_at_the_train_shape(one_chip, dtype, batch):
     for name in ("flash_attention_fwd", "flash_attention_bwd_dkv",
                  "flash_attention_bwd_dq"):
         assert _has_kernel(compiled, name), name
+    # what the benchmark's `classify` counts to find them
+    # (`flash_attn_roofline.train`): [b*h, seq, d] operands, exactly
+    # three for the forward call, five or more for each backward call
+    shaped = {}
+    for line in compiled.as_text().splitlines():
+        m = re.search(r"%\w*?(flash_attention_(?:fwd|bwd_dkv|bwd_dq))_*"
+                      r"(\.\d+)? = .*custom-call\((.*?)\), "
+                      r"custom_call_target", line)
+        if m:
+            layouts = line.split("operand_layout_constraints=", 1)[1].split(
+                "frontend_", 1)[0]
+            shaped[m.group(1)] = (
+                len(m.group(3).split(", ")),
+                len(re.findall(rf"\[{batch * HEADS},{seq},{HEAD_DIM}\]",
+                               layouts)))
+    assert shaped == {"flash_attention_fwd": (3, 3),
+                      "flash_attention_bwd_dkv": (6, 5),
+                      "flash_attention_bwd_dq": (6, 5)}
 
 
 def test_layer_norm_768(one_chip):

@@ -6,6 +6,7 @@ shapes, and writes BENCH_kernels.json at the repo root.
 Run on a real TPU chip:  python tools/bench_kernels.py
 """
 import functools
+import itertools
 import json
 import os
 import sys
@@ -121,9 +122,7 @@ def main():
             t_xla = None
 
         best = None
-        for bq, bk in ((256, 256), (512, 256), (256, 512), (512, 512),
-                       (128, 256), (256, 128), (1024, 512), (512, 1024),
-                       (1024, 1024), (1024, 256)):
+        for bq, bk in itertools.product((128, 256, 512, 1024), repeat=2):
             if S % bq or S % bk:
                 continue
             pl_attn = lambda q, k, v: fa._flash_diff(q, k, v, causal, None,
