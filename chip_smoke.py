@@ -260,8 +260,8 @@ def serve_phase(model, ragged: bool):
     emit(phase=name, num_pages=SERVE["num_pages"],
          config=eng.statusz()["config"],
          weights_dtype=str(eng._params["wte"].dtype),
-         kv_pages_dtype=str(eng._k_pages.dtype),
-         kv_pool_bytes=2 * eng._k_pages.nbytes,
+         kv_pages_dtype=str(eng._kv.dtype),
+         kv_pool_bytes=sum(a.nbytes for a in eng._kv.pages),
          warmup_seconds_with_compile=warm_seconds,
          executables=[t.site for t in trackers],
          prompt_lens=list(lens), new_tokens=SERVE["new_tokens"],
@@ -380,15 +380,15 @@ def sharded_serve_phase():
     seconds = round(time.perf_counter() - t0, 3)
     spread = [_spread("qkv_w", eng._params["blocks"][0]["qkv_w"], 4),
               _spread("fc2_w", eng._params["blocks"][0]["fc2_w"], 4),
-              _spread("k_pages", eng._k_pages, 4),
-              _spread("v_pages", eng._v_pages, 4)]
+              _spread("k_pages", eng._kv.k, 4),
+              _spread("v_pages", eng._kv.v, 4)]
     (tracker,) = [t for t in eng._trackers() if t is not None]
     text = inspect_executable(f"serve mp=4: {tracker.site}",
                               tracker.lower()).as_text()
     collectives = hlo_collectives(text)
     # the pool must stay where it is: no all-gather may touch an array
     # whose trailing dims are the pool's [num_pages, page, head_dim]
-    pool_dims = "{},{},{}]".format(*eng._k_pages.shape[2:])
+    pool_dims = "{},{},{}]".format(*eng._kv.k.shape[2:])
     gathers = [ln.strip()[:200] for ln in text.splitlines()
                if " all-gather(" in ln or " all-gather-start(" in ln]
     emit(phase="sharded_serve", serve_mesh="mp=4", **kw,

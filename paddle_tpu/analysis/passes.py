@@ -33,11 +33,10 @@ otherwise only prose in a docstring:
   on an engine receiver) outside the sanctioned between-steps sites
   are findings.
 * **donation** (`DonationPass`) — every ``jax.jit`` site is
-  cross-checked: all ``*_pages`` pool parameters of the jitted
-  function — and the ``*_scales`` quant-scale arrays that count as
-  pool state under FLAGS_kv_quant — must appear in ``donate_argnums``
-  (a missed donation means a full extra copy of the KV pool, or a
-  silently copied scale buffer, per step).
+  cross-checked: a jitted function that takes the KV pool (its ``kv``
+  parameter: one `ops.pallas.paged_attention.KVPool`, pages and quant
+  scales together) must have it in ``donate_argnums`` (a missed
+  donation means a full extra copy of the KV pool per step).
 * **fleet-trace** (`FleetTracePass`) — every HTTP site under the
   fleet plane (a client leg calling ``urlopen``, or a ``do_*``
   server-handler method) must carry the fleet trace: reference the
@@ -722,13 +721,13 @@ class EngineMutationPass:
 # donation coverage
 # ---------------------------------------------------------------------------
 class DonationPass:
-    """Every jax.jit site whose function carries KV-pool parameters —
-    ``*_pages`` page pools AND the ``*_scales`` quant-scale arrays
-    that live beside them (FLAGS_kv_quant) — must donate ALL of them.
-    The scale arrays are pool state: a jit site donating the pages but
-    copying the scales would silently pay (and leak) a per-step scale
-    buffer, and under FLAGS_sanitize the tombstoned and live sets
-    would diverge."""
+    """Every jax.jit site whose function takes the KV pool — the
+    parameter named ``kv``, as every step function of the serving
+    stack names its `KVPool` — must donate it.  The pool is one
+    argument whatever it holds (pages, and the quant scales of an int8
+    pool), so a site cannot donate half of it."""
+
+    POOL_PARAM = "kv"
 
     def run(self, modules: Sequence[SourceModule],
             sites: Optional[List[JitSite]] = None) -> List[Finding]:
@@ -741,27 +740,23 @@ class DonationPass:
             args = fn.args
             params = [a.arg for a in getattr(args, "posonlyargs", [])] + \
                 [a.arg for a in args.args]
-            pages = [(i, n) for i, n in enumerate(params)
-                     if n.endswith("_pages") or n.endswith("_scales")]
-            if not pages:
+            if self.POOL_PARAM not in params:
                 continue
-            donated = set(site.donate_argnums or ())
-            for i, name in pages:
-                jit_idx = i - site.pos_shift
-                if jit_idx < 0:
-                    continue  # bound by partial positionally: not a
-                    #         # jit argument at all
-                if jit_idx not in donated:
-                    f = site.module.finding(
-                        "donation", site.call,
-                        f"jax.jit of `{site.fn_name}` does not donate "
-                        f"pool parameter `{name}` (argnum {jit_idx}) — "
-                        f"add it to donate_argnums or the step pays a "
-                        f"full extra copy of the KV pool"
-                        + ("" if site.donate_argnums is not None
-                           else " (no donate_argnums at all)"))
-                    if f:
-                        out.append(f)
+            jit_idx = params.index(self.POOL_PARAM) - site.pos_shift
+            if jit_idx < 0:
+                continue  # bound by partial positionally: not a jit
+                #         # argument at all
+            if jit_idx not in set(site.donate_argnums or ()):
+                f = site.module.finding(
+                    "donation", site.call,
+                    f"jax.jit of `{site.fn_name}` does not donate "
+                    f"pool parameter `{self.POOL_PARAM}` (argnum "
+                    f"{jit_idx}) — add it to donate_argnums or the "
+                    f"step pays a full extra copy of the KV pool"
+                    + ("" if site.donate_argnums is not None
+                       else " (no donate_argnums at all)"))
+                if f:
+                    out.append(f)
         return out
 
 

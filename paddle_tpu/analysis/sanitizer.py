@@ -235,26 +235,18 @@ class Sanitizer:
             hit = self._tombstones.get(id(arr))
         return None if hit is None else hit[1]
 
-    def check_live(self, obj, context: str = "", _depth: int = 0):
-        """Raise `UseAfterDonateError` if ``obj`` (or, for shallow
-        containers, any leaf) was donated earlier.  Called by
-        `_JitTracker` on every executable argument, so feeding a stale
-        pre-donation reference back into a step fails at the call."""
-        site = self.donation_site(obj)
+    def check_live(self, arr, context: str = ""):
+        """Raise `UseAfterDonateError` if ``arr`` was donated earlier.
+        Called by `_JitTracker` on every leaf of every executable
+        argument, so feeding a stale pre-donation reference back into a
+        step fails at the call."""
+        site = self.donation_site(arr)
         if site is not None:
             raise UseAfterDonateError(
                 f"use after donate{': ' + context if context else ''} — "
                 f"this buffer was donated at {site} and its device "
                 f"memory has been reused; rebind to the executable's "
                 f"returned arrays instead of holding the input")
-        if _depth >= 3:
-            return
-        if isinstance(obj, dict):
-            for v in obj.values():
-                self.check_live(v, context, _depth + 1)
-        elif isinstance(obj, (list, tuple)):
-            for v in obj:
-                self.check_live(v, context, _depth + 1)
 
 
 _SAN = Sanitizer()

@@ -544,25 +544,9 @@ class DurabilityManager:
             # own cache — don't pay the device fetch + fsync for bytes
             # that can never install
             return None
-        import jax
-
         items = sorted(eng.pool._page_hash.items())  # (page, hash)
-        ids = np.asarray([p for p, _ in items], np.int32)
-        arrays = {
-            # the K/V lanes of a row (a float pool's rows are wider:
-            # `pa.kv_pool_width`)
-            "k": np.asarray(jax.device_get(
-                eng._k_pages[:, :, ids, :, :eng._head_dim])),
-            "v": np.asarray(jax.device_get(
-                eng._v_pages[:, :, ids, :, :eng._head_dim])),
-        }
-        if eng._kv_quant:
-            arrays["ks"] = np.asarray(
-                jax.device_get(eng._k_scales[:, :, ids]))
-            arrays["vs"] = np.asarray(
-                jax.device_get(eng._v_scales[:, :, ids]))
         buf = io.BytesIO()
-        np.savez(buf, **arrays)
+        np.savez(buf, **eng._kv.export_pages([p for p, _ in items]))
         payload = buf.getvalue()
         path = os.path.join(self.journal_dir, KV_PAGES_NAME)
         tmp = path + ".tmp"
@@ -573,7 +557,7 @@ class DurabilityManager:
         os.replace(tmp, path)
         return {"file": KV_PAGES_NAME, "crc": zlib.crc32(payload),
                 "hashes": [h.hex() for _, h in items],
-                "dtype": str(eng._k_pages.dtype),
+                "dtype": str(eng._kv.dtype),
                 "page": int(eng._page), "bytes": len(payload)}
 
     def close(self):
@@ -624,64 +608,40 @@ def _install_kv_sidecar(journal_dir: str, snap: SnapshotWire,
         payload = f.read()
     if zlib.crc32(payload) != int(meta.get("crc", -1)):
         return 0  # torn/stale sidecar: recompute instead
-    if str(meta.get("dtype")) != str(eng._k_pages.dtype) or \
+    if str(meta.get("dtype")) != str(eng._kv.dtype) or \
             int(meta.get("page", -1)) != int(eng._page):
         return 0  # config drift (should be impossible past the
         #         # fingerprint check, but never install wrong bytes)
     import io
 
     try:
-        data = np.load(io.BytesIO(payload))
-        k, v = data["k"], data["v"]
+        with np.load(io.BytesIO(payload)) as data:
+            arrays = {name: data[name] for name in data.files}
     except Exception:
         return 0
-    if eng._kv_quant and not ("ks" in data.files and
-                              "vs" in data.files):
-        # an int8 sidecar without BOTH scale arrays is inconsistent
-        # (crc proves the bytes, not the key set): installing would
-        # either crash on the missing key or dequantize cached KV
-        # with zero scales — fall back to recompute instead
-        return 0
     hashes = [bytes.fromhex(h) for h in meta.get("hashes", [])]
-    if k.shape[2] != len(hashes) or \
-            k.shape[:2] + k.shape[3:] != (eng._num_layers,
-                                          eng._num_heads, eng._page,
-                                          eng._head_dim):
+    # the arrays must be what this pool exports (crc proves the bytes,
+    # not the key set or the geometry: an int8 sidecar without BOTH
+    # scale arrays would dequantize cached KV with zero scales), a
+    # page a hash — else fall back to recompute
+    if not eng._kv.fits(arrays) or arrays["k"].shape[2] != len(hashes):
         return 0
     n = min(len(hashes), eng.pool.free_count)
     if n == 0:
         return 0
-    import jax.numpy as jnp
-
     # raw pool allocs (not the engine's fresh-marking wrapper): the
     # installed pages carry LIVE scales that the between-steps scale
     # reset must not zero
     ids = [eng.pool.alloc_page() for _ in range(n)]
-    idx = jnp.asarray(np.asarray(ids, np.int32))
-    eng._k_pages = eng._k_pages.at[:, :, idx, :, :eng._head_dim].set(
-        jnp.asarray(k[:, :, :n]))
-    eng._v_pages = eng._v_pages.at[:, :, idx, :, :eng._head_dim].set(
-        jnp.asarray(v[:, :, :n]))
-    if eng._kv_quant:
-        eng._k_scales = eng._k_scales.at[:, :, idx].set(
-            jnp.asarray(data["ks"][:, :, :n]))
-        eng._v_scales = eng._v_scales.at[:, :, idx].set(
-            jnp.asarray(data["vs"][:, :, :n]))
+    eng._kv = eng._kv.import_pages(
+        ids, {name: a[:, :, :n] for name, a in arrays.items()})
     if getattr(eng, "_mesh", None) is not None:
         # the host-side scatter above ran OUTSIDE the step executables
         # and may have left the pool with whatever sharding GSPMD
         # propagated; re-pin the head-axis layout so the first step
         # after restore sees the exact input shardings it compiled
         # against (a drifted sharding would be a warm retrace)
-        import jax
-
-        eng._k_pages = jax.device_put(eng._k_pages, eng._page_sharding)
-        eng._v_pages = jax.device_put(eng._v_pages, eng._page_sharding)
-        if eng._kv_quant:
-            eng._k_scales = jax.device_put(eng._k_scales,
-                                           eng._scale_sharding)
-            eng._v_scales = jax.device_put(eng._v_scales,
-                                           eng._scale_sharding)
+        eng._kv = eng._kv.sharded(eng._mesh)
     installed = 0
     for pid, key in zip(ids, hashes[:n]):
         if eng.pool.register_page(pid, key):

@@ -235,7 +235,7 @@ class _JitTracker:
     def __call__(self, *args):
         san = _san.active()
         if san is not None:
-            for a in args:
+            for a in jax.tree_util.tree_leaves(args):
                 san.check_live(a, context=f"argument of {self.site}")
         if not self._warm:
             self.signature = jax.tree_util.tree_map(
@@ -257,7 +257,8 @@ class _JitTracker:
         if san is not None:
             for i in self.donate_argnums:
                 if i < len(args):
-                    san.tombstone(args[i], self.site)
+                    for a in jax.tree_util.tree_leaves(args[i]):
+                        san.tombstone(a, self.site)
         return out
 
     def lower(self):
@@ -904,17 +905,33 @@ def _guard_tokens(logits, tokens):
     return jnp.where(ok, tokens, NAN_TOKEN)
 
 
-def _gpt_prefill(params, ids, true_len, bt_row, k_pages, v_pages, key, *,
+def _pack_refolds(kv, out, refolds):
+    """A step's sampled tokens as the host fetches them.  A quantized
+    pool's step packs its refold count (`KVPool.write`) behind the
+    tokens — one more int32 element of a scalar or a row, one more row
+    (count in column 0) of a ``[B, Q]`` block — so the host learns both
+    from the single blocking fetch the step already pays
+    (`DecodeEngine._note_refolds` takes it off again).  A float pool's
+    tokens pass through: nothing is traced."""
+    if not kv.quantized:
+        return out
+    out = out.astype(jnp.int32)
+    if out.ndim == 2:
+        pack = jnp.zeros((1, out.shape[1]), jnp.int32).at[0, 0].set(refolds)
+        return jnp.concatenate([out, pack], axis=0)
+    return jnp.concatenate([out.reshape(-1), refolds[None]])
+
+
+def _gpt_prefill(params, ids, true_len, bt_row, kv, key, *,
                  num_heads, head_dim, eps, sampler, temperature, top_k,
                  top_p):
     """Prompt pass for ONE request: full causal attention over the
-    (bucket-padded) prompt, its ``true_len`` K/V rows written into the
-    request's pages (`pa.paged_kv_write`; padding rows are not), first
-    token sampled from the last valid position's logits.
+    (bucket-padded) prompt's in-flight K/V, its ``true_len`` K/V rows
+    written into the request's pages (`pa.KVPool.write`; padding rows
+    are not), first token sampled from the last valid position's logits.
 
     ids: [1, S_pad] int32; true_len: scalar int32; bt_row: [pages_max]
-    int32; k_pages/v_pages: [L, Hkv, num_pages, page, W] (donated; rows
-    W = `pa.kv_pool_width` of D wide).
+    int32; kv: the pool (donated).  Returns ``(kv, token)``.
     """
     from ..nn.functional.attention import _sdpa_reference
 
@@ -926,6 +943,7 @@ def _gpt_prefill(params, ids, true_len, bt_row, k_pages, v_pages, key, *,
     # the one-request form of the batched write: a run of true_len rows
     # from position 0 (rows past it are bucket padding and are not written)
     bt, start, cap = bt_row[None], jnp.zeros((1,), jnp.int32), true_len[None]
+    refolds = 0
 
     for li, blk in enumerate(params["blocks"]):
         y = _ln(x, blk["ln1_w"], blk["ln1_b"], eps)
@@ -934,10 +952,9 @@ def _gpt_prefill(params, ids, true_len, bt_row, k_pages, v_pages, key, *,
         q = qkv[:, 0].transpose(1, 0, 2)[None]  # [1, H, S, D]
         k = qkv[:, 1].transpose(1, 0, 2)[None]
         v = qkv[:, 2].transpose(1, 0, 2)[None]
-        k_pages = pa.paged_kv_write(k_pages, li, qkv[None, :, 1], bt,
-                                    start, cap)
-        v_pages = pa.paged_kv_write(v_pages, li, qkv[None, :, 2], bt,
-                                    start, cap)
+        kv, rk = kv.write("k", li, qkv[None, :, 1], bt, start, cap)
+        kv, rv = kv.write("v", li, qkv[None, :, 2], bt, start, cap)
+        refolds += rk + rv
         attn = _sdpa_reference(q, k, v, None, 0.0, None, True)[0]
         attn = attn.transpose(1, 0, 2).reshape(s_pad, h)
         x = x + _wmm(attn, blk, "out_w") + blk["out_b"]
@@ -952,20 +969,19 @@ def _gpt_prefill(params, ids, true_len, bt_row, k_pages, v_pages, key, *,
     token = sample_logits(logits, sampler=sampler, temperature=temperature,
                           top_k=top_k, top_p=top_p, key=key)
     token = _guard_tokens(logits, token)[0]
-    return k_pages, v_pages, token
+    return kv, _pack_refolds(kv, token, refolds)
 
 
-def _gpt_decode_step(params, k_pages, v_pages, block_tables, seq_lens,
-                     tokens, active, key, *, num_heads, head_dim, eps,
-                     sampler, temperature, top_k, top_p):
+def _gpt_decode_step(params, kv, block_tables, seq_lens, tokens, active,
+                     key, *, num_heads, head_dim, eps, sampler,
+                     temperature, top_k, top_p):
     """One batched decode step over every slot: write the incoming
     token's K/V into its page, ragged paged attention over the pool,
-    sample the next token.  The pools ([L, Hkv, P, page, W], rows
-    `pa.kv_pool_width` wide) are donated and `pa.paged_kv_write`
-    rewrites one page a live slot where it lies: on the chip nothing
-    pool-sized moves but the per-layer slice the kernel takes
-    (`pa.kv_layer`; tests/test_tpu_compile.py).  Inactive slots write
-    nothing (cap 0) and read length 0."""
+    sample the next token.  The pool (`pa.KVPool`) is donated; a float
+    one is rewritten one page a live slot where it lies: on the chip
+    nothing pool-sized moves but the per-layer slice the kernel takes
+    (tests/test_tpu_compile.py).  Inactive slots write nothing (cap 0)
+    and read length 0.  Returns ``(kv, next tokens [B])``."""
     b = tokens.shape[0]
     h = num_heads * head_dim
 
@@ -973,19 +989,17 @@ def _gpt_decode_step(params, k_pages, v_pages, block_tables, seq_lens,
     x = params["wte"][tokens] + params["wpe"][pos]  # [B, h]
     caps = active.astype(jnp.int32)  # one row a live slot, none otherwise
     lens_now = seq_lens + caps
+    refolds = 0
 
     for li, blk in enumerate(params["blocks"]):
         y = _ln(x, blk["ln1_w"], blk["ln1_b"], eps)
         qkv = _wmm(y, blk, "qkv_w") + blk["qkv_b"]
         qkv = qkv.reshape(b, 3, num_heads, head_dim)
         q, k, v = qkv[:, 0], qkv[:, 1], qkv[:, 2]  # [B, H, D]
-        k_pages = pa.paged_kv_write(k_pages, li, k[:, None], block_tables,
-                                    seq_lens, caps)
-        v_pages = pa.paged_kv_write(v_pages, li, v[:, None], block_tables,
-                                    seq_lens, caps)
-        attn = pa.paged_attention(q, pa.kv_layer(k_pages, li, head_dim),
-                                  pa.kv_layer(v_pages, li, head_dim),
-                                  block_tables, lens_now)
+        kv, rk = kv.write("k", li, k[:, None], block_tables, seq_lens, caps)
+        kv, rv = kv.write("v", li, v[:, None], block_tables, seq_lens, caps)
+        refolds += rk + rv
+        attn = kv.attend(q, li, block_tables, lens_now)
         x = x + _wmm(attn.reshape(b, h), blk, "out_w") + blk["out_b"]
         y = _ln(x, blk["ln2_w"], blk["ln2_b"], eps)
         y = jax.nn.gelu(_wmm(y, blk, "fc1_w") + blk["fc1_b"],
@@ -997,11 +1011,52 @@ def _gpt_decode_step(params, k_pages, v_pages, block_tables, seq_lens,
     nxt = sample_logits(logits, sampler=sampler, temperature=temperature,
                         top_k=top_k, top_p=top_p, key=key)
     nxt = _guard_tokens(logits, nxt)
-    return k_pages, v_pages, jnp.where(active, nxt, 0)
+    return kv, _pack_refolds(kv, jnp.where(active, nxt, 0), refolds)
 
 
-def _gpt_mixed_step(params, k_pages, v_pages, block_tables, seq_lens,
-                    tokens, write_caps, sample_idx, sample_mask, key, *,
+def _gpt_layer_bq(blk, li, x, kv, block_tables, seq_lens, write_caps,
+                  lens_now, *, num_heads, head_dim, eps, mesh=None):
+    """Block ``li`` over a ``[B, Q]`` grid of incoming tokens — the one
+    layer `_gpt_mixed_step`, `_gpt_ragged_step` and
+    `speculative._gpt_spec_verify` run; they differ in what they sample
+    afterwards.  x: [B, Q, h]; rows ``i < write_caps[b]`` of slot ``b``
+    are written at positions ``seq_lens[b] + i`` and attend causally
+    from there (``lens_now = seq_lens + write_caps``).  Returns
+    ``(x, kv, refolds)``.  ``mesh`` (the ragged step's alone) makes it
+    the tensor-parallel layer; ``None`` constrains nothing."""
+    b, qn, h = x.shape
+    cst = pa.mesh_constrain(mesh)
+    y = _ln(x.reshape(b * qn, h), blk["ln1_w"], blk["ln1_b"], eps)
+    qkv = _wmm(y, blk, "qkv_w") + blk["qkv_b"]
+    # head axis sharded over 'mp' from here: the K/V write and the
+    # paged-attention gather stay chip-local (each chip owns its
+    # head-slice of every page, and of a quantized pool's scales)
+    qkv = cst(qkv.reshape(b, qn, 3, num_heads, head_dim),
+              None, None, None, "mp", None)
+    q = qkv[:, :, 0]                                     # [B, Q, H, D]
+    kv, rk = kv.write("k", li, qkv[:, :, 1], block_tables, seq_lens,
+                      write_caps, mesh=mesh)
+    kv, rv = kv.write("v", li, qkv[:, :, 2], block_tables, seq_lens,
+                      write_caps, mesh=mesh)
+    attn = cst(kv.attend(q, li, block_tables, lens_now,
+                         q_offsets=seq_lens, mesh=mesh),
+               None, None, "mp", None)
+    # row-parallel out proj: replicating the residual forces the
+    # cross-chip all-reduce exactly here (heads fuse head-major
+    # into h, so the reshape keeps the 'mp' shards contiguous)
+    x = cst(x + _wmm(attn.reshape(b, qn, h), blk, "out_w")
+            + blk["out_b"])
+    y = _ln(x.reshape(b * qn, h), blk["ln2_w"], blk["ln2_b"], eps)
+    y = cst(jax.nn.gelu(_wmm(y, blk, "fc1_w") + blk["fc1_b"],
+                        approximate=True),
+            None, "mp")
+    # row-parallel fc2: second all-reduce of the block
+    x = cst(x + (_wmm(y, blk, "fc2_w") + blk["fc2_b"]).reshape(b, qn, h))
+    return x, kv, rk + rv
+
+
+def _gpt_mixed_step(params, kv, block_tables, seq_lens, tokens,
+                    write_caps, sample_idx, sample_mask, key, *,
                     num_heads, head_dim, eps, sampler, temperature,
                     top_k, top_p):
     """ONE mixed prefill+decode step over every slot: prefilling slots
@@ -1009,8 +1064,8 @@ def _gpt_mixed_step(params, k_pages, v_pages, block_tables, seq_lens,
     decoding slots contribute their last sampled token (cap 1), stalled
     or inactive slots contribute nothing (cap 0).  K/V for every
     contributed row is written into the slot's already-reserved pages
-    (`pa.paged_kv_write`: a page at a time, rows past the cap keep what
-    the page held), attention runs through
+    (`pa.KVPool.write`: rows past the cap keep what the page held),
+    attention runs through
     the ragged multi-query paged kernel with per-sequence causal
     offsets (``q_offsets = seq_lens``: each chunk starts at the slot's
     current KV length), and ONE token per slot is sampled from the row
@@ -1019,43 +1074,28 @@ def _gpt_mixed_step(params, k_pages, v_pages, block_tables, seq_lens,
     zeroes the draw for slots still mid-prefill.
 
     tokens: [B, Q_max] int32; write_caps/sample_idx: [B] int32;
-    sample_mask: [B] bool; k_pages/v_pages donated (the update is in
+    sample_mask: [B] bool; kv donated (the update is in
     place, on the chip too: see `_gpt_decode_step`).
-    Returns (k_pages, v_pages, sampled [B] int32).
+    Returns (kv, sampled [B] int32).
 
     The shapes are fixed per engine, so this compiles ONCE — the pow-2
     bucket zoo of legacy prefill executables collapses into this single
     program, and the `_JitTracker` retrace contract covers it.
     """
     b, qn = tokens.shape
-    h = num_heads * head_dim
 
     offs = jnp.arange(qn, dtype=jnp.int32)
     pos = seq_lens[:, None] + offs[None, :]              # [B, Q]
     wpe_max = params["wpe"].shape[0] - 1
     x = params["wte"][tokens] + params["wpe"][jnp.minimum(pos, wpe_max)]
     lens_now = seq_lens + write_caps
+    refolds = 0
 
     for li, blk in enumerate(params["blocks"]):
-        y = _ln(x.reshape(b * qn, h), blk["ln1_w"], blk["ln1_b"], eps)
-        qkv = _wmm(y, blk, "qkv_w") + blk["qkv_b"]
-        qkv = qkv.reshape(b, qn, 3, num_heads, head_dim)
-        q = qkv[:, :, 0]                                 # [B, Q, H, D]
-        k_pages = pa.paged_kv_write(k_pages, li, qkv[:, :, 1],
-                                    block_tables, seq_lens, write_caps)
-        v_pages = pa.paged_kv_write(v_pages, li, qkv[:, :, 2],
-                                    block_tables, seq_lens, write_caps)
-        attn = pa.paged_attention(q, pa.kv_layer(k_pages, li, head_dim),
-                                  pa.kv_layer(v_pages, li, head_dim),
-                                  block_tables, lens_now,
-                                  q_offsets=seq_lens)
-        x = x + _wmm(attn.reshape(b, qn, h), blk, "out_w") \
-            + blk["out_b"]
-        y = _ln(x.reshape(b * qn, h), blk["ln2_w"], blk["ln2_b"], eps)
-        y = jax.nn.gelu(_wmm(y, blk, "fc1_w") + blk["fc1_b"],
-                        approximate=True)
-        x = x + (_wmm(y, blk, "fc2_w") + blk["fc2_b"]
-                 ).reshape(b, qn, h)
+        x, kv, r = _gpt_layer_bq(
+            blk, li, x, kv, block_tables, seq_lens, write_caps, lens_now,
+            num_heads=num_heads, head_dim=head_dim, eps=eps)
+        refolds += r
 
     # sample ONE row per slot (not all Q like the verify step): the
     # lm-head matmul runs over [B, h], so mixed-step sampling costs the
@@ -1066,213 +1106,13 @@ def _gpt_mixed_step(params, k_pages, v_pages, block_tables, seq_lens,
     nxt = sample_logits(logits, sampler=sampler, temperature=temperature,
                         top_k=top_k, top_p=top_p, key=key)
     nxt = _guard_tokens(logits, nxt)
-    return k_pages, v_pages, jnp.where(sample_mask, nxt, 0)
-
-
-# ---------------------------------------------------------------------------
-# Quantized-KV twins of the step functions (FLAGS_kv_quant=int8).
-#
-# Pages store int8 with per-page, per-head symmetric scales in parallel
-# ``k_scales``/``v_scales`` arrays ([L, Hkv, P] f32) that are donated
-# and threaded through every executable exactly like the page pools.
-# The write path quantizes the scattered chunk in-graph
-# (`pa.paged_quant_write`: per-head absmax folded into the running page
-# scale, existing rows re-quantized when the scale grows), and the read
-# path fuses dequant into the paged-attention K/V loads — no separate
-# materialization pass ever exists.  The sampled-token output is PACKED
-# with the step's refold count (one extra int32 row/element) so the
-# host learns both from the single blocking fetch the step already
-# pays — the sanitizer's one-sync-per-step contract holds in quantized
-# mode too.
-#
-# The unquantized functions above stay byte-identical — they are the
-# FLAGS_kv_quant=off path and the bit-exactness oracle; keeping the
-# twins separate (rather than a mode flag inside one body) is what
-# lets the off path compile the exact same executables as before this
-# feature existed (zero new executables in off mode, pinned by
-# tools/bench_kv_quant.py).
-# ---------------------------------------------------------------------------
-def _gpt_prefill_q(params, ids, true_len, bt_row, k_pages, v_pages,
-                   k_scales, v_scales, key, *, num_heads, head_dim, eps,
-                   sampler, temperature, top_k, top_p):
-    """Quantized-storage `_gpt_prefill`: the prompt pass itself attends
-    over the in-flight full-precision K/V (same `_sdpa_reference`), but
-    every K/V row scattered into the request's pages is quantized via
-    the running page scales — later chunked/decode steps read this
-    prompt's KV through the fused dequant exactly as if the chunked
-    path had written it.  Returns ``(k_pages, v_pages, k_scales,
-    v_scales, [token, refolds])``."""
-    from ..nn.functional.attention import _sdpa_reference
-
-    s_pad = ids.shape[1]
-    h = num_heads * head_dim
-    num_pages_total = k_pages.shape[2]
-    page = k_pages.shape[3]
-    pos = jnp.arange(s_pad, dtype=jnp.int32)
-    x = params["wte"][ids[0]] + params["wpe"][pos]  # [S, h]
-
-    valid = pos < true_len
-    page_idx = jnp.where(valid, bt_row[pos // page], num_pages_total)
-    slot = pos % page
-    spans = pa.paged_write_spans(
-        bt_row[None], jnp.zeros((1,), jnp.int32),
-        jnp.reshape(true_len, (1,)), s_pad, num_pages_total, page)
-    refolds = jnp.int32(0)
-
-    for li, blk in enumerate(params["blocks"]):
-        y = _ln(x, blk["ln1_w"], blk["ln1_b"], eps)
-        qkv = _wmm(y, blk, "qkv_w") + blk["qkv_b"]
-        qkv = qkv.reshape(s_pad, 3, num_heads, head_dim)
-        q = qkv[:, 0].transpose(1, 0, 2)[None]  # [1, H, S, D]
-        k = qkv[:, 1].transpose(1, 0, 2)[None]
-        v = qkv[:, 2].transpose(1, 0, 2)[None]
-        k_pages, k_scales, rk = pa.paged_quant_write(
-            k_pages, k_scales, li, k[0].transpose(1, 0, 2), page_idx,
-            slot, spans)
-        v_pages, v_scales, rv = pa.paged_quant_write(
-            v_pages, v_scales, li, v[0].transpose(1, 0, 2), page_idx,
-            slot, spans)
-        refolds = refolds + rk + rv
-        attn = _sdpa_reference(q, k, v, None, 0.0, None, True)[0]
-        attn = attn.transpose(1, 0, 2).reshape(s_pad, h)
-        x = x + _wmm(attn, blk, "out_w") + blk["out_b"]
-        y = _ln(x, blk["ln2_w"], blk["ln2_b"], eps)
-        y = jax.nn.gelu(_wmm(y, blk, "fc1_w") + blk["fc1_b"],
-                        approximate=True)
-        x = x + _wmm(y, blk, "fc2_w") + blk["fc2_b"]
-
-    h_last = jnp.take(x, true_len - 1, axis=0)[None]  # [1, h]
-    h_last = _ln(h_last, params["lnf_w"], params["lnf_b"], eps)
-    logits = _logits_of(params, h_last).astype(jnp.float32)
-    token = sample_logits(logits, sampler=sampler, temperature=temperature,
-                          top_k=top_k, top_p=top_p, key=key)
-    token = _guard_tokens(logits, token)[0]
-    out = jnp.stack([token.astype(jnp.int32), refolds])
-    return k_pages, v_pages, k_scales, v_scales, out
-
-
-def _gpt_decode_step_q(params, k_pages, v_pages, k_scales, v_scales,
-                       block_tables, seq_lens, tokens, active, key, *,
-                       num_heads, head_dim, eps, sampler, temperature,
-                       top_k, top_p):
-    """Quantized-storage `_gpt_decode_step`: the incoming token's K/V
-    quantizes into its page (scale fold + refold), attention reads the
-    pool through the fused dequant.  Returns ``(k_pages, v_pages,
-    k_scales, v_scales, out)`` with ``out`` = sampled tokens packed
-    with the refold count as its last element ([B+1] int32)."""
-    b = tokens.shape[0]
-    h = num_heads * head_dim
-    num_pages_total = k_pages.shape[2]
-    page = k_pages.shape[3]
-
-    pos = seq_lens  # the incoming token's position
-    x = params["wte"][tokens] + params["wpe"][pos]  # [B, h]
-    page_idx = jnp.where(
-        active, block_tables[jnp.arange(b), pos // page], num_pages_total)
-    slot = pos % page
-    lens_now = seq_lens + active.astype(jnp.int32)
-    refolds = jnp.int32(0)
-
-    for li, blk in enumerate(params["blocks"]):
-        y = _ln(x, blk["ln1_w"], blk["ln1_b"], eps)
-        qkv = _wmm(y, blk, "qkv_w") + blk["qkv_b"]
-        qkv = qkv.reshape(b, 3, num_heads, head_dim)
-        q, k, v = qkv[:, 0], qkv[:, 1], qkv[:, 2]  # [B, H, D]
-        k_pages, k_scales, rk = pa.paged_quant_write(
-            k_pages, k_scales, li, k, page_idx, slot)
-        v_pages, v_scales, rv = pa.paged_quant_write(
-            v_pages, v_scales, li, v, page_idx, slot)
-        refolds = refolds + rk + rv
-        attn = pa.paged_attention(q, k_pages[li], v_pages[li],
-                                  block_tables, lens_now,
-                                  k_scales=k_scales[li],
-                                  v_scales=v_scales[li])
-        x = x + _wmm(attn.reshape(b, h), blk, "out_w") + blk["out_b"]
-        y = _ln(x, blk["ln2_w"], blk["ln2_b"], eps)
-        y = jax.nn.gelu(_wmm(y, blk, "fc1_w") + blk["fc1_b"],
-                        approximate=True)
-        x = x + _wmm(y, blk, "fc2_w") + blk["fc2_b"]
-
-    x = _ln(x, params["lnf_w"], params["lnf_b"], eps)
-    logits = _logits_of(params, x).astype(jnp.float32)
-    nxt = sample_logits(logits, sampler=sampler, temperature=temperature,
-                        top_k=top_k, top_p=top_p, key=key)
-    nxt = _guard_tokens(logits, nxt)
-    out = jnp.concatenate([jnp.where(active, nxt, 0).astype(jnp.int32),
-                           refolds[None]])
-    return k_pages, v_pages, k_scales, v_scales, out
-
-
-def _gpt_mixed_step_q(params, k_pages, v_pages, k_scales, v_scales,
-                      block_tables, seq_lens, tokens, write_caps,
-                      sample_idx, sample_mask, key, *, num_heads,
-                      head_dim, eps, sampler, temperature, top_k, top_p):
-    """Quantized-storage `_gpt_mixed_step`: every contributed prompt/
-    decode row quantizes into its slot's pages, the ragged multi-query
-    attention reads through the fused dequant.  Returns ``(k_pages,
-    v_pages, k_scales, v_scales, out)`` with ``out`` [B+1] int32 (the
-    sampled token per slot + the refold count)."""
-    b, qn = tokens.shape
-    h = num_heads * head_dim
-    num_pages_total = k_pages.shape[2]
-    page = k_pages.shape[3]
-
-    offs = jnp.arange(qn, dtype=jnp.int32)
-    pos = seq_lens[:, None] + offs[None, :]              # [B, Q]
-    wpe_max = params["wpe"].shape[0] - 1
-    x = params["wte"][tokens] + params["wpe"][jnp.minimum(pos, wpe_max)]
-    page_idx, slot = pa.paged_write_indices(
-        block_tables, seq_lens, write_caps, qn, num_pages_total, page)
-    flat_idx = page_idx.reshape(-1)                      # [B*Q]
-    flat_slot = slot.reshape(-1)
-    spans = pa.paged_write_spans(
-        block_tables, seq_lens, write_caps, qn, num_pages_total, page)
-    lens_now = seq_lens + write_caps
-    refolds = jnp.int32(0)
-
-    for li, blk in enumerate(params["blocks"]):
-        y = _ln(x.reshape(b * qn, h), blk["ln1_w"], blk["ln1_b"], eps)
-        qkv = _wmm(y, blk, "qkv_w") + blk["qkv_b"]
-        qkv = qkv.reshape(b, qn, 3, num_heads, head_dim)
-        q = qkv[:, :, 0]                                 # [B, Q, H, D]
-        k_pages, k_scales, rk = pa.paged_quant_write(
-            k_pages, k_scales, li,
-            qkv[:, :, 1].reshape(b * qn, num_heads, head_dim),
-            flat_idx, flat_slot, spans)
-        v_pages, v_scales, rv = pa.paged_quant_write(
-            v_pages, v_scales, li,
-            qkv[:, :, 2].reshape(b * qn, num_heads, head_dim),
-            flat_idx, flat_slot, spans)
-        refolds = refolds + rk + rv
-        attn = pa.paged_attention(q, k_pages[li], v_pages[li],
-                                  block_tables, lens_now,
-                                  q_offsets=seq_lens,
-                                  k_scales=k_scales[li],
-                                  v_scales=v_scales[li])
-        x = x + _wmm(attn.reshape(b, qn, h), blk, "out_w") \
-            + blk["out_b"]
-        y = _ln(x.reshape(b * qn, h), blk["ln2_w"], blk["ln2_b"], eps)
-        y = jax.nn.gelu(_wmm(y, blk, "fc1_w") + blk["fc1_b"],
-                        approximate=True)
-        x = x + (_wmm(y, blk, "fc2_w") + blk["fc2_b"]
-                 ).reshape(b, qn, h)
-
-    sel = x[jnp.arange(b), sample_idx]                   # [B, h]
-    sel = _ln(sel, params["lnf_w"], params["lnf_b"], eps)
-    logits = _logits_of(params, sel).astype(jnp.float32)
-    nxt = sample_logits(logits, sampler=sampler, temperature=temperature,
-                        top_k=top_k, top_p=top_p, key=key)
-    nxt = _guard_tokens(logits, nxt)
-    out = jnp.concatenate(
-        [jnp.where(sample_mask, nxt, 0).astype(jnp.int32),
-         refolds[None]])
-    return k_pages, v_pages, k_scales, v_scales, out
+    return kv, _pack_refolds(kv, jnp.where(sample_mask, nxt, 0), refolds)
 
 
 # ---------------------------------------------------------------------------
 # The unified ragged step (FLAGS_ragged_step).
 #
-# ONE executable per KV mode serves every phase of a speculative,
+# ONE executable serves every phase of a speculative,
 # chunk-prefilling, continuously-batched serve: each slot's row in the
 # fixed ``[slots, Q_r]`` grid carries its own query span via
 # ``write_caps`` — 1 for a decoding slot, C for a prompt chunk, K+1
@@ -1282,78 +1122,19 @@ def _gpt_mixed_step_q(params, k_pages, v_pages, k_scales, v_scales,
 # per-position targets by phase: row 0 for a decode slot, row C-1 for
 # a slot finishing its prefill, the accept loop for a verify window.
 # Collapsing `_gpt_decode_step` / `_gpt_mixed_step` /
-# `_gpt_spec_verify` (and the `_q` twins) into this one program means
+# `_gpt_spec_verify` into this one program means
 # one compile, one retrace contract, no compile-time phase branch —
 # the "ragged_compiles == 1, {decode,mixed,verify}_compiles == 0"
-# counter assertion tests/test_ragged_step.py pins.
-#
-# The split-path functions above stay byte-identical — they are the
-# FLAGS_ragged_step=off path and the greedy-parity oracle; keeping the
-# twins separate (rather than a mode flag inside one body) is what
-# lets the off path compile the exact same executables as before this
-# feature existed (zero new executables in off mode).
+# counter assertion tests/test_ragged_step.py pins.  The split-path
+# functions above are the FLAGS_ragged_step=off path and the
+# greedy-parity oracle (which of the two goes: ROADMAP Queue 3).
 # ---------------------------------------------------------------------------
-def _mesh_constrain(mesh):
-    """Sharding-constraint applicator for the serving mesh: ``None``
-    (the single-chip path) returns an identity, so the ragged twins
-    trace EXACTLY the ops they always traced — zero sharding machinery
-    on the off path.  With a mesh, ``cst(x, *axes)`` pins ``x`` to
-    ``PartitionSpec(*axes)`` over it (``cst(x)`` = replicated), the
-    GSPMD boundary annotations that turn the one ragged executable
-    into a tensor-parallel program: column-split qkv/fc1 compute runs
-    head-/feature-local, row-split out/fc2 matmuls end in the
-    all-reduce the replicated-residual constraint forces."""
-    if mesh is None:
-        return lambda x, *spec: x
-
-    def cst(x, *spec):
-        return jax.lax.with_sharding_constraint(
-            x, NamedSharding(mesh, PartitionSpec(*spec)))
-
-    return cst
-
-
-def _mesh_paged_attention(mesh):
-    """`pa.paged_attention` for the ragged twins: called directly on the
-    single-chip path (``mesh=None``), and inside a `jax.shard_map` over
-    ``mp`` under the serving mesh.  Heads are already chip-local there
-    (`partition.kv_pages_spec`), so each chip attends over its own
-    head-slice of every page with no communication — but a Mosaic kernel
-    cannot be partitioned by GSPMD from sharding constraints alone, it
-    has to be told per chip, which is what the shard_map does.  Block
-    tables and lengths are replicated host state."""
-    def direct(q, k_pages, v_pages, block_tables, seq_lens, q_offsets,
-               k_scales=None, v_scales=None):
-        return pa.paged_attention(q, k_pages, v_pages, block_tables,
-                                  seq_lens, q_offsets=q_offsets,
-                                  k_scales=k_scales, v_scales=v_scales)
-
-    if mesh is None:
-        return direct
-    heads = PartitionSpec(None, None, "mp", None)  # q, out: [B, Q, H, D]
-    by_head = PartitionSpec("mp")   # pages [Hkv, P, page, D], scales [Hkv, P]
-    rep = PartitionSpec()
-
-    def sharded(q, k_pages, v_pages, block_tables, seq_lens, q_offsets,
-                k_scales=None, v_scales=None):
-        scales = () if k_scales is None else (k_scales, v_scales)
-        return jax.shard_map(
-            direct, mesh=mesh,
-            in_specs=(heads, by_head, by_head, rep, rep, rep)
-            + (by_head,) * len(scales),
-            out_specs=heads, check_vma=False,
-        )(q, k_pages, v_pages, block_tables, seq_lens, q_offsets, *scales)
-
-    return sharded
-
-
-def _gpt_ragged_step(params, k_pages, v_pages, block_tables, seq_lens,
-                     tokens, write_caps, key, *, num_heads, head_dim,
-                     eps, sampler, temperature, top_k, top_p,
-                     mesh=None):
+def _gpt_ragged_step(params, kv, block_tables, seq_lens, tokens,
+                     write_caps, key, *, num_heads, head_dim, eps,
+                     sampler, temperature, top_k, top_p, mesh=None):
     """The unified ragged step: score up to Q_r incoming tokens per
     slot in ONE pass — write rows ``i < write_caps[b]`` into the slot's
-    already-reserved pages (`pa.paged_kv_write` leaves capped rows out),
+    already-reserved pages (`pa.KVPool.write` leaves capped rows out),
     run ragged multi-query paged attention with per-sequence causal
     offsets, and draw a target token at EVERY position with the
     engine's own `sample_logits`.
@@ -1361,9 +1142,9 @@ def _gpt_ragged_step(params, k_pages, v_pages, block_tables, seq_lens,
     tokens: [B, Q_r] int32 — position ``seq_lens[b] + i`` holds
     ``tokens[b, i]``; write_caps: [B] int32 in [0, Q_r] — the row's
     span (0 = the slot sits this step out; its targets are garbage the
-    host ignores); k_pages/v_pages donated (in-place cache update; a
+    host ignores); kv donated (in-place cache update; a
     speculative rejection only shrinks the host's ``seq_lens``).
-    Returns (k_pages, v_pages, targets [B, Q_r] int32).
+    Returns (kv, targets [B, Q_r] int32).
 
     Positions sample with ``fold_in(key, i)`` (the verify convention);
     greedy ignores the key, which is why greedy tokens are
@@ -1373,47 +1154,18 @@ def _gpt_ragged_step(params, k_pages, v_pages, block_tables, seq_lens,
     ``prefill_q_max`` / K to the traffic when decode dominates."""
     b, qn = tokens.shape
     h = num_heads * head_dim
-    cst = _mesh_constrain(mesh)
-    attend = _mesh_paged_attention(mesh)
 
     pos = seq_lens[:, None] + jnp.arange(qn, dtype=jnp.int32)[None, :]
     wpe_max = params["wpe"].shape[0] - 1
     x = params["wte"][tokens] + params["wpe"][jnp.minimum(pos, wpe_max)]
     lens_now = seq_lens + write_caps
+    refolds = 0
 
     for li, blk in enumerate(params["blocks"]):
-        y = _ln(x.reshape(b * qn, h), blk["ln1_w"], blk["ln1_b"], eps)
-        qkv = _wmm(y, blk, "qkv_w") + blk["qkv_b"]
-        # head axis sharded over 'mp' from here: the K/V write and the
-        # paged-attention gather stay chip-local (each chip owns its
-        # head-slice of every page)
-        qkv = cst(qkv.reshape(b, qn, 3, num_heads, head_dim),
-                  None, None, None, "mp", None)
-        q = qkv[:, :, 0]                                 # [B, Q, H, D]
-        k_pages = cst(
-            pa.paged_kv_write(k_pages, li, qkv[:, :, 1], block_tables,
-                              seq_lens, write_caps),
-            None, "mp", None, None, None)
-        v_pages = cst(
-            pa.paged_kv_write(v_pages, li, qkv[:, :, 2], block_tables,
-                              seq_lens, write_caps),
-            None, "mp", None, None, None)
-        attn = cst(attend(q, pa.kv_layer(k_pages, li, head_dim),
-                          pa.kv_layer(v_pages, li, head_dim), block_tables,
-                          lens_now, seq_lens),
-                   None, None, "mp", None)
-        # row-parallel out proj: replicating the residual forces the
-        # cross-chip all-reduce exactly here (heads fuse head-major
-        # into h, so the reshape keeps the 'mp' shards contiguous)
-        x = cst(x + _wmm(attn.reshape(b, qn, h), blk, "out_w")
-                + blk["out_b"])
-        y = _ln(x.reshape(b * qn, h), blk["ln2_w"], blk["ln2_b"], eps)
-        y = cst(jax.nn.gelu(_wmm(y, blk, "fc1_w") + blk["fc1_b"],
-                            approximate=True),
-                None, "mp")
-        # row-parallel fc2: second all-reduce of the block
-        x = cst(x + (_wmm(y, blk, "fc2_w") + blk["fc2_b"]
-                     ).reshape(b, qn, h))
+        x, kv, r = _gpt_layer_bq(
+            blk, li, x, kv, block_tables, seq_lens, write_caps, lens_now,
+            num_heads=num_heads, head_dim=head_dim, eps=eps, mesh=mesh)
+        refolds += r
 
     xf = _ln(x.reshape(b * qn, h), params["lnf_w"], params["lnf_b"], eps)
     logits = _logits_of(params, xf).astype(jnp.float32)
@@ -1426,90 +1178,7 @@ def _gpt_ragged_step(params, k_pages, v_pages, block_tables, seq_lens,
                           top_p=top_p, key=jax.random.fold_in(key, i)))
         for i in range(qn)
     ]
-    return k_pages, v_pages, jnp.stack(targets, axis=1)
-
-
-def _gpt_ragged_step_q(params, k_pages, v_pages, k_scales, v_scales,
-                       block_tables, seq_lens, tokens, write_caps, key,
-                       *, num_heads, head_dim, eps, sampler,
-                       temperature, top_k, top_p, mesh=None):
-    """Quantized-storage `_gpt_ragged_step` (FLAGS_kv_quant=int8):
-    every contributed row quantizes into its slot's pages through
-    `pa.paged_quant_write` (span-aware: capped rows never fold a
-    scale), attention reads through the fused dequant.  Returns
-    ``(k_pages, v_pages, k_scales, v_scales, out)`` with ``out``
-    [B+1, Q_r] int32: rows 0..B-1 the per-position targets, row B the
-    step's refold count packed in column 0 — the one blocking fetch
-    the step already pays carries both."""
-    b, qn = tokens.shape
-    h = num_heads * head_dim
-    num_pages_total = k_pages.shape[2]
-    page = k_pages.shape[3]
-    cst = _mesh_constrain(mesh)
-    attend = _mesh_paged_attention(mesh)
-
-    pos = seq_lens[:, None] + jnp.arange(qn, dtype=jnp.int32)[None, :]
-    wpe_max = params["wpe"].shape[0] - 1
-    x = params["wte"][tokens] + params["wpe"][jnp.minimum(pos, wpe_max)]
-    page_idx, slot = pa.paged_write_indices(
-        block_tables, seq_lens, write_caps, qn, num_pages_total, page)
-    flat_idx = page_idx.reshape(-1)
-    flat_slot = slot.reshape(-1)
-    spans = pa.paged_write_spans(
-        block_tables, seq_lens, write_caps, qn, num_pages_total, page)
-    lens_now = seq_lens + write_caps
-    refolds = jnp.int32(0)
-
-    for li, blk in enumerate(params["blocks"]):
-        y = _ln(x.reshape(b * qn, h), blk["ln1_w"], blk["ln1_b"], eps)
-        qkv = _wmm(y, blk, "qkv_w") + blk["qkv_b"]
-        # head axis sharded over 'mp' from here (see _gpt_ragged_step);
-        # the per-head quant scales shard with their pages, so the
-        # scale fold/refold reductions over head_dim stay chip-local
-        qkv = cst(qkv.reshape(b, qn, 3, num_heads, head_dim),
-                  None, None, None, "mp", None)
-        q = qkv[:, :, 0]                                 # [B, Q, H, D]
-        k_pages, k_scales, rk = pa.paged_quant_write(
-            k_pages, k_scales, li,
-            qkv[:, :, 1].reshape(b * qn, num_heads, head_dim),
-            flat_idx, flat_slot, spans)
-        k_pages = cst(k_pages, None, "mp", None, None, None)
-        k_scales = cst(k_scales, None, "mp", None)
-        v_pages, v_scales, rv = pa.paged_quant_write(
-            v_pages, v_scales, li,
-            qkv[:, :, 2].reshape(b * qn, num_heads, head_dim),
-            flat_idx, flat_slot, spans)
-        v_pages = cst(v_pages, None, "mp", None, None, None)
-        v_scales = cst(v_scales, None, "mp", None)
-        refolds = refolds + rk + rv
-        attn = cst(attend(q, k_pages[li], v_pages[li], block_tables,
-                          lens_now, seq_lens, k_scales[li], v_scales[li]),
-                   None, None, "mp", None)
-        # row-parallel out proj / fc2: the block's two all-reduces
-        x = cst(x + _wmm(attn.reshape(b, qn, h), blk, "out_w")
-                + blk["out_b"])
-        y = _ln(x.reshape(b * qn, h), blk["ln2_w"], blk["ln2_b"], eps)
-        y = cst(jax.nn.gelu(_wmm(y, blk, "fc1_w") + blk["fc1_b"],
-                            approximate=True),
-                None, "mp")
-        x = cst(x + (_wmm(y, blk, "fc2_w") + blk["fc2_b"]
-                     ).reshape(b, qn, h))
-
-    xf = _ln(x.reshape(b * qn, h), params["lnf_w"], params["lnf_b"], eps)
-    logits = _logits_of(params, xf).astype(jnp.float32)
-    logits = logits.reshape(b, qn, -1)
-    targets = [
-        _guard_tokens(
-            logits[:, i],
-            sample_logits(logits[:, i], sampler=sampler,
-                          temperature=temperature, top_k=top_k,
-                          top_p=top_p, key=jax.random.fold_in(key, i)))
-        for i in range(qn)
-    ]
-    out = jnp.stack(targets, axis=1).astype(jnp.int32)
-    pack = jnp.zeros((1, qn), jnp.int32).at[0, 0].set(refolds)
-    return k_pages, v_pages, k_scales, v_scales, \
-        jnp.concatenate([out, pack], axis=0)
+    return kv, _pack_refolds(kv, jnp.stack(targets, axis=1), refolds)
 
 
 def _reset_kv_scales(k_scales, v_scales, fresh_idx):
@@ -1633,26 +1302,16 @@ class DecodeEngine:
         self._pages_per_seq = -(-self._max_seq_len // self._page)
         n_pages = int(num_pages or self._slots * self._pages_per_seq)
         self.pool = KVBlockPool(n_pages)
-        # a float pool's rows are whole 128-lane rows: how the kernel
-        # reads them on the chip, said in the shape
-        shape = (self._num_layers, self._num_heads, n_pages, self._page,
-                 pa.kv_pool_width(self._head_dim, storage_dtype))
-        self._k_pages = jnp.zeros(shape, storage_dtype)
-        self._v_pages = jnp.zeros(shape, storage_dtype)
-        # per-page, per-head dequant scales (quantized mode only):
-        # donated pool state threaded through every step executable
-        # beside the pages — tracecheck's donation pass counts
-        # ``*_scales`` params as pool state
-        self._k_scales = self._v_scales = None
+        # the K/V pages, and the per-page, per-head dequant scales of a
+        # quantized pool: ONE donated argument of every step executable
+        self._kv = pa.KVPool.zeros(self._num_layers, self._num_heads,
+                                   n_pages, self._page, self._head_dim,
+                                   storage_dtype)
         self._scale_reset_fn = None
         # pages the allocator handed out since the last scale reset —
         # their (possibly stale) scale entries zero on the next
         # between-steps flush, BEFORE any quantized write sees them
         self._fresh_pages: List[int] = []
-        if self._kv_quant:
-            sshape = (self._num_layers, self._num_heads, n_pages)
-            self._k_scales = jnp.zeros(sshape, jnp.float32)
-            self._v_scales = jnp.zeros(sshape, jnp.float32)
 
         self._bt = np.zeros((self._slots, self._pages_per_seq), np.int32)
         self._lens = np.zeros(self._slots, np.int32)
@@ -1803,7 +1462,7 @@ class DecodeEngine:
         # unified ragged step (explicit arg wins, else
         # FLAGS_ragged_step): decode, mixed prefill+decode, and
         # speculative-verify traffic all dispatch the ONE
-        # `_gpt_ragged_step[_q]` executable, each row carrying its own
+        # `_gpt_ragged_step` executable, each row carrying its own
         # query span.  Off (the default) keeps the split executables
         # byte-identical — the greedy-parity oracle.
         ragged_explicit = ragged_step is not None
@@ -1830,13 +1489,9 @@ class DecodeEngine:
         self._mesh = None
         self._mesh_mp = 1
         self._repl_sharding = None
-        self._page_sharding = None
-        self._scale_sharding = None
         if serve_mesh:
             from ..parallel.partition import (build_mesh,
                                               gpt_serving_rules,
-                                              kv_pages_spec,
-                                              kv_scales_spec,
                                               match_partition_rules,
                                               parse_mesh_spec)
 
@@ -1864,25 +1519,13 @@ class DecodeEngine:
             self._mesh_mp = mp
             self._repl_sharding = NamedSharding(self._mesh,
                                                 PartitionSpec())
-            self._page_sharding = NamedSharding(self._mesh,
-                                                kv_pages_spec())
-            self._scale_sharding = NamedSharding(self._mesh,
-                                                 kv_scales_spec())
             specs = match_partition_rules(gpt_serving_rules(),
                                           self._params)
             self._params = jax.tree_util.tree_map(
                 lambda x, s: jax.device_put(
                     x, NamedSharding(self._mesh, s)),
                 self._params, specs)
-            self._k_pages = jax.device_put(self._k_pages,
-                                           self._page_sharding)
-            self._v_pages = jax.device_put(self._v_pages,
-                                           self._page_sharding)
-            if self._kv_quant:
-                self._k_scales = jax.device_put(self._k_scales,
-                                                self._scale_sharding)
-                self._v_scales = jax.device_put(self._v_scales,
-                                                self._scale_sharding)
+            self._kv = self._kv.sharded(self._mesh)
         # the unified executable's per-slot row width: wide enough for
         # the widest span any phase contributes — a decode row (1), a
         # prompt chunk (Q_max), a verify window (K+1).  Rows past a
@@ -2198,7 +1841,7 @@ class DecodeEngine:
                 # one-to-one); adding the mode string would break
                 # fingerprint compatibility with pre-quant journals
                 # for off-mode engines whose executables ARE identical
-                str(self._k_pages.dtype),
+                str(self._kv.dtype),
                 tuple(sorted(self._sampling.items())),
                 self._spec.k if self._spec else 0,
                 self._chunked_cfg)).encode())
@@ -2530,24 +2173,28 @@ class DecodeEngine:
         buf[:len(ids)] = ids
         fn = self._scale_reset_tracker()
         with self._phase("cache"):
-            self._k_scales, self._v_scales = fn(
-                self._k_scales, self._v_scales, self._dev(buf))
-            if self._spec is not None and \
-                    getattr(self._spec.drafter, "_k_scales", None) \
-                    is not None:
-                d = self._spec.drafter
-                dfn = d._scale_reset_tracker()
-                d._k_scales, d._v_scales = dfn(
-                    d._k_scales, d._v_scales, jnp.asarray(buf))
+            self._kv = self._kv.with_scales(
+                *fn(*self._kv.scales, self._dev(buf)))
+            d = self._spec.drafter if self._spec is not None else None
+            if getattr(d, "_kv", None) is not None:
+                # the draft model's pool quantizes with the engine's
+                d._kv = d._kv.with_scales(
+                    *d._scale_reset_tracker()(
+                        *d._kv.scales, jnp.asarray(buf)))
         _stats_add(kv_quant_pages=len(ids))
         _obs.KV_QUANT_PAGES.inc(len(ids))
 
-    def _note_refolds(self, n: int):
-        """Account one quantized step's scale refolds (the packed
-        count the step executable returned with its tokens)."""
+    def _note_refolds(self, out):
+        """A step's fetched output as its tokens: a quantized pool's
+        step packed its scale-refold count behind them
+        (`_pack_refolds`) — account it and take it off."""
+        if not self._kv_quant:
+            return out
+        n = int(out[-1].flat[0])
         if n:
-            _stats_add(kv_quant_refolds=int(n))
-            _obs.KV_QUANT_REFOLDS.inc(int(n))
+            _stats_add(kv_quant_refolds=n)
+            _obs.KV_QUANT_REFOLDS.inc(n)
+        return out[:-1]
 
     def _kv_byte_occupancy(self) -> dict:
         """Device bytes the KV pool currently holds in non-free pages
@@ -2555,13 +2202,13 @@ class DecodeEngine:
         the density numbers the flight recorder stamps per step and
         tools/bench_kv_quant.py gates on."""
         per_page_payload = 2 * self._num_layers * self._num_heads * \
-            self._page * self._head_dim * self._k_pages.dtype.itemsize
+            self._page * self._head_dim * self._kv.dtype.itemsize
         per_page_scales = 0
         if self._kv_quant:
             per_page_scales = 2 * self._num_layers * self._num_heads * 4
         used = self.pool.used_count
         return {
-            "dtype": str(self._k_pages.dtype),
+            "dtype": str(self._kv.dtype),
             "payload_bytes": used * per_page_payload,
             "scale_bytes": used * per_page_scales,
             "bytes_per_token": (per_page_payload + per_page_scales)
@@ -2826,25 +2473,14 @@ class DecodeEngine:
             # prompt-length bucket is an expected warmup event, not a
             # steady-state retrace) — only per-bucket recompiles count
             # toward retraces_after_warmup
-            if self._kv_quant:
-                fn = _JitTracker(
-                    functools.partial(_gpt_prefill_q,
-                                      num_heads=self._num_heads,
-                                      head_dim=self._head_dim,
-                                      eps=self._eps, **self._sampling),
-                    "prefill_compiles", donate_argnums=(4, 5, 6, 7),
-                    site=f"DecodeEngine prefill bucket {bucket} "
-                         f"(_gpt_prefill_q)")
-            else:
-                fn = _JitTracker(
-                    functools.partial(_gpt_prefill,
-                                      num_heads=self._num_heads,
-                                      head_dim=self._head_dim,
-                                      eps=self._eps, **self._sampling),
-                    "prefill_compiles", donate_argnums=(4, 5),
-                    site=f"DecodeEngine prefill bucket {bucket} "
-                         f"(_gpt_prefill)")
-            self._prefill_fns[bucket] = fn
+            fn = self._prefill_fns[bucket] = _JitTracker(
+                functools.partial(_gpt_prefill,
+                                  num_heads=self._num_heads,
+                                  head_dim=self._head_dim,
+                                  eps=self._eps, **self._sampling),
+                "prefill_compiles", donate_argnums=(4,),
+                site=f"DecodeEngine prefill bucket {bucket} "
+                     f"(_gpt_prefill)")
         t0 = time.perf_counter()
         t0_ns = _obs.now_ns()
         # prefill keys live in the upper fold_in window (decode steps
@@ -2859,24 +2495,10 @@ class DecodeEngine:
         fr = self._flight
         self._flush_fresh_scales()
         with self._phase("prefill"):
-            if self._kv_quant:
-                (self._k_pages, self._v_pages, self._k_scales,
-                 self._v_scales, tok) = fn(
-                    self._params, self._dev(ids), jnp.int32(p_len),
-                    self._dev(self._bt[slot]), self._k_pages,
-                    self._v_pages, self._k_scales, self._v_scales,
-                    self._dev(key))
-            else:
-                self._k_pages, self._v_pages, tok = fn(
-                    self._params, self._dev(ids), jnp.int32(p_len),
-                    self._dev(self._bt[slot]), self._k_pages,
-                    self._v_pages, self._dev(key))
-        tok = self._host_fetch(tok)
-        if self._kv_quant:
-            self._note_refolds(int(tok[1]))
-            tok = int(tok[0])
-        else:
-            tok = int(tok)
+            self._kv, tok = fn(
+                self._params, self._dev(ids), jnp.int32(p_len),
+                self._dev(self._bt[slot]), self._kv, self._dev(key))
+        tok = int(self._note_refolds(self._host_fetch(tok)).flat[0])
         # the pass's wall time is real either way; the token count,
         # prefill count and TTFT stamp wait for the NaN-sentinel check
         # below — a quarantined prefill emitted nothing (mirrors the
@@ -3318,51 +2940,32 @@ class DecodeEngine:
     def _mixed_fn_tracker(self) -> _JitTracker:
         fn = self._mixed_fn
         if fn is None:
-            if self._kv_quant:
-                fn = self._mixed_fn = _JitTracker(
-                    functools.partial(_gpt_mixed_step_q,
-                                      num_heads=self._num_heads,
-                                      head_dim=self._head_dim,
-                                      eps=self._eps, **self._sampling),
-                    "mixed_compiles", donate_argnums=(1, 2, 3, 4),
-                    site="DecodeEngine mixed step (_gpt_mixed_step_q)")
-            else:
-                fn = self._mixed_fn = _JitTracker(
-                    functools.partial(_gpt_mixed_step,
-                                      num_heads=self._num_heads,
-                                      head_dim=self._head_dim,
-                                      eps=self._eps, **self._sampling),
-                    "mixed_compiles", donate_argnums=(1, 2),
-                    site="DecodeEngine mixed step (_gpt_mixed_step)")
+            fn = self._mixed_fn = _JitTracker(
+                functools.partial(_gpt_mixed_step,
+                                  num_heads=self._num_heads,
+                                  head_dim=self._head_dim,
+                                  eps=self._eps, **self._sampling),
+                "mixed_compiles", donate_argnums=(1,),
+                site="DecodeEngine mixed step (_gpt_mixed_step)")
         return fn
 
     def _ragged_fn_tracker(self) -> _JitTracker:
         """The ONE step executable of the ragged path
         (FLAGS_ragged_step): decode rows, prefill chunks, and
         speculative verify windows all dispatch through this tracker,
-        so steady-state serving compiles exactly one executable per KV
-        mode (counter: ``ragged_compiles``) and a warm retrace of it is
+        so steady-state serving compiles exactly one executable
+        (counter: ``ragged_compiles``) and a warm retrace of it is
         attributed to ``ragged_retraces``."""
         fn = self._ragged_fn
         if fn is None:
-            if self._kv_quant:
-                fn = self._ragged_fn = _JitTracker(
-                    functools.partial(_gpt_ragged_step_q,
-                                      num_heads=self._num_heads,
-                                      head_dim=self._head_dim,
-                                      eps=self._eps,
-                                      mesh=self._mesh, **self._sampling),
-                    "ragged_compiles", donate_argnums=(1, 2, 3, 4),
-                    site="DecodeEngine ragged step (_gpt_ragged_step_q)")
-            else:
-                fn = self._ragged_fn = _JitTracker(
-                    functools.partial(_gpt_ragged_step,
-                                      num_heads=self._num_heads,
-                                      head_dim=self._head_dim,
-                                      eps=self._eps,
-                                      mesh=self._mesh, **self._sampling),
-                    "ragged_compiles", donate_argnums=(1, 2),
-                    site="DecodeEngine ragged step (_gpt_ragged_step)")
+            fn = self._ragged_fn = _JitTracker(
+                functools.partial(_gpt_ragged_step,
+                                  num_heads=self._num_heads,
+                                  head_dim=self._head_dim,
+                                  eps=self._eps,
+                                  mesh=self._mesh, **self._sampling),
+                "ragged_compiles", donate_argnums=(1,),
+                site="DecodeEngine ragged step (_gpt_ragged_step)")
         return fn
 
     def _mixed_step(self, decode_rows=True) -> bool:
@@ -3441,34 +3044,13 @@ class DecodeEngine:
                 # sample_mask operands — every position draws a
                 # target and the host selects each slot's span-end
                 # row after the fetch below
-                if self._kv_quant:
-                    (self._k_pages, self._v_pages, self._k_scales,
-                     self._v_scales, toks) = fn(
-                        self._params, self._k_pages, self._v_pages,
-                        self._k_scales, self._v_scales,
-                        self._dev(self._bt),
-                        self._dev(self._lens),
-                        self._dev(tokens), self._dev(caps),
-                        self._dev(key))
-                else:
-                    self._k_pages, self._v_pages, toks = fn(
-                        self._params, self._k_pages, self._v_pages,
-                        self._dev(self._bt),
-                        self._dev(self._lens),
-                        self._dev(tokens), self._dev(caps),
-                        self._dev(key))
-            elif self._kv_quant:
-                (self._k_pages, self._v_pages, self._k_scales,
-                 self._v_scales, toks) = fn(
-                    self._params, self._k_pages, self._v_pages,
-                    self._k_scales, self._v_scales,
-                    jnp.asarray(self._bt), jnp.asarray(self._lens),
-                    jnp.asarray(tokens), jnp.asarray(caps),
-                    jnp.asarray(sample_idx),
-                    jnp.asarray(sample_mask), key)
+                self._kv, toks = fn(
+                    self._params, self._kv, self._dev(self._bt),
+                    self._dev(self._lens), self._dev(tokens),
+                    self._dev(caps), self._dev(key))
             else:
-                self._k_pages, self._v_pages, toks = fn(
-                    self._params, self._k_pages, self._v_pages,
+                self._kv, toks = fn(
+                    self._params, self._kv,
                     jnp.asarray(self._bt), jnp.asarray(self._lens),
                     jnp.asarray(tokens), jnp.asarray(caps),
                     jnp.asarray(sample_idx),
@@ -3484,11 +3066,7 @@ class DecodeEngine:
                 self._profiling.probe(
                     "ragged" if self._ragged else "mixed",
                     toks, t0, t0_ns)
-        toks = self._host_fetch(toks)
-        if self._kv_quant:
-            self._note_refolds(int(toks[-1, 0] if self._ragged
-                                   else toks[-1]))
-            toks = toks[:-1]
+        toks = self._note_refolds(self._host_fetch(toks))
         if self._ragged:
             # host-side span-end selection: a decode row's token sits
             # at column 0, a finishing chunk's at column c-1; padding
@@ -4030,22 +3608,13 @@ class DecodeEngine:
 
         fn = self._decode_fn
         if fn is None:
-            if self._kv_quant:
-                fn = self._decode_fn = _JitTracker(
-                    functools.partial(_gpt_decode_step_q,
-                                      num_heads=self._num_heads,
-                                      head_dim=self._head_dim,
-                                      eps=self._eps, **self._sampling),
-                    "decode_compiles", donate_argnums=(1, 2, 3, 4),
-                    site="DecodeEngine decode step (_gpt_decode_step_q)")
-            else:
-                fn = self._decode_fn = _JitTracker(
-                    functools.partial(_gpt_decode_step,
-                                      num_heads=self._num_heads,
-                                      head_dim=self._head_dim,
-                                      eps=self._eps, **self._sampling),
-                    "decode_compiles", donate_argnums=(1, 2),
-                    site="DecodeEngine decode step (_gpt_decode_step)")
+            fn = self._decode_fn = _JitTracker(
+                functools.partial(_gpt_decode_step,
+                                  num_heads=self._num_heads,
+                                  head_dim=self._head_dim,
+                                  eps=self._eps, **self._sampling),
+                "decode_compiles", donate_argnums=(1,),
+                site="DecodeEngine decode step (_gpt_decode_step)")
 
         if self._fault is not None:
             self._resilience.step_fault_point("decode_step")
@@ -4057,30 +3626,17 @@ class DecodeEngine:
         t0 = time.perf_counter()
         t0_ns = _obs.now_ns()
         with self._phase("decode"):
-            if self._kv_quant:
-                (self._k_pages, self._v_pages, self._k_scales,
-                 self._v_scales, toks) = fn(
-                    self._params, self._k_pages, self._v_pages,
-                    self._k_scales, self._v_scales,
-                    jnp.asarray(self._bt), jnp.asarray(self._lens),
-                    jnp.asarray(self._last),
-                    jnp.asarray(self._active), key)
-            else:
-                self._k_pages, self._v_pages, toks = fn(
-                    self._params, self._k_pages, self._v_pages,
-                    jnp.asarray(self._bt), jnp.asarray(self._lens),
-                    jnp.asarray(self._last),
-                    jnp.asarray(self._active), key)
+            self._kv, toks = fn(
+                self._params, self._kv,
+                jnp.asarray(self._bt), jnp.asarray(self._lens),
+                jnp.asarray(self._last), jnp.asarray(self._active), key)
             if self._profiling is not None:
                 # sampled device-sync probe: block on the step's
                 # output INSIDE the phase (the phase wall absorbs
                 # the wait) so dispatch-start -> ready is the
                 # executable's measured device seconds
                 self._profiling.probe("decode", toks, t0, t0_ns)
-        toks = self._host_fetch(toks)
-        if self._kv_quant:
-            self._note_refolds(int(toks[-1]))
-            toks = toks[:-1]
+        toks = self._note_refolds(self._host_fetch(toks))
         dt = time.perf_counter() - t0
         self._batch_s += dt
         if self._fault is not None:

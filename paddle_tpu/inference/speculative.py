@@ -72,11 +72,10 @@ import jax.numpy as jnp
 
 from .serving import (RNG_DECODE_DOMAIN, _JitTracker,
                       _extract_gpt_params, _fold_counter,
-                      _gpt_decode_step, _gpt_decode_step_q,
-                      _gpt_mixed_step, _gpt_mixed_step_q, _gpt_prefill,
-                      _gpt_prefill_q, _guard_tokens, _ln, _logits_of,
-                      _quantize_gpt_params, _reset_kv_scales,
-                      _stats_add, _wmm, sample_logits)
+                      _gpt_decode_step, _gpt_layer_bq, _gpt_mixed_step,
+                      _gpt_prefill, _guard_tokens, _ln, _logits_of,
+                      _pack_refolds, _quantize_gpt_params,
+                      _reset_kv_scales, _stats_add, sample_logits)
 from .. import observability as _obs
 from ..ops.pallas import paged_attention as pa
 
@@ -87,11 +86,11 @@ __all__ = ["Drafter", "PromptLookupDrafter", "DraftModelDrafter",
 # ---------------------------------------------------------------------------
 # The multi-token verify step (pure, jit-compiled once per engine)
 # ---------------------------------------------------------------------------
-def _gpt_spec_verify(params, k_pages, v_pages, block_tables, seq_lens,
-                     tokens, write_caps, key, *, num_heads, head_dim,
-                     eps, sampler, temperature, top_k, top_p):
+def _gpt_spec_verify(params, kv, block_tables, seq_lens, tokens,
+                     write_caps, key, *, num_heads, head_dim, eps,
+                     sampler, temperature, top_k, top_p):
     """Score Q = K+1 incoming tokens per slot in ONE pass: write their
-    K/V into the slots' already-reserved pages (`pa.paged_kv_write`,
+    K/V into the slots' already-reserved pages (`pa.KVPool.write`,
     write-capped per sequence so rows past a request's token budget
     are left out), run ragged multi-query paged attention with per-sequence
     causal offsets, and draw a target token at every position with the
@@ -101,9 +100,17 @@ def _gpt_spec_verify(params, k_pages, v_pages, block_tables, seq_lens,
     ``tokens[b, i]`` (the last sampled token followed by the K drafts);
     write_caps: [B] int32 in [0, Q] — rows ``i < write_caps[b]`` are
     written and attendable (0 = inactive slot -> zero logits, target 0
-    ignored by the host); k_pages/v_pages donated: the K/V write is in
+    ignored by the host); kv donated: the K/V write is in
     place, and a later rejection only shrinks the host's ``seq_lens``.
-    Returns (k_pages, v_pages, targets [B, Q] int32).
+    Returns (kv, targets [B, Q] int32).
+
+    Quantized pools: a REJECTED draft row's absmax may have grown a
+    page scale before the host rolled ``seq_lens`` back, so a
+    speculative quantized serve can quantize slightly differently than
+    a non-speculative quantized serve over the same tokens (greedy
+    equality holds for float pools and for non-speculative quantized
+    engines; speculative quantized mode is gated on measured
+    token-match instead).
     """
     b, qn = tokens.shape
     h = num_heads * head_dim
@@ -112,27 +119,13 @@ def _gpt_spec_verify(params, k_pages, v_pages, block_tables, seq_lens,
     wpe_max = params["wpe"].shape[0] - 1
     x = params["wte"][tokens] + params["wpe"][jnp.minimum(pos, wpe_max)]
     lens_now = seq_lens + write_caps
+    refolds = 0
 
     for li, blk in enumerate(params["blocks"]):
-        y = _ln(x.reshape(b * qn, h), blk["ln1_w"], blk["ln1_b"], eps)
-        qkv = _wmm(y, blk, "qkv_w") + blk["qkv_b"]
-        qkv = qkv.reshape(b, qn, 3, num_heads, head_dim)
-        q = qkv[:, :, 0]                                 # [B, Q, H, D]
-        k_pages = pa.paged_kv_write(k_pages, li, qkv[:, :, 1],
-                                    block_tables, seq_lens, write_caps)
-        v_pages = pa.paged_kv_write(v_pages, li, qkv[:, :, 2],
-                                    block_tables, seq_lens, write_caps)
-        attn = pa.paged_attention(q, pa.kv_layer(k_pages, li, head_dim),
-                                  pa.kv_layer(v_pages, li, head_dim),
-                                  block_tables, lens_now,
-                                  q_offsets=seq_lens)
-        x = x + _wmm(attn.reshape(b, qn, h), blk, "out_w") \
-            + blk["out_b"]
-        y = _ln(x.reshape(b * qn, h), blk["ln2_w"], blk["ln2_b"], eps)
-        y = jax.nn.gelu(_wmm(y, blk, "fc1_w") + blk["fc1_b"],
-                        approximate=True)
-        x = x + (_wmm(y, blk, "fc2_w") + blk["fc2_b"]
-                 ).reshape(b, qn, h)
+        x, kv, r = _gpt_layer_bq(
+            blk, li, x, kv, block_tables, seq_lens, write_caps, lens_now,
+            num_heads=num_heads, head_dim=head_dim, eps=eps)
+        refolds += r
 
     xf = _ln(x.reshape(b * qn, h), params["lnf_w"], params["lnf_b"], eps)
     logits = _logits_of(params, xf).astype(jnp.float32)
@@ -148,89 +141,7 @@ def _gpt_spec_verify(params, k_pages, v_pages, block_tables, seq_lens,
                           top_p=top_p, key=jax.random.fold_in(key, i)))
         for i in range(qn)
     ]
-    return k_pages, v_pages, jnp.stack(targets, axis=1)
-
-
-def _gpt_spec_verify_q(params, k_pages, v_pages, k_scales, v_scales,
-                       block_tables, seq_lens, tokens, write_caps, key,
-                       *, num_heads, head_dim, eps, sampler,
-                       temperature, top_k, top_p):
-    """Quantized-storage `_gpt_spec_verify` (FLAGS_kv_quant=int8): the
-    verify window's K/V rows quantize into the slots' pages through
-    `pa.paged_quant_write` (per-head absmax folded into the running
-    page scales, existing rows refolded on growth) and the multi-query
-    attention reads through the fused dequant.  Returns ``(k_pages,
-    v_pages, k_scales, v_scales, out)`` with ``out`` [B+1, Q] int32:
-    rows 0..B-1 are the per-position targets, row B packs the step's
-    refold count in column 0 — the host learns both from the one fetch
-    the round already pays.
-
-    Quantization caveat the docs spell out: a REJECTED draft row's
-    absmax may have grown a page scale before the host rolled
-    ``seq_lens`` back, so a speculative quantized serve can quantize
-    slightly differently than a non-speculative quantized serve over
-    the same tokens (greedy equality holds at the off setting and for
-    non-speculative quantized engines; speculative quantized mode is
-    gated on measured token-match instead)."""
-    b, qn = tokens.shape
-    h = num_heads * head_dim
-    num_pages_total = k_pages.shape[2]
-    page = k_pages.shape[3]
-
-    pos = seq_lens[:, None] + jnp.arange(qn, dtype=jnp.int32)[None, :]
-    wpe_max = params["wpe"].shape[0] - 1
-    x = params["wte"][tokens] + params["wpe"][jnp.minimum(pos, wpe_max)]
-    page_idx, slot = pa.paged_write_indices(
-        block_tables, seq_lens, write_caps, qn, num_pages_total, page)
-    flat_idx = page_idx.reshape(-1)
-    flat_slot = slot.reshape(-1)
-    spans = pa.paged_write_spans(
-        block_tables, seq_lens, write_caps, qn, num_pages_total, page)
-    lens_now = seq_lens + write_caps
-    refolds = jnp.int32(0)
-
-    for li, blk in enumerate(params["blocks"]):
-        y = _ln(x.reshape(b * qn, h), blk["ln1_w"], blk["ln1_b"], eps)
-        qkv = _wmm(y, blk, "qkv_w") + blk["qkv_b"]
-        qkv = qkv.reshape(b, qn, 3, num_heads, head_dim)
-        q = qkv[:, :, 0]                                 # [B, Q, H, D]
-        k_pages, k_scales, rk = pa.paged_quant_write(
-            k_pages, k_scales, li,
-            qkv[:, :, 1].reshape(b * qn, num_heads, head_dim),
-            flat_idx, flat_slot, spans)
-        v_pages, v_scales, rv = pa.paged_quant_write(
-            v_pages, v_scales, li,
-            qkv[:, :, 2].reshape(b * qn, num_heads, head_dim),
-            flat_idx, flat_slot, spans)
-        refolds = refolds + rk + rv
-        attn = pa.paged_attention(q, k_pages[li], v_pages[li],
-                                  block_tables, lens_now,
-                                  q_offsets=seq_lens,
-                                  k_scales=k_scales[li],
-                                  v_scales=v_scales[li])
-        x = x + _wmm(attn.reshape(b, qn, h), blk, "out_w") \
-            + blk["out_b"]
-        y = _ln(x.reshape(b * qn, h), blk["ln2_w"], blk["ln2_b"], eps)
-        y = jax.nn.gelu(_wmm(y, blk, "fc1_w") + blk["fc1_b"],
-                        approximate=True)
-        x = x + (_wmm(y, blk, "fc2_w") + blk["fc2_b"]
-                 ).reshape(b, qn, h)
-
-    xf = _ln(x.reshape(b * qn, h), params["lnf_w"], params["lnf_b"], eps)
-    logits = _logits_of(params, xf).astype(jnp.float32)
-    logits = logits.reshape(b, qn, -1)
-    targets = [
-        _guard_tokens(
-            logits[:, i],
-            sample_logits(logits[:, i], sampler=sampler,
-                          temperature=temperature, top_k=top_k,
-                          top_p=top_p, key=jax.random.fold_in(key, i)))
-        for i in range(qn)
-    ]
-    out = jnp.stack(targets, axis=1).astype(jnp.int32)
-    pack = jnp.zeros((1, qn), jnp.int32).at[0, 0].set(refolds)
-    return k_pages, v_pages, k_scales, v_scales, \
-        jnp.concatenate([out, pack], axis=0)
+    return kv, _pack_refolds(kv, jnp.stack(targets, axis=1), refolds)
 
 
 # ---------------------------------------------------------------------------
@@ -403,57 +314,32 @@ class DraftModelDrafter(Drafter):
             _obs.WEIGHT_QUANT_SAVED_BYTES.inc(
                 saved, engine=engine._engine_id)
         n_layers = len(self._params["blocks"])
-        # the draft cache quantizes WITH the engine (same page ids,
-        # same storage dtype, its own scale arrays): the density win
-        # covers both pools, and the drafter's executables follow the
-        # same packed-output/donation conventions as the engine's
-        self._quant = bool(engine._kv_quant)
-        dtype = engine._k_pages.dtype
-        shape = (n_layers, self._num_heads, engine.pool.num_pages,
-                 engine._page, pa.kv_pool_width(self._head_dim, dtype))
-        self._k_pages = jnp.zeros(shape, dtype)
-        self._v_pages = jnp.zeros(shape, dtype)
-        self._k_scales = self._v_scales = None
+        # the draft cache is stored as the engine's is (same page ids,
+        # same storage dtype, its own scale arrays when quantized): the
+        # density win covers both pools
+        self._kv = pa.KVPool.zeros(
+            n_layers, self._num_heads, engine.pool.num_pages, engine._page,
+            self._head_dim, engine._kv.dtype)
         self._scale_reset_fn = None
-        if self._quant:
-            sshape = (n_layers, self._num_heads, engine.pool.num_pages)
-            self._k_scales = jnp.zeros(sshape, jnp.float32)
-            self._v_scales = jnp.zeros(sshape, jnp.float32)
         self._lens = np.zeros(engine._slots, np.int32)
         greedy = dict(sampler="greedy", temperature=1.0, top_k=0,
                       top_p=1.0)
         self._greedy = greedy
         self._chunk_fn = None  # chunked prefill ingest (lazy)
-        if self._quant:
-            self._catch_fn = _JitTracker(
-                functools.partial(_gpt_spec_verify_q,
-                                  num_heads=self._num_heads,
-                                  head_dim=self._head_dim,
-                                  eps=self._eps, **greedy),
-                "draft_compiles", donate_argnums=(1, 2, 3, 4),
-                site="DraftModelDrafter catch-up (_gpt_spec_verify_q)")
-            self._step_fn = _JitTracker(
-                functools.partial(_gpt_decode_step_q,
-                                  num_heads=self._num_heads,
-                                  head_dim=self._head_dim,
-                                  eps=self._eps, **greedy),
-                "draft_compiles", donate_argnums=(1, 2, 3, 4),
-                site="DraftModelDrafter step (_gpt_decode_step_q)")
-        else:
-            self._catch_fn = _JitTracker(
-                functools.partial(_gpt_spec_verify,
-                                  num_heads=self._num_heads,
-                                  head_dim=self._head_dim,
-                                  eps=self._eps, **greedy),
-                "draft_compiles", donate_argnums=(1, 2),
-                site="DraftModelDrafter catch-up (_gpt_spec_verify)")
-            self._step_fn = _JitTracker(
-                functools.partial(_gpt_decode_step,
-                                  num_heads=self._num_heads,
-                                  head_dim=self._head_dim,
-                                  eps=self._eps, **greedy),
-                "draft_compiles", donate_argnums=(1, 2),
-                site="DraftModelDrafter step (_gpt_decode_step)")
+        self._catch_fn = _JitTracker(
+            functools.partial(_gpt_spec_verify,
+                              num_heads=self._num_heads,
+                              head_dim=self._head_dim,
+                              eps=self._eps, **greedy),
+            "draft_compiles", donate_argnums=(1,),
+            site="DraftModelDrafter catch-up (_gpt_spec_verify)")
+        self._step_fn = _JitTracker(
+            functools.partial(_gpt_decode_step,
+                              num_heads=self._num_heads,
+                              head_dim=self._head_dim,
+                              eps=self._eps, **greedy),
+            "draft_compiles", donate_argnums=(1,),
+            site="DraftModelDrafter step (_gpt_decode_step)")
         self._prefill_fns = {}
 
     def _scale_reset_tracker(self) -> _JitTracker:
@@ -492,45 +378,22 @@ class DraftModelDrafter(Drafter):
         ids[0, :p_len] = req.prompt_ids
         fn = self._prefill_fns.get(bucket)
         if fn is None:
-            if self._quant:
-                fn = _JitTracker(
-                    functools.partial(_gpt_prefill_q,
-                                      num_heads=self._num_heads,
-                                      head_dim=self._head_dim,
-                                      eps=self._eps, sampler="greedy",
-                                      temperature=1.0, top_k=0,
-                                      top_p=1.0),
-                    "draft_compiles", donate_argnums=(4, 5, 6, 7),
-                    site=f"DraftModelDrafter prefill bucket {bucket} "
-                         f"(_gpt_prefill_q)")
-            else:
-                fn = _JitTracker(
-                    functools.partial(_gpt_prefill,
-                                      num_heads=self._num_heads,
-                                      head_dim=self._head_dim,
-                                      eps=self._eps, sampler="greedy",
-                                      temperature=1.0, top_k=0,
-                                      top_p=1.0),
-                    "draft_compiles", donate_argnums=(4, 5),
-                    site=f"DraftModelDrafter prefill bucket {bucket} "
-                         f"(_gpt_prefill)")
-            self._prefill_fns[bucket] = fn
+            fn = self._prefill_fns[bucket] = _JitTracker(
+                functools.partial(_gpt_prefill,
+                                  num_heads=self._num_heads,
+                                  head_dim=self._head_dim,
+                                  eps=self._eps, **self._greedy),
+                "draft_compiles", donate_argnums=(4,),
+                site=f"DraftModelDrafter prefill bucket {bucket} "
+                     f"(_gpt_prefill)")
         t0 = time.perf_counter()
-        if self._quant:
-            # the sampled-token/refold pack is deliberately NOT fetched
-            # (the draft's sample is unused), so no extra host sync —
-            # draft-side refolds go uncounted by design
-            (self._k_pages, self._v_pages, self._k_scales,
-             self._v_scales, _) = fn(
-                self._params, jnp.asarray(ids), jnp.int32(p_len),
-                jnp.asarray(eng._bt[slot]), self._k_pages,
-                self._v_pages, self._k_scales, self._v_scales,
-                eng._key)
-        else:
-            self._k_pages, self._v_pages, _ = fn(
-                self._params, jnp.asarray(ids), jnp.int32(p_len),
-                jnp.asarray(eng._bt[slot]), self._k_pages,
-                self._v_pages, eng._key)
+        # the sampled token (and a quantized pool's refold count behind
+        # it) is deliberately NOT fetched — the draft's sample is
+        # unused — so no extra host sync: draft-side prefill refolds go
+        # uncounted by design
+        self._kv, _ = fn(
+            self._params, jnp.asarray(ids), jnp.int32(p_len),
+            jnp.asarray(eng._bt[slot]), self._kv, eng._key)
         _stats_add(draft_time_s=time.perf_counter() - t0)
         self._lens[slot] = p_len
 
@@ -546,42 +409,21 @@ class DraftModelDrafter(Drafter):
         eng = self.engine
         fn = self._chunk_fn
         if fn is None:
-            if self._quant:
-                fn = self._chunk_fn = _JitTracker(
-                    functools.partial(_gpt_mixed_step_q,
-                                      num_heads=self._num_heads,
-                                      head_dim=self._head_dim,
-                                      eps=self._eps, **self._greedy),
-                    "draft_compiles", donate_argnums=(1, 2, 3, 4),
-                    site="DraftModelDrafter chunk ingest "
-                         "(_gpt_mixed_step_q)")
-            else:
-                fn = self._chunk_fn = _JitTracker(
-                    functools.partial(_gpt_mixed_step,
-                                      num_heads=self._num_heads,
-                                      head_dim=self._head_dim,
-                                      eps=self._eps, **self._greedy),
-                    "draft_compiles", donate_argnums=(1, 2),
-                    site="DraftModelDrafter chunk ingest "
-                         "(_gpt_mixed_step)")
+            fn = self._chunk_fn = _JitTracker(
+                functools.partial(_gpt_mixed_step,
+                                  num_heads=self._num_heads,
+                                  head_dim=self._head_dim,
+                                  eps=self._eps, **self._greedy),
+                "draft_compiles", donate_argnums=(1,),
+                site="DraftModelDrafter chunk ingest (_gpt_mixed_step)")
         caps = np.asarray(caps, np.int32)
         t0 = time.perf_counter()
-        if self._quant:
-            (self._k_pages, self._v_pages, self._k_scales,
-             self._v_scales, _) = fn(
-                self._params, self._k_pages, self._v_pages,
-                self._k_scales, self._v_scales,
-                jnp.asarray(eng._bt), jnp.asarray(self._lens),
-                jnp.asarray(tokens), jnp.asarray(caps),
-                jnp.zeros(eng._slots, jnp.int32),
-                jnp.zeros(eng._slots, bool), eng._key)
-        else:
-            self._k_pages, self._v_pages, _ = fn(
-                self._params, self._k_pages, self._v_pages,
-                jnp.asarray(eng._bt), jnp.asarray(self._lens),
-                jnp.asarray(tokens), jnp.asarray(caps),
-                jnp.zeros(eng._slots, jnp.int32),
-                jnp.zeros(eng._slots, bool), eng._key)
+        self._kv, _ = fn(
+            self._params, self._kv,
+            jnp.asarray(eng._bt), jnp.asarray(self._lens),
+            jnp.asarray(tokens), jnp.asarray(caps),
+            jnp.zeros(eng._slots, jnp.int32),
+            jnp.zeros(eng._slots, bool), eng._key)
         _stats_add(draft_time_s=time.perf_counter() - t0)
         self._lens = self._lens + caps
 
@@ -612,22 +454,10 @@ class DraftModelDrafter(Drafter):
             catch[s, :pend] = full[self._lens[s]: self._lens[s] + pend]
             caps[s] = pend
         bt = jnp.asarray(eng._bt)  # invariant across the round
-        if self._quant:
-            (self._k_pages, self._v_pages, self._k_scales,
-             self._v_scales, targets) = self._catch_fn(
-                self._params, self._k_pages, self._v_pages,
-                self._k_scales, self._v_scales,
-                bt, jnp.asarray(self._lens),
-                jnp.asarray(catch), jnp.asarray(caps), eng._key)
-            targets = eng._host_fetch(targets)
-            eng._note_refolds(int(targets[slots, 0]))
-            targets = targets[:slots]
-        else:
-            self._k_pages, self._v_pages, targets = self._catch_fn(
-                self._params, self._k_pages, self._v_pages,
-                bt, jnp.asarray(self._lens),
-                jnp.asarray(catch), jnp.asarray(caps), eng._key)
-            targets = eng._host_fetch(targets)
+        self._kv, targets = self._catch_fn(
+            self._params, self._kv, bt, jnp.asarray(self._lens),
+            jnp.asarray(catch), jnp.asarray(caps), eng._key)
+        targets = eng._note_refolds(eng._host_fetch(targets))
         self._lens[active] += caps[active]
         cur = np.where(
             active,
@@ -645,24 +475,10 @@ class DraftModelDrafter(Drafter):
             step_active = active & (i <= write_caps - 1)
             if not step_active.any():
                 break
-            if self._quant:
-                (self._k_pages, self._v_pages, self._k_scales,
-                 self._v_scales, nxt) = self._step_fn(
-                    self._params, self._k_pages, self._v_pages,
-                    self._k_scales, self._v_scales,
-                    bt, jnp.asarray(self._lens),
-                    jnp.asarray(cur), jnp.asarray(step_active),
-                    eng._key)
-                nxt = eng._host_fetch(nxt).astype(np.int32)
-                eng._note_refolds(int(nxt[-1]))
-                nxt = nxt[:-1]
-            else:
-                self._k_pages, self._v_pages, nxt = self._step_fn(
-                    self._params, self._k_pages, self._v_pages,
-                    bt, jnp.asarray(self._lens),
-                    jnp.asarray(cur), jnp.asarray(step_active),
-                    eng._key)
-                nxt = eng._host_fetch(nxt).astype(np.int32)
+            self._kv, nxt = self._step_fn(
+                self._params, self._kv, bt, jnp.asarray(self._lens),
+                jnp.asarray(cur), jnp.asarray(step_active), eng._key)
+            nxt = eng._note_refolds(eng._host_fetch(nxt)).astype(np.int32)
             self._lens[step_active] += 1
             cur = np.where(step_active, nxt, cur).astype(np.int32)
             drafts[:, i] = np.where(step_active, nxt, 0)
@@ -892,24 +708,13 @@ class SpeculativeDecoder:
         else:
             fn = self._verify_fn
             if fn is None:
-                if eng._kv_quant:
-                    fn = self._verify_fn = _JitTracker(
-                        functools.partial(_gpt_spec_verify_q,
-                                          num_heads=eng._num_heads,
-                                          head_dim=eng._head_dim,
-                                          eps=eng._eps, **eng._sampling),
-                        "verify_compiles", donate_argnums=(1, 2, 3, 4),
-                        site="SpeculativeDecoder verify "
-                             "(_gpt_spec_verify_q)")
-                else:
-                    fn = self._verify_fn = _JitTracker(
-                        functools.partial(_gpt_spec_verify,
-                                          num_heads=eng._num_heads,
-                                          head_dim=eng._head_dim,
-                                          eps=eng._eps, **eng._sampling),
-                        "verify_compiles", donate_argnums=(1, 2),
-                        site="SpeculativeDecoder verify "
-                             "(_gpt_spec_verify)")
+                fn = self._verify_fn = _JitTracker(
+                    functools.partial(_gpt_spec_verify,
+                                      num_heads=eng._num_heads,
+                                      head_dim=eng._head_dim,
+                                      eps=eng._eps, **eng._sampling),
+                    "verify_compiles", donate_argnums=(1,),
+                    site="SpeculativeDecoder verify (_gpt_spec_verify)")
 
         tokens = np.concatenate(
             [eng._last[:, None].astype(np.int32), drafts], axis=1)
@@ -929,20 +734,10 @@ class SpeculativeDecoder:
         t0 = time.perf_counter()
         tv_ns = _obs.now_ns()
         with eng._phase("verify"):
-            if eng._kv_quant:
-                (eng._k_pages, eng._v_pages, eng._k_scales,
-                 eng._v_scales, targets) = fn(
-                    eng._params, eng._k_pages, eng._v_pages,
-                    eng._k_scales, eng._v_scales,
-                    eng._dev(eng._bt), eng._dev(eng._lens),
-                    eng._dev(tokens), eng._dev(caps),
-                    eng._dev(key))
-            else:
-                eng._k_pages, eng._v_pages, targets = fn(
-                    eng._params, eng._k_pages, eng._v_pages,
-                    eng._dev(eng._bt), eng._dev(eng._lens),
-                    eng._dev(tokens), eng._dev(caps),
-                    eng._dev(key))
+            eng._kv, targets = fn(
+                eng._params, eng._kv,
+                eng._dev(eng._bt), eng._dev(eng._lens),
+                eng._dev(tokens), eng._dev(caps), eng._dev(key))
             if eng._profiling is not None:
                 # sampled device-sync probe (observability.
                 # profiling): the verify executable's measured
@@ -950,10 +745,7 @@ class SpeculativeDecoder:
                 eng._profiling.probe(
                     "ragged" if eng._ragged else "verify",
                     targets, t0, tv_ns)
-        targets = eng._host_fetch(targets)
-        if eng._kv_quant:
-            eng._note_refolds(int(targets[slots, 0]))
-            targets = targets[:slots]
+        targets = eng._note_refolds(eng._host_fetch(targets))
         t_verify = time.perf_counter() - t0
         if eng._fault is not None:
             targets = eng._resilience.corrupt_tokens(

@@ -291,21 +291,24 @@ def profile_signature(site: str, args) -> tuple:
                    bool(getattr(a, "weak_type", False)))
             tag = _shard_tag(a)
             sig.append(row if tag is None else row + (tag,))
-        elif isinstance(a, dict):
-            # pytree operand (the step fns' params dict): flatten to
-            # leaf shapes/dtypes so weight-shape changes re-key
-            import jax
+            continue
+        import jax
 
-            rows = []
-            for x in jax.tree_util.tree_leaves(a):
-                if not hasattr(x, "shape"):
-                    continue
-                row = (tuple(x.shape), str(x.dtype))
-                tag = _shard_tag(x)
-                rows.append(row if tag is None else row + (tag,))
-            sig.append(tuple(rows))
-        else:
+        leaves = jax.tree_util.tree_leaves(a)
+        if not leaves or (len(leaves) == 1 and leaves[0] is a):
             sig.append(("s", type(a).__name__, repr(a)[:32]))
+            continue
+        # pytree operand (the step fns' params dict, the K/V pool):
+        # flatten to leaf shapes/dtypes so weight-shape changes re-key
+        # — never `repr`, which would fetch every array to the host
+        rows = []
+        for x in leaves:
+            if not hasattr(x, "shape"):
+                continue
+            row = (tuple(x.shape), str(x.dtype))
+            tag = _shard_tag(x)
+            rows.append(row if tag is None else row + (tag,))
+        sig.append(tuple(rows))
     return (site, tuple(sig))
 
 
@@ -561,7 +564,7 @@ class CostModel:
             layers=eng._num_layers, hidden=hidden, vocab=vocab,
             num_heads=eng._num_heads,
             weight_bytes=wb,
-            kv_bytes=eng._k_pages.dtype.itemsize)
+            kv_bytes=eng._kv.dtype.itemsize)
         return CostProfile(site="analytical", flops=c["flops"],
                            bytes_accessed=c["bytes_accessed"],
                            source="analytical")
@@ -823,17 +826,16 @@ class CostModel:
                     claim(leaf, "weights")
 
         claim_weights(eng._params)
-        claim(eng._k_pages, "kv_pages")
-        claim(eng._v_pages, "kv_pages")
-        claim(eng._k_scales, "kv_scales")
-        claim(eng._v_scales, "kv_scales")
+        for arr in eng._kv.pages:
+            claim(arr, "kv_pages")
+        for arr in eng._kv.scales:
+            claim(arr, "kv_scales")
         claim(eng._key, "misc")
         if eng._spec is not None:
             d = eng._spec.drafter
             claim_weights(getattr(d, "_params", None) or {})
-            for name in ("_k_pages", "_v_pages", "_k_scales",
-                         "_v_scales"):
-                claim(getattr(d, name, None), "draft_pool")
+            for arr in jax.tree_util.tree_leaves(getattr(d, "_kv", None)):
+                claim(arr, "draft_pool")
         cats = {c: 0 for c in LEDGER_CATEGORIES}
         unattributed = 0
         total = 0

@@ -52,14 +52,24 @@ its rows are `kv_pool_width` wide); that of an int8 pool is
 scattered K/V chunk in-graph (per-head absmax folded into the running
 page scale, existing page rows re-quantized when the scale grows —
 the "refold").
+
+`KVPool` is the one place that knows which of the two a pool is: the
+serving step bodies hold a pool and ask it to `write` rows and to
+`attend`; the engine builds it with `KVPool.zeros` and moves pages
+through the host with `export_pages` / `import_pages`.
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 import math
+from typing import Optional
+
+import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec
 
 from . import flash_attention as fa
 
@@ -127,7 +137,7 @@ def default_page_size(max_len, d, dtype=jnp.float32):
 
 
 # ---------------------------------------------------------------------------
-# Write-capped K/V row coordinates: the int8 twins' row scatter
+# Write-capped K/V row coordinates: the int8 pool's row scatter
 # (`paged_quant_write`) and the oracle of tests/test_paged_kv_write.py.
 # Float pools are written a page at a time, by `paged_kv_write` below.
 # ---------------------------------------------------------------------------
@@ -715,3 +725,214 @@ def _paged_kernel_wanted() -> bool:
         return False
     pol = str(_flags.flag("use_pallas_attention"))
     return pol in ("1", "True", "true", "auto")
+
+
+# ---------------------------------------------------------------------------
+# The pool: K/V pages and how they are stored, behind one type
+# ---------------------------------------------------------------------------
+def mesh_constrain(mesh):
+    """Sharding-constraint applicator for the serving mesh: ``None``
+    (the single-chip path) returns an identity, so a step traces
+    EXACTLY the ops it always traced — zero sharding machinery on the
+    off path.  With a mesh, ``cst(x, *axes)`` pins ``x`` to
+    ``PartitionSpec(*axes)`` over it (``cst(x)`` = replicated), the
+    GSPMD boundary annotations that turn the one ragged executable
+    into a tensor-parallel program: column-split qkv/fc1 compute runs
+    head-/feature-local, row-split out/fc2 matmuls end in the
+    all-reduce the replicated-residual constraint forces, and the
+    pool's arrays stay split on their head axis."""
+    if mesh is None:
+        return lambda x, *spec: x
+
+    def cst(x, *spec):
+        return jax.lax.with_sharding_constraint(
+            x, NamedSharding(mesh, PartitionSpec(*spec)))
+
+    return cst
+
+
+@functools.partial(jax.tree_util.register_dataclass,
+                   data_fields=("k", "v", "k_scales", "v_scales"),
+                   meta_fields=("head_dim",))
+@dataclasses.dataclass(frozen=True, repr=False)
+class KVPool:
+    """The K and V page pools of one model, and the storage format they
+    are kept in.  A pytree: a float pool flattens to its two arrays
+    ``(k, v)``, an int8 pool to four ``(k, v, k_scales, v_scales)`` (a
+    ``None`` field is no leaf), so a step executable takes and donates
+    it as ONE argument whatever it holds.  The format is chosen at trace
+    time from what the pool holds, as `serving._wmm` does for weights:
+    one mode per executable, no in-graph select.
+
+    k, v: [L, Hkv, P, page, W] — float, W = `kv_pool_width` of
+    ``head_dim`` (whole 128-lane rows, lanes ``head_dim..`` zero); or
+    int8, W = ``head_dim``, with k_scales, v_scales: [L, Hkv, P] f32
+    running page scales (`paged_quant_write`)."""
+
+    k: jax.Array
+    v: jax.Array
+    head_dim: int
+    k_scales: Optional[jax.Array] = None
+    v_scales: Optional[jax.Array] = None
+
+    @classmethod
+    def zeros(cls, layers, heads, pages, page, head_dim, dtype):
+        """An empty pool of ``dtype`` storage (int8: quantized, with
+        zero scales — "never written")."""
+        shape = (layers, heads, pages, page, kv_pool_width(head_dim, dtype))
+        k, v = (jnp.zeros(shape, dtype) for _ in "kv")
+        if jnp.dtype(dtype) != jnp.int8:
+            return cls(k, v, head_dim)
+        k_scales, v_scales = (
+            jnp.zeros((layers, heads, pages), jnp.float32) for _ in "kv")
+        return cls(k, v, head_dim, k_scales, v_scales)
+
+    def __repr__(self):
+        # shapes only: a dataclass's own repr prints its fields, and
+        # printing a device array fetches it to the host (2.4 GB here)
+        return (f"KVPool({self.dtype}{list(self.k.shape)}, "
+                f"head_dim={self.head_dim}, quantized={self.quantized})")
+
+    @property
+    def quantized(self) -> bool:
+        return self.k_scales is not None
+
+    @property
+    def dtype(self):
+        """The storage dtype of a K/V element."""
+        return self.k.dtype
+
+    @property
+    def pages(self):
+        """The page arrays: what the pool's bytes are, bar the scales."""
+        return (self.k, self.v)
+
+    @property
+    def scales(self):
+        """``(k_scales, v_scales)`` of an int8 pool; ``()`` of a float one."""
+        return (self.k_scales, self.v_scales) if self.quantized else ()
+
+    def with_scales(self, k_scales, v_scales):
+        """The pool with its scales replaced (the engine zeroes those of
+        freshly allocated pages between steps)."""
+        return dataclasses.replace(self, k_scales=k_scales,
+                                   v_scales=v_scales)
+
+    def sharded(self, mesh):
+        """The pool laid out over the serving mesh: every array split
+        on its head axis (`partition.kv_pages_spec`)."""
+        from ...parallel.partition import kv_pages_spec
+
+        by_head = NamedSharding(mesh, kv_pages_spec())
+        return jax.tree_util.tree_map(
+            lambda a: jax.device_put(a, by_head), self)
+
+    # -- inside a step executable --------------------------------------------
+    def write(self, name, li, rows, block_tables, seq_lens, write_caps,
+              mesh=None):
+        """Layer ``li``'s new K rows (``name`` "k") or V rows ("v") in:
+        row ``i < write_caps[b]`` of sequence ``b`` lands at position
+        ``seq_lens[b] + i`` of its pages.  rows: [B, Q, Hkv, D];
+        block_tables: [B, pages_max] int32; seq_lens, write_caps: [B]
+        int32, caps in [0, Q].  Returns ``(pool, refolds)``: the count
+        of page scales that grew (`paged_quant_write`) — the int 0 for
+        a float pool, which traces nothing.  Under ``mesh`` the written
+        arrays stay split on their head axis.
+
+        One array a call, so that a step body traces K's rows, K's
+        write, V's rows, V's write in the order it always did."""
+        cst = mesh_constrain(mesh)
+        pages = getattr(self, name)
+        if not self.quantized:
+            pages = paged_kv_write(pages, li, rows, block_tables, seq_lens,
+                                   write_caps)
+            return dataclasses.replace(self, **{
+                name: cst(pages, None, "mp", None, None, None)}), 0
+        b, qn, hkv, d = rows.shape
+        num_pages, page = pages.shape[2:4]
+        page_idx, slot = paged_write_indices(
+            block_tables, seq_lens, write_caps, qn, num_pages, page)
+        pages, scales, refolds = paged_quant_write(
+            pages, getattr(self, name + "_scales"), li,
+            rows.reshape(b * qn, hkv, d), page_idx.reshape(-1),
+            slot.reshape(-1),
+            paged_write_spans(block_tables, seq_lens, write_caps, qn,
+                              num_pages, page))
+        return dataclasses.replace(self, **{
+            name: cst(pages, None, "mp", None, None, None),
+            name + "_scales": cst(scales, None, "mp", None)}), refolds
+
+    def attend(self, q, li, block_tables, seq_lens, q_offsets=None,
+               mesh=None):
+        """`paged_attention` of ``q`` over layer ``li`` (int8: dequant
+        fused into the K/V loads).  Under ``mesh`` the call sits in a
+        `jax.shard_map` over ``mp`` (q: [B, Q, Hq, D], ``q_offsets``
+        given): heads are chip-local there, so each chip attends over
+        its own head-slice of every page with no communication — but a
+        Mosaic kernel cannot be partitioned by GSPMD from sharding
+        constraints alone, it has to be told per chip.  Block tables
+        and lengths are replicated host state."""
+        k = kv_layer(self.k, li, self.head_dim)
+        v = kv_layer(self.v, li, self.head_dim)
+        scales = tuple(s[li] for s in self.scales)
+
+        def direct(q, k, v, block_tables, seq_lens, q_offsets, *scales):
+            k_scales, v_scales = scales or (None, None)
+            return paged_attention(q, k, v, block_tables, seq_lens,
+                                   q_offsets=q_offsets, k_scales=k_scales,
+                                   v_scales=v_scales)
+
+        if mesh is None:
+            return direct(q, k, v, block_tables, seq_lens, q_offsets,
+                          *scales)
+        heads = PartitionSpec(None, None, "mp", None)  # q, out
+        by_head = PartitionSpec("mp")  # a layer's pages, its scales
+        rep = PartitionSpec()
+        return jax.shard_map(
+            direct, mesh=mesh,
+            in_specs=(heads, by_head, by_head, rep, rep, rep)
+            + (by_head,) * len(scales),
+            out_specs=heads, check_vma=False,
+        )(q, k, v, block_tables, seq_lens, q_offsets, *scales)
+
+    # -- on the host ---------------------------------------------------------
+    def export_pages(self, ids):
+        """Pages ``ids`` off the device, as numpy arrays by name: "k",
+        "v" [L, Hkv, n, page, head_dim] in the storage dtype (the K/V
+        lanes of a row only) and, for an int8 pool, "ks", "vs"
+        [L, Hkv, n].  Axis 2 is the page axis of every array."""
+        ids = np.asarray(ids, np.int32)
+        out = {"k": self.k[:, :, ids, :, :self.head_dim],
+               "v": self.v[:, :, ids, :, :self.head_dim]}
+        if self.quantized:
+            out["ks"] = self.k_scales[:, :, ids]
+            out["vs"] = self.v_scales[:, :, ids]
+        return {n: np.asarray(jax.device_get(a)) for n, a in out.items()}
+
+    def fits(self, arrays) -> bool:
+        """Are ``arrays`` what `export_pages` of a pool of this geometry
+        and storage format gives (for any number of pages)?"""
+        layers, heads, _, page, _ = self.k.shape
+        row = {"k": (page, self.head_dim), "v": (page, self.head_dim)}
+        if self.quantized:
+            row.update(ks=(), vs=())
+        if set(arrays) != set(row):
+            return False
+        n = arrays["k"].shape[2:3]
+        return all(arrays[name].shape == (layers, heads) + n + row[name]
+                   for name in row)
+
+    def import_pages(self, ids, arrays):
+        """The pool with `export_pages`' ``arrays`` installed at pages
+        ``ids`` (scales and all: an int8 page comes back bit for bit)."""
+        idx = jnp.asarray(np.asarray(ids, np.int32))
+        k = self.k.at[:, :, idx, :, :self.head_dim].set(
+            jnp.asarray(arrays["k"]))
+        v = self.v.at[:, :, idx, :, :self.head_dim].set(
+            jnp.asarray(arrays["v"]))
+        if not self.quantized:
+            return KVPool(k, v, self.head_dim)
+        return KVPool(
+            k, v, self.head_dim,
+            self.k_scales.at[:, :, idx].set(jnp.asarray(arrays["ks"])),
+            self.v_scales.at[:, :, idx].set(jnp.asarray(arrays["vs"])))

@@ -39,7 +39,6 @@ __all__ = [
     "gpt_train_rules",
     "gpt_serving_rules",
     "kv_pages_spec",
-    "kv_scales_spec",
     "parse_mesh_spec",
     "build_mesh",
     "hlo_collectives",
@@ -170,7 +169,9 @@ def gpt_serving_rules() -> List[Tuple[str, P]]:
 
 
 def kv_pages_spec() -> P:
-    """KV page pool [L, H, n_pages, page, W]: sharded on the head axis
+    """KV page pool [L, H, n_pages, page, W] (and an int8 pool's page
+    scales [L, H, n_pages]: `ops.pallas.paged_attention.KVPool.sharded`):
+    sharded on the head axis
     — each chip holds its head-slice of EVERY page, so page ids stay
     logical and the allocator/block tables stay host-global.  Trailing
     replicated axes are TRIMMED (``P(None, 'mp')``, not the 5-element
@@ -179,12 +180,6 @@ def kv_pages_spec() -> P:
     next-step-input — an untrimmed construction-time spec would differ
     from the step's own output spec and retrace the warm cache on the
     second step."""
-    return P(None, "mp")
-
-
-def kv_scales_spec() -> P:
-    """int8 KV page scales [L, H, n_pages]: head axis follows the
-    pages (trailing replicated axes trimmed, as in `kv_pages_spec`)."""
     return P(None, "mp")
 
 

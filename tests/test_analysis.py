@@ -776,55 +776,53 @@ class TestEngineMutationLint:
 # donation analysis
 # ---------------------------------------------------------------------------
 class TestDonationLint:
-    def test_missing_pages_donation_flagged(self, tmp_path):
+    def test_missing_pool_donation_flagged(self, tmp_path):
         mods = _scan_snippet(tmp_path, """
             import functools
             import jax
 
-            def step(params, k_pages, v_pages, tokens):
-                return k_pages, v_pages, tokens
+            def step(params, kv, tokens):
+                return kv, tokens
 
-            bad = jax.jit(functools.partial(step), donate_argnums=(1,))
+            bad = jax.jit(functools.partial(step), donate_argnums=(2,))
             worse = jax.jit(step)
-            good = jax.jit(step, donate_argnums=(1, 2))
+            good = jax.jit(step, donate_argnums=(1,))
         """)
         found = DonationPass().run(mods)
         msgs = sorted(f.message for f in found)
-        # bad misses v_pages; worse misses both
-        assert len(found) == 3, msgs
-        assert sum("`v_pages`" in m for m in msgs) == 2
-        assert sum("`k_pages`" in m for m in msgs) == 1
-        assert any("no donate_argnums at all" in m for m in msgs)
+        # bad donates the tokens instead; worse donates nothing
+        assert len(found) == 2, msgs
+        assert all("`kv` (argnum 1)" in m for m in msgs)
+        assert sum("no donate_argnums at all" in m for m in msgs) == 1
 
-    def test_missing_scales_donation_flagged(self, tmp_path):
-        """Quantized KV pools (FLAGS_kv_quant) thread per-page scale
-        arrays beside the pages; the donation pass counts ``*_scales``
-        params as pool state — a site donating the pages but copying
-        the scales is the known-bad fixture here."""
+    def test_pool_found_by_name_wherever_it_sits(self, tmp_path):
+        """The pool is ONE argument whatever it holds (an int8 pool's
+        quant scales travel inside it), so "pages donated, scales
+        copied" cannot be written any more; what can still go wrong is
+        donating the wrong position — prefill takes the pool fifth.
+        Functions that take no pool (the scale reset works on bare
+        scale arrays) are not this pass's business."""
         mods = _scan_snippet(tmp_path, """
             import functools
             import jax
 
-            def step_q(params, k_pages, v_pages, k_scales, v_scales,
-                       tokens):
-                return k_pages, v_pages, k_scales, v_scales, tokens
+            def prefill(params, ids, true_len, bt_row, kv, key):
+                return kv, ids
 
-            bad = jax.jit(functools.partial(step_q),
-                          donate_argnums=(1, 2))
-            good = jax.jit(step_q, donate_argnums=(1, 2, 3, 4))
+            bad = jax.jit(functools.partial(prefill),
+                          donate_argnums=(1,))
+            good = jax.jit(prefill, donate_argnums=(4,))
 
             def reset(k_scales, v_scales, idx):
                 return k_scales, v_scales
 
-            bad_reset = jax.jit(reset, donate_argnums=(0,))
-            good_reset = jax.jit(reset, donate_argnums=(0, 1))
+            reset_some = jax.jit(reset, donate_argnums=(0,))
+            reset_all = jax.jit(reset, donate_argnums=(0, 1))
         """)
         found = DonationPass().run(mods)
         msgs = sorted(f.message for f in found)
-        # bad misses both scale params; bad_reset misses v_scales
-        assert len(found) == 3, msgs
-        assert sum("`k_scales`" in m for m in msgs) == 1
-        assert sum("`v_scales`" in m for m in msgs) == 2
+        assert len(found) == 1, msgs
+        assert "`prefill`" in msgs[0] and "`kv` (argnum 4)" in msgs[0]
 
     def test_tracker_owned_jit_site(self, tmp_path):
         """The serving pattern after the single-source-of-truth
@@ -834,51 +832,50 @@ class TestDonationLint:
         mods = _scan_snippet(tmp_path, """
             import functools
 
-            def step(params, k_pages, v_pages, tokens):
+            def step(params, kv, tokens):
                 if tokens.sum() > 0:
-                    return k_pages, v_pages
-                return v_pages, k_pages
+                    return kv, tokens
+                return kv, -tokens
 
             good = _JitTracker(functools.partial(step), "decode_compiles",
-                               donate_argnums=(1, 2), site="good")
+                               donate_argnums=(1,), site="good")
             bad = _JitTracker(functools.partial(step), "decode_compiles",
-                              donate_argnums=(1,), site="bad")
+                              donate_argnums=(), site="bad")
         """)
         donation = DonationPass().run(mods)
-        assert len(donation) == 1 and "`v_pages`" in donation[0].message
+        assert len(donation) == 1 and "`kv`" in donation[0].message
         hazards = TraceHazardPass().run(mods)
         assert len(hazards) == 1 and "tokens.sum()" in hazards[0].snippet
 
-    def test_mesh_wrapped_twin_sharded_pages_not_donated(self, tmp_path):
+    def test_mesh_wrapped_step_sharded_pool_not_donated(self, tmp_path):
         """The multichip serving pattern (FLAGS_serve_mesh): the ragged
-        twins are partial-bound with a ``mesh=`` kwarg and their page
-        pool operands are mesh-sharded arrays — donation coverage must
+        step is partial-bound with a ``mesh=`` kwarg and its pool
+        operand holds mesh-sharded arrays — donation coverage must
         see straight through the wrapper, because an undonated SHARDED
         pool is worse than the single-chip bug (every chip copies its
-        page shard every step).  Known-bad fixture: the mesh twin
-        donates the pages but not the scales → finding; the good twin
-        with the full pool tuple is clean."""
+        page shard every step).  Known-bad fixture: the mesh step
+        donates its tokens but not the pool → finding; the good one
+        donating the pool is clean."""
         mods = _scan_snippet(tmp_path, """
             import functools
 
             MESH = object()
 
-            def ragged_step(params, k_pages, v_pages, k_scales,
-                            v_scales, tokens, mesh=None):
-                return k_pages, v_pages, k_scales, v_scales, tokens
+            def ragged_step(params, kv, tokens, mesh=None):
+                return kv, tokens
 
             bad = _JitTracker(
                 functools.partial(ragged_step, mesh=MESH),
-                "ragged_compiles", donate_argnums=(1, 2, 3),
-                site="bad mesh twin")
+                "ragged_compiles", donate_argnums=(2,),
+                site="bad mesh step")
             good = _JitTracker(
                 functools.partial(ragged_step, mesh=MESH),
-                "ragged_compiles", donate_argnums=(1, 2, 3, 4),
-                site="good mesh twin")
+                "ragged_compiles", donate_argnums=(1,),
+                site="good mesh step")
         """)
         found = DonationPass().run(mods)
         assert len(found) == 1, [f.message for f in found]
-        assert "`v_scales`" in found[0].message
+        assert "`kv`" in found[0].message
 
     def test_partial_positional_shift(self, tmp_path):
         """Positionally-bound partial args shift the donate indices."""
@@ -886,18 +883,18 @@ class TestDonationLint:
             import functools
             import jax
 
-            def step(params, k_pages, v_pages):
-                return k_pages, v_pages
+            def step(params, kv, tokens):
+                return kv, tokens
 
             PARAMS = {}
             good = jax.jit(functools.partial(step, PARAMS),
-                           donate_argnums=(0, 1))
+                           donate_argnums=(0,))
             bad = jax.jit(functools.partial(step, PARAMS),
-                          donate_argnums=(0,))
+                          donate_argnums=(1,))
         """)
         found = DonationPass().run(mods)
         assert len(found) == 1
-        assert "`v_pages` (argnum 1)" in found[0].message
+        assert "`kv` (argnum 0)" in found[0].message
 
 
 # ---------------------------------------------------------------------------
@@ -1128,7 +1125,7 @@ class TestSanitizer:
         CPU, XLA ignores donation entirely, so only the sanitizer can
         catch this class before TPU hardware does."""
         eng = _tiny_engine()
-        stale = eng._k_pages
+        stale = eng._kv.k
         eng.add_request([1, 2, 3], max_new_tokens=4)
         eng.run()
         site = sanitizer.get().donation_site(stale)
@@ -1150,7 +1147,7 @@ class TestSanitizer:
         donation site, which is exactly the debugging gap the
         sanitizer closes."""
         eng = _tiny_engine()
-        stale = eng._k_pages
+        stale = eng._kv.k
         eng.add_request([1, 2, 3], max_new_tokens=4)
         eng.run()
         assert sanitizer.get().donation_site(stale) is None

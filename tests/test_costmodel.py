@@ -89,6 +89,29 @@ class TestProfiles:
         assert s1 != costmodel.profile_signature(
             "site", (jnp.zeros((4, 9), jnp.float32),))
 
+    def test_signature_walks_a_pool_and_never_prints_it(self, monkeypatch):
+        """The K/V pool is one pytree operand: it keys by its leaves'
+        shapes and dtypes, as the weights do.  Falling back to `repr`
+        would fetch both pools to the host (1.4 s of the serve cell's
+        set-up on the chip, PR 31)."""
+        import jax.numpy as jnp
+
+        from paddle_tpu.ops.pallas import paged_attention as pa
+
+        def no_repr(self):
+            raise AssertionError("profile_signature printed the pool")
+
+        monkeypatch.setattr(pa.KVPool, "__repr__", no_repr)
+        f32 = pa.KVPool.zeros(2, 2, 4, 16, 8, jnp.float32)
+        s1 = costmodel.profile_signature("site", ({}, f32, 3))
+        assert s1 == costmodel.profile_signature(
+            "site", ({}, pa.KVPool.zeros(2, 2, 4, 16, 8, jnp.float32), 3))
+        assert s1 != costmodel.profile_signature(
+            "site", ({}, pa.KVPool.zeros(2, 2, 8, 16, 8, jnp.float32), 3))
+        assert s1 != costmodel.profile_signature(
+            "site", ({}, pa.KVPool.zeros(2, 2, 4, 16, 8, jnp.int8), 3))
+        assert s1 != costmodel.profile_signature("site", ({}, f32, 4))
+
     def test_profile_extraction_never_compiles(self, model):
         """The lower()+cost_analysis() path must not touch the jit's
         executable cache — zero new executables is the armed-mode
@@ -298,8 +321,8 @@ class TestLedger:
         led = eng._cost.hbm_ledger(set_gauges=True)
         cats = led["categories"]
         assert cats["weights"] > 0
-        assert cats["kv_pages"] == eng._k_pages.nbytes + \
-            eng._v_pages.nbytes
+        assert cats["kv_pages"] == eng._kv.k.nbytes + \
+            eng._kv.v.nbytes
         # the reconciliation identity: attributed + unattributed is
         # EXACTLY the live total (temp_scratch sits outside it)
         live_cats = sum(v for k, v in cats.items()
@@ -318,7 +341,7 @@ class TestLedger:
         eng.generate(_prompts(2), max_new_tokens=4)
         led = eng._cost.hbm_ledger()
         assert led["categories"]["kv_scales"] == \
-            eng._k_scales.nbytes + eng._v_scales.nbytes
+            eng._kv.k_scales.nbytes + eng._kv.v_scales.nbytes
 
     def test_draft_pool_category(self, model):
         from paddle_tpu.inference.speculative import DraftModelDrafter

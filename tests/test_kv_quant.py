@@ -281,7 +281,7 @@ class TestQuantEngine:
         prompts = _prompts()
         default = _engine(m)
         out_default = default.generate(prompts, max_new_tokens=4)
-        assert default._kv_quant is False and default._k_scales is None
+        assert default._kv_quant is False and not default._kv.quantized
         reset_decode_stats()
         off = _engine(m, kv_quant="off")
         out_off = off.generate(prompts, max_new_tokens=4)
@@ -291,6 +291,31 @@ class TestQuantEngine:
         assert st["kv_quant_refolds"] == 0
         assert st["kv_quant_compiles"] == 0  # zero new executables
         assert st["retraces_after_warmup"] == 0
+
+    @pytest.mark.parametrize("which", ["_decode_fn", "_mixed_fn"])
+    def test_float_step_takes_the_pool_as_two_donated_operands(self, which):
+        """What the compile cache and the benchmark's `classify` lean on:
+        a float engine's step executables take the weights, then the
+        pool as exactly two arrays (K, V — no scale operand), donated
+        and nothing else donated, and hand the pool back first."""
+        eng = _engine(_tiny_gpt())
+        eng.generate(_prompts(), max_new_tokens=4)
+        tracker = getattr(eng, which)
+        assert tracker.donate_argnums == (1,)
+        lowered = tracker.lower()
+        args, _ = lowered.args_info
+        pool = jax.tree_util.tree_leaves(args[1])
+        assert [tuple(a.shape) for a in pool] == \
+            [tuple(eng._kv.k.shape), tuple(eng._kv.v.shape)]
+        assert all(a.donated for a in pool)
+        n_weights = len(jax.tree_util.tree_leaves(args[0]))
+        flat = jax.tree_util.tree_leaves(args)
+        assert flat[n_weights:n_weights + 2] == pool
+        assert sum(a.donated for a in flat) == 2
+        text = lowered.as_text()
+        assert text.count("tf.aliasing_output") == 2
+        assert 'jax.result_info = "result[0].k"' in text
+        assert "xi8>" not in text  # no int8 storage in a float engine
 
     def test_invalid_mode_rejected(self):
         with pytest.raises(ValueError, match="kv_quant"):
@@ -308,8 +333,8 @@ class TestQuantEngine:
         e2 = _engine(m, kv_quant="int8")
         out2 = e2.generate(prompts, max_new_tokens=4)
         assert out1 == out2
-        assert e1._k_pages.dtype == jnp.int8
-        assert e1._k_scales.shape == (TINY.num_layers, TINY.num_heads,
+        assert e1._kv.dtype == jnp.int8
+        assert e1._kv.k_scales.shape == (TINY.num_layers, TINY.num_heads,
                                       e1.pool.num_pages)
 
     def test_quant_tracks_f32_outputs(self):
@@ -449,10 +474,10 @@ class TestQuantDurability:
         ids_new = [p for p, _ in installed]
         ids_old = [eng.pool._hash_to_page[h] for _, h in installed]
         for new_arr, old_arr in (
-                (eng2._k_pages, eng._k_pages),
-                (eng2._v_pages, eng._v_pages),
-                (eng2._k_scales, eng._k_scales),
-                (eng2._v_scales, eng._v_scales)):
+                (eng2._kv.k, eng._kv.k),
+                (eng2._kv.v, eng._kv.v),
+                (eng2._kv.k_scales, eng._kv.k_scales),
+                (eng2._kv.v_scales, eng._kv.v_scales)):
             np.testing.assert_array_equal(
                 np.asarray(jax.device_get(new_arr[:, :, ids_new])),
                 np.asarray(jax.device_get(old_arr[:, :, ids_old])))

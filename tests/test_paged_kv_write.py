@@ -132,3 +132,65 @@ def test_pool_rows_are_whole_lane_rows(head_dim, width):
     assert layer.shape == (3, 4, 16, head_dim)
     np.testing.assert_array_equal(np.asarray(layer),
                                   np.asarray(pool)[1, ..., :head_dim])
+
+
+# ---------------------------------------------------------------------------
+# `pa.KVPool`: the one type that knows how the pool is stored
+# ---------------------------------------------------------------------------
+def _filled_pool(dtype):
+    """A pool whose every element (padding lanes and scales too) differs."""
+    kv = pa.KVPool.zeros(L, HKV, P, PAGE, D, dtype)
+    rng = np.random.RandomState(5)
+    return jax.tree_util.tree_map(
+        lambda a: jnp.asarray(
+            rng.randint(-100, 100, a.shape).astype(a.dtype)), kv)
+
+
+@pytest.mark.parametrize("dtype, leaves",
+                         [(jnp.float32, 2), (jnp.bfloat16, 2), (jnp.int8, 4)])
+def test_pool_flattens_to_its_arrays(dtype, leaves):
+    """A float pool is exactly (k, v) — the two operands the step
+    executables always took, in that order — an int8 one (k, v, k_scales,
+    v_scales): a ``None`` field is no leaf."""
+    kv = pa.KVPool.zeros(L, HKV, P, PAGE, D, dtype)
+    flat, tree = jax.tree_util.tree_flatten(kv)
+    assert len(flat) == leaves and kv.quantized == (leaves == 4)
+    assert flat[0] is kv.k and flat[1] is kv.v
+    assert kv.k.shape == (L, HKV, P, PAGE, pa.kv_pool_width(D, dtype))
+    assert kv.dtype == dtype and tuple(kv.pages) == (kv.k, kv.v)
+    if kv.quantized:
+        assert flat[2] is kv.k_scales and flat[3] is kv.v_scales
+        assert kv.k_scales.shape == (L, HKV, P)
+    back = jax.tree_util.tree_unflatten(tree, flat)
+    assert back.head_dim == D and back.quantized == kv.quantized
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.int8])
+def test_pool_pages_round_trip_through_the_host(dtype):
+    """`export_pages` gives the K/V lanes of the named pages (not the
+    padding lanes of a float pool's 128-lane rows) and an int8 pool's
+    scales; `import_pages` puts them back bit for bit, elsewhere in
+    another pool, and touches nothing else."""
+    src = _filled_pool(dtype)
+    ids, dst_ids = [7, 2, 11], [0, 5, 3]
+    arrays = src.export_pages(ids)
+    assert sorted(arrays) == (["k", "ks", "v", "vs"] if src.quantized
+                              else ["k", "v"])
+    assert arrays["k"].shape == (L, HKV, len(ids), PAGE, D)
+    np.testing.assert_array_equal(
+        arrays["v"], np.asarray(src.v)[:, :, ids, :, :D])
+    dst = pa.KVPool.zeros(L, HKV, P, PAGE, D, dtype)
+    assert dst.fits(arrays)
+    got = dst.import_pages(dst_ids, arrays)
+    for name, a in got.export_pages(dst_ids).items():
+        np.testing.assert_array_equal(a, arrays[name])
+    rest = [p for p in range(P) if p not in dst_ids]
+    for a in jax.tree_util.tree_leaves(got):
+        assert not np.asarray(a)[:, :, rest].any()
+    if not src.quantized:  # the padding lanes stay zero
+        assert src.k.shape[-1] > D
+        assert not np.asarray(got.k)[..., D:].any()
+    # what does not fit: another geometry, another storage kind
+    assert not pa.KVPool.zeros(L, HKV, P, PAGE * 2, D, dtype).fits(arrays)
+    other = jnp.int8 if dtype != jnp.int8 else jnp.float32
+    assert not pa.KVPool.zeros(L, HKV, P, PAGE, D, other).fits(arrays)

@@ -295,12 +295,12 @@ class TestPrefixAdmission:
         eng = _engine(m, prefix_cache=True)
         _, pages_a = _serve_track(eng, pa)
         page = pages_a[0]
-        before_k = np.asarray(jax.device_get(eng._k_pages[:, :, page]))
-        before_v = np.asarray(jax.device_get(eng._v_pages[:, :, page]))
+        before_k = np.asarray(jax.device_get(eng._kv.k[:, :, page]))
+        before_v = np.asarray(jax.device_get(eng._kv.v[:, :, page]))
         _, pages_b = _serve_track(eng, pb)
         assert pages_b[0] == page  # served from cache...
-        after_k = np.asarray(jax.device_get(eng._k_pages[:, :, page]))
-        after_v = np.asarray(jax.device_get(eng._v_pages[:, :, page]))
+        after_k = np.asarray(jax.device_get(eng._kv.k[:, :, page]))
+        after_v = np.asarray(jax.device_get(eng._kv.v[:, :, page]))
         np.testing.assert_array_equal(before_k, after_k)  # ...read-only
         np.testing.assert_array_equal(before_v, after_v)
 

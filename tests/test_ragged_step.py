@@ -516,7 +516,7 @@ class TestRetraceAttribution:
         bad = jnp.zeros((slots, eng._q_ragged + 1), jnp.int32)
         with pytest.raises(sanitizer.WarmRetraceError,
                            match="ragged step"):
-            fn(eng._params, eng._k_pages, eng._v_pages,
+            fn(eng._params, eng._kv,
                jnp.asarray(eng._bt), zeros, bad, zeros,
                jax.random.PRNGKey(0))
 
@@ -526,10 +526,10 @@ class TestRetraceAttribution:
 # ---------------------------------------------------------------------------
 class TestTracecheckCoverage:
     def test_ragged_sites_discovered_with_pool_donation(self):
-        """Both ragged twins are AST-discovered as tracker-owned jit
-        sites carrying the full pool-donation contract — the
-        DonationPass contract that every `*_pages` / `*_scales`
-        parameter is donated covers the new executables for free."""
+        """The ragged step is AST-discovered as ONE tracker-owned jit
+        site (both storage kinds of the pool run it) donating the pool
+        — the DonationPass contract that a function taking ``kv``
+        donates it covers the executable for free."""
         from paddle_tpu.analysis import repo_root
         from paddle_tpu.analysis.passes import (collect_jit_sites,
                                                 scan_paths)
@@ -539,10 +539,9 @@ class TestTracecheckCoverage:
         by = {}
         for s in collect_jit_sites(mods):
             by.setdefault(s.fn_name, []).append(s)
-        (f32,) = by["_gpt_ragged_step"]
-        (q,) = by["_gpt_ragged_step_q"]
-        assert f32.donate_argnums == (1, 2)
-        assert q.donate_argnums == (1, 2, 3, 4)
+        (site,) = by["_gpt_ragged_step"]
+        assert site.donate_argnums == (1,)
+        assert "_gpt_ragged_step_q" not in by
 
     def test_serving_stack_scan_clean(self):
         """The touched serving modules carry zero NEW tracecheck

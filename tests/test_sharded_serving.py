@@ -182,7 +182,7 @@ class TestShardedProgram:
         caps = eng._dev(np.zeros((eng._slots,), np.int32))
         key = eng._dev(jax.random.PRNGKey(0))
         lowered = tr.fn.lower(
-            eng._params, eng._k_pages, eng._v_pages,
+            eng._params, eng._kv,
             eng._dev(eng._bt), eng._dev(eng._lens), tokens, caps, key)
         hlo = lowered.compile().as_text()
         colls = hlo_collectives(hlo)

@@ -985,10 +985,10 @@ def test_tracecheck_smoke(tmp_path):
     bad = tmp_path / "bad_mod.py"
     bad.write_text(
         "import jax\n\n"
-        "def step(params, k_pages, v_pages, x):\n"
+        "def step(params, kv, x):\n"
         "    if x > 0:\n"
-        "        return k_pages, v_pages, int(x)\n"
-        "    return k_pages, v_pages, 0\n\n"
+        "        return kv, int(x)\n"
+        "    return kv, 0\n\n"
         "fn = jax.jit(step)\n")
     r = subprocess.run(
         [sys.executable, "tools/tracecheck.py", str(bad),

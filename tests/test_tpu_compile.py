@@ -249,8 +249,8 @@ _POOL_OPS = {"parameter", "get-tuple-element", "tuple", "while", "bitcast",
 
 def _serve_step(one_chip, which, num_pages):
     """`_gpt_decode_step` / `_gpt_mixed_step` compiled as the engine runs
-    them: full width and depth, the pools as the engine makes them
-    (rows `PA.kv_pool_width` wide), donated."""
+    them: full width and depth, the pool as the engine makes it
+    (`PA.KVPool`, rows `PA.kv_pool_width` wide), donated."""
     cfg = GPTConfig(use_parallel_layers=False, **BASE)
     page = PA.default_page_size(MAX_LEN, HEAD_DIM, jnp.float32)
     slots = CELL["slots"]
@@ -258,9 +258,10 @@ def _serve_step(one_chip, which, num_pages):
     def S(shape, dt=jnp.int32):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
 
-    pool = S((cfg.num_layers, HEADS, num_pages, page,
-              PA.kv_pool_width(HEAD_DIM)), jnp.float32)
-    head = (_engine_param_shapes(cfg, one_chip=one_chip), pool, pool,
+    pages = S((cfg.num_layers, HEADS, num_pages, page,
+               PA.kv_pool_width(HEAD_DIM)), jnp.float32)
+    head = (_engine_param_shapes(cfg, one_chip=one_chip),
+            PA.KVPool(pages, pages, HEAD_DIM),
             S((slots, MAX_LEN // page)), S((slots,)))
     if which == "decode":
         fn = serving._gpt_decode_step
@@ -273,7 +274,7 @@ def _serve_step(one_chip, which, num_pages):
         fn, num_heads=HEADS, head_dim=HEAD_DIM, eps=1e-5, sampler="greedy",
         temperature=1.0, top_k=0, top_p=1.0)
     return _compile(step, *head, *tail, S((2,), jnp.uint32),
-                    donate_argnums=(1, 2))
+                    donate_argnums=(1,))
 
 
 def _pool_sized_faults(text, num_pages, layer_elems):
@@ -362,9 +363,10 @@ def test_sharded_serving_step_holds_the_kernel(topo, as_on_tpu):
         eps=1e-5, sampler="greedy", temperature=1.0, top_k=0, top_p=1.0,
         mesh=mesh)
     compiled = _compile(
-        step, _engine_param_shapes(cfg, mesh), pages, pages,
+        step, _engine_param_shapes(cfg, mesh),
+        PA.KVPool(pages, pages, HEAD_DIM),
         R((slots, MAX_LEN // page)), R((slots,)), R((slots, Q_MAX)),
-        R((slots,)), R((2,), jnp.uint32), donate_argnums=(1, 2))
+        R((slots,)), R((2,), jnp.uint32), donate_argnums=(1,))
     text = compiled.as_text()
     assert chip_smoke.KERNEL in text
     assert " all-reduce" in text  # row-parallel out-proj and fc2

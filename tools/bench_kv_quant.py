@@ -176,8 +176,9 @@ def _teacher_forced_match(model, args, prompts, refs):
 
 def _logit_probe(model, args, prompts, refs):
     """Final-position logits for each reference context, through a
-    probe that mirrors the serving path: pages written via the same
-    quantize/scatter primitive, attention through pa.paged_attention.
+    probe that mirrors the serving path: rows written into, and
+    attention read out of, the pool type the step bodies use
+    (`pa.KVPool`).
     Self-check: the fp32 probe's argmax must equal the fp32 engine's
     sampled token (proves the probe measures the real path)."""
     import jax
@@ -197,19 +198,10 @@ def _logit_probe(model, args, prompts, refs):
         n_pages = -(-s // page)
         bt = jnp.arange(n_pages, dtype=jnp.int32)[None]
         pos = jnp.arange(s, dtype=jnp.int32)
-        page_idx = bt[0][pos // page]
-        slot = pos % page
-        if quant:
-            kp = jnp.zeros((cfg.num_layers, cfg.num_heads, n_pages,
-                            page, hd), jnp.int8)
-            ks = jnp.zeros((cfg.num_layers, cfg.num_heads, n_pages),
-                           jnp.float32)
-            vp, vs = kp, ks
-        else:
-            kp = jnp.zeros((cfg.num_layers, cfg.num_heads, n_pages,
-                            page, hd), jnp.float32)
-            vp = kp
+        kv = pa.KVPool.zeros(cfg.num_layers, cfg.num_heads, n_pages, page,
+                             hd, jnp.int8 if quant else jnp.float32)
         x = params["wte"][jnp.asarray(ids)] + params["wpe"][pos]
+        start = jnp.zeros(1, jnp.int32)
         lens = jnp.asarray([s], jnp.int32)
         for li, blk in enumerate(params["blocks"]):
             y = _ln(x, blk["ln1_w"], blk["ln1_b"],
@@ -217,21 +209,9 @@ def _logit_probe(model, args, prompts, refs):
             qkv = jnp.matmul(y, blk["qkv_w"]) + blk["qkv_b"]
             qkv = qkv.reshape(s, 3, cfg.num_heads, hd)
             q = qkv[:, 0][None]  # [1, S, H, D]
-            if quant:
-                kp, ks, _ = pa.paged_quant_write(
-                    kp, ks, li, qkv[:, 1], page_idx, slot)
-                vp, vs, _ = pa.paged_quant_write(
-                    vp, vs, li, qkv[:, 2], page_idx, slot)
-                attn = pa.paged_attention(
-                    q, kp[li], vp[li], bt, lens,
-                    q_offsets=jnp.zeros(1, jnp.int32),
-                    k_scales=ks[li], v_scales=vs[li])
-            else:
-                kp = kp.at[li, :, page_idx, slot, :].set(qkv[:, 1])
-                vp = vp.at[li, :, page_idx, slot, :].set(qkv[:, 2])
-                attn = pa.paged_attention(
-                    q, kp[li], vp[li], bt, lens,
-                    q_offsets=jnp.zeros(1, jnp.int32))
+            kv, _ = kv.write("k", li, qkv[None, :, 1], bt, start, lens)
+            kv, _ = kv.write("v", li, qkv[None, :, 2], bt, start, lens)
+            attn = kv.attend(q, li, bt, lens, q_offsets=start)
             x = x + jnp.matmul(attn[0].reshape(s, cfg.hidden_size),
                                blk["out_w"]) + blk["out_b"]
             y = _ln(x, blk["ln2_w"], blk["ln2_b"],

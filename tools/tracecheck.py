@@ -23,8 +23,8 @@ Passes (see docs/STATIC_ANALYSIS.md for the catalog):
                     their designated lock
 * engine-mutation — DecodeEngine mutating calls outside the sanctioned
                     between-steps sites
-* donation        — jax.jit sites whose *_pages pool parameters are
-                    not all donated
+* donation        — jax.jit sites whose function takes the KV pool
+                    (`kv`) and does not donate it
 * fleet-trace     — HTTP sites under paddle_tpu/fleet/ (urlopen client
                     legs, do_* handlers) that neither propagate the
                     x-paddle-trace header nor sit on the control-plane
