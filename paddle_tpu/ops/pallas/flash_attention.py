@@ -5,11 +5,11 @@ Replaces the reference's fused inference attention
 matmul+softmax+matmul training path with tiled online-softmax kernels that
 keep the running statistics in VMEM (per /opt/skills/guides/pallas_guide.md).
 
-The backward pass is a real pair of Pallas kernels (dq and dk/dv tiles,
-recomputing P per tile from the saved logsumexp — no S^2 tensor ever hits
-HBM), matching the memory behaviour the flash-attention algorithm promises.
-Falls back to the XLA composed form when shapes don't tile or a dense mask
-is supplied.
+The backward pass is one Pallas kernel (dq, dk and dv from the same
+sub-tiles, P recomputed per sub-tile from the saved logsumexp — no S^2
+tensor ever hits HBM), matching the memory behaviour the flash-attention
+algorithm promises.  Falls back to the XLA composed form when shapes don't
+tile or a dense mask is supplied.
 
 Precision: every matrix product takes its operands in the dtype the caller
 passed and accumulates in float32 — bf16 q, k, v, dO (the train cells under
@@ -103,9 +103,9 @@ def _dot(a, b, dims):
 # skip more (10 of 16 at 256 against 3 of 4) and lose more than that to
 # the phases of a sub-tile not overlapping (per 256 x 256 of scores, in
 # bundles of the compiler's schedule: forward 289 / dk,dv 409 / dq 320 at
-# 512, 427 / 579 / 522 at 256; on the chip the three kernels take 2.14 ms
-# at 512 and 3.20 ms at 256, 16 x 12 heads of 1024: PR 30, PERF.md
-# section 6).
+# 512, 427 / 579 / 522 at 256; on the chip those three kernels took
+# 2.14 ms at 512 and 3.20 ms at 256, 16 x 12 heads of 1024: PR 30,
+# PERF.md section 6; the one backward kernel since PR 33 is 472 at 512).
 _SUB_Q = 512
 _SUB_K = 512
 
@@ -145,7 +145,8 @@ def _for_live_subtiles(causal, qi, kj, block_q, block_k, tile,
     position.  Full attention runs them all; under the causal mask all but
     those wholly above the diagonal (last q_pos < first k_pos).  The q
     sub-blocks are the outer loop, or with ``k_outer`` the K sub-blocks
-    (the dk/dv kernel, whose accumulators belong to a K sub-block)."""
+    (the backward kernel: its dk/dv accumulators belong to a K
+    sub-block)."""
     # a sub-tile's edge: _SUB_* where it divides the block, else the block
     sub_q = _SUB_Q if block_q % _SUB_Q == 0 else block_q
     sub_k = _SUB_K if block_k % _SUB_K == 0 else block_k
@@ -187,8 +188,8 @@ def _lanes(x, n):
     return x if n == _LANES else pltpu.repeat(x, n // _LANES, axis=1)
 
 
-# Causal dead-block fetch clamps, shared by the forward and both backward
-# kernels.  A tile wholly above the causal diagonal contributes nothing:
+# Causal dead-block fetch clamps, of the forward and the backward
+# kernel.  A tile wholly above the causal diagonal contributes nothing:
 # `_for_live_subtiles` finds no live sub-tile there, and these index maps
 # additionally skip the DMA by clamping the streamed block index to the
 # live range (Pallas skips re-fetch when the index repeats).
@@ -212,11 +213,14 @@ def _causal_q_clamp(block_q, block_k):
     return idx
 
 
+# The VMEM a kernel may use unless it asks for more, on this toolchain.
+_SCOPED_VMEM_BYTES = 16 * 1024 * 1024
+
 # VMEM budget for holding a head's full K+V resident in the forward
 # kernel: one K block spans the sequence, so a (batch*head, q block) pair
-# is one grid step however long the sequence (the scoped limit on this
-# toolchain is 16MB; leave room for the q/o blocks and pipelining
-# buffers).  Beyond it K/V stream in block_k pieces.
+# is one grid step however long the sequence (the rest of the scoped
+# limit is room for the q/o blocks and pipelining buffers).  Beyond it
+# K/V stream in block_k pieces.
 _RESIDENT_KV_BYTES = 6 * 1024 * 1024
 
 
@@ -225,14 +229,14 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_scr, l_scr, *,
     # grid (bh, num_q, num_k): K/V blocks stream through VMEM (k is the
     # fastest grid dim; one block when K/V are resident) while the
     # (bh, q)-pinned output block and the f32 scratch accumulators stay
-    # put — constant VMEM at any sequence length, same scheme as the
-    # backward kernels.  The scores are built TRANSPOSED, [keys, queries]:
-    # the running max and sum are then rows (lanes are queries; a
-    # reduction runs down the sublanes on the vector unit, a rescale is a
-    # sublane broadcast), and O^T = V^T P^T puts the head's 64 on the
-    # matrix unit's streaming side, where it wastes nothing, with P^T the
-    # stationary operand as it lies.  acc is O^T [d, block_q]; m and l are
-    # [8, block_q], sublanes equal.  The flush transposes back.
+    # put — constant VMEM at any sequence length.  The scores are built
+    # TRANSPOSED, [keys, queries]: the running max and sum are then rows
+    # (lanes are queries; a reduction runs down the sublanes on the vector
+    # unit, a rescale is a sublane broadcast), and O^T = V^T P^T puts the
+    # head's 64 on the matrix unit's streaming side, where it wastes
+    # nothing, with P^T the stationary operand as it lies.  acc is O^T
+    # [d, block_q]; m and l are [8, block_q], sublanes equal.  The flush
+    # transposes back.
     qi = pl.program_id(1)
     kj = pl.program_id(2)
     num_k = seq_k // block_k
@@ -324,27 +328,42 @@ def _pallas_forward(q, k, v, is_causal, scale, block_q, block_k):
 
 
 # ---------------------------------------------------------------------------
-# Backward kernels
+# Backward kernel
 # ---------------------------------------------------------------------------
 #
-# Standard flash-attention backward split into two kernels so each output
-# tile has a single writer:
-#   dkv kernel: grid over K blocks, loops over Q blocks, accumulates
-#               dV^T = dO^T P and dK^T = scale * Q^T dS
-#   dq  kernel: grid over Q blocks, loops over K blocks, accumulates
-#               dQ^T = scale * K^T dS^T
-# (transposed, [d, rows]: the head's 64 is then the matrix unit's
-# streaming side and P / dS its stationary operand, half the passes of
-# P^T dO with 64 of 128 output columns used; the flush transposes back and
-# applies the scale to the float32 accumulator)
-# with P recomputed per tile from the saved logsumexp and
-# dS = P * (dP - delta).  delta = rowsum(dO * O) is computed in-kernel
-# from the saved O (cheap VPU reduce) rather than precomputed — passing O
-# (input dtype, D lanes) costs 1/8 the HBM traffic of a broadcast f32
-# 128-lane delta array.  lse stays in the 128-lane broadcast layout
+# One kernel gives dQ, dK and dV.  S = Q K^T and dP = dO V^T are what every
+# gradient is made from, and the matrix unit holds the backward (module
+# docstring), so a live sub-tile builds P and dS once and feeds all three
+# accumulators from them: five products,
+#   S = Q K^T    dP = dO V^T    dV^T += dO^T P    dK^T += Q^T dS
+#   dQ^T += K^T dS^T
+# where a dk/dv kernel and a dq kernel that each rebuilt S and dP spent
+# seven (until PR 33; PERF.md section 6).  The accumulators are transposed,
+# [d, rows]: the head's 64 is then the matrix unit's streaming side and
+# P / dS its stationary operand, half the passes of P^T dO with 64 of 128
+# output columns used; the flush transposes back and applies the scale to
+# the float32 accumulator.  P is recomputed per sub-tile from the saved
+# logsumexp and dS = P * (dP - delta).  delta = rowsum(dO * O) is computed
+# in-kernel from the saved O (cheap VPU reduce) rather than precomputed —
+# passing O (input dtype, D lanes) costs 1/8 the HBM traffic of a broadcast
+# f32 128-lane delta array.  lse stays in the 128-lane broadcast layout
 # (upstream jax's flash kernel convention); the compact
 # (sq//128, 128)-packed alternative needs a cross-lane reshape in-kernel,
 # which Mosaic fails to lower.
+#
+# Who writes what: dK and dV belong to a K block, which the grid pins
+# while the q side streams past it (q is the fastest axis), so each has one
+# writer.  dQ belongs to a q block and sums over the K blocks, grid steps
+# that are not consecutive, and an output block can only be revisited
+# while it stays put: dQ's block is therefore the head's whole [seq_q, d],
+# resident with its [d, seq_q] float32 accumulator from the head's first
+# grid step to its last.  Where the blocks span the sequence (the train
+# cells: (1024, 1024) at 1024) a head is one grid step.  The kernel's VMEM
+# therefore grows with seq_q (768 bytes a row for bf16 heads of 64), and
+# `_bwd_vmem_limit` asks for what the shapes need once that passes what
+# every kernel gets.  The chip's 128 MiB end it: the chip's compiler takes
+# 131,072 rows of bf16 heads of 64 and 65,536 of float32 ones or of heads
+# of 128 (tests/test_tpu_compile.py), and refuses twice that.
 
 
 def _p_and_ds(q_sub, k_sub, v_sub, do_sub, o_sub, lse, scale, q_first,
@@ -362,64 +381,73 @@ def _p_and_ds(q_sub, k_sub, v_sub, do_sub, o_sub, lse, scale, q_first,
     return p, p * (dp - delta)
 
 
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
-                    dk_ref, dv_ref, acc_dk, acc_dv, *, block_q, block_k,
-                    seq_q, scale, causal):
+def _bwd_vmem_limit(seq_q, block_q, block_k, d, itemsize):
+    """The scoped VMEM the backward kernel has to ask for, or None while
+    what it holds fits in what every kernel gets.  Counted from the shapes,
+    with room: the streamed blocks in their two buffers (q, dO, O and the
+    log-sum-exp a q block; k, v, dk, dv a K block; a row of d < 128 takes
+    a whole lane tile), the dk/dv accumulators, a sub-tile's float32
+    intermediates, and what dQ keeps for a head: its float32 accumulator
+    and its output block's two buffers.  Not stated where it is not
+    needed: a stated limit is VMEM the compiler keeps free around the
+    kernel, whatever the kernel then uses of it (64 MiB stated at the
+    train cells' shape, where 8 are used, cost gpt2m-train's step 2.7%:
+    PR 33, PERF.md section 6)."""
+    row = itemsize * max(d, _LANES)
+    need = (2 * row * (3 * block_q + 4 * block_k)
+            + 2 * 4 * _LANES * block_q + 2 * 4 * d * block_k
+            + 6 * 4 * min(block_q, _SUB_Q) * min(block_k, _SUB_K)
+            + seq_q * (4 * d + 2 * row))
+    return need if need > _SCOPED_VMEM_BYTES else None
+
+
+def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
+                dq_ref, dk_ref, dv_ref, acc_dq, acc_dk, acc_dv, *,
+                block_q, block_k, seq_q, seq_k, scale, causal):
     # grid (bh, num_k, num_q): the q axis is the FASTEST grid dim, so the
-    # (bh, k)-pinned output blocks and f32 scratch accumulators stay
-    # resident while q/do/o/lse blocks stream through VMEM — constant VMEM
-    # at any sequence length (the all-rows-in-VMEM form topped out ~4k)
+    # (bh, k)-pinned dk/dv blocks and their f32 accumulators stay resident
+    # while q/do/o/lse blocks stream through VMEM; dq's block and
+    # accumulator span the head's rows and stay through all of its steps.
+    # A row's dQ sums its K sub-blocks in ascending order, whatever the
+    # blocks: ki is the slower axis and the sub-tile walk is K-outer.
     ki = pl.program_id(1)
     qj = pl.program_id(2)
-    num_q = seq_q // block_q
+    first_q = qj == 0
+    last_q = qj == seq_q // block_q - 1
 
-    @pl.when(qj == 0)
+    @pl.when(first_q)
     def _init():
         acc_dk[...] = jnp.zeros_like(acc_dk)
         acc_dv[...] = jnp.zeros_like(acc_dv)
 
+    @pl.when(first_q & (ki == 0))
+    def _init_dq():
+        acc_dq[...] = jnp.zeros_like(acc_dq)
+
     def tile(rows, cols, q_first, k_first):
         q_sub = q_ref[rows, :]                          # [sub_q, d]
+        k_sub = k_ref[cols, :]                          # [sub_k, d]
         do_sub = do_ref[rows, :]
         p, ds = _p_and_ds(
-            q_sub, k_ref[cols, :], v_ref[cols, :], do_sub, o_ref[rows, :],
+            q_sub, k_sub, v_ref[cols, :], do_sub, o_ref[rows, :],
             lse_ref[rows, :], scale, q_first, k_first, causal)
+        ds = ds.astype(q_sub.dtype)
         acc_dv[:, cols] += _dot(do_sub, p.astype(do_sub.dtype), _TN)
-        acc_dk[:, cols] += _dot(q_sub, ds.astype(q_sub.dtype), _TN)
+        acc_dk[:, cols] += _dot(q_sub, ds, _TN)
+        # the sub-tile's rows in the head, not in the streamed q block
+        head_rows = rows if block_q == seq_q else pl.ds(
+            pl.multiple_of(q_first, q_sub.shape[0]), q_sub.shape[0])
+        acc_dq[:, head_rows] += _dot(k_sub, ds, _TT)
 
     _for_live_subtiles(causal, qj, ki, block_q, block_k, tile, k_outer=True)
 
-    @pl.when(qj == num_q - 1)
+    @pl.when(last_q)
     def _flush():
         dk_ref[...] = (acc_dk[...].T * scale).astype(dk_ref.dtype)
         dv_ref[...] = acc_dv[...].T.astype(dv_ref.dtype)
 
-
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
-                   dq_ref, acc_dq, *, block_q, block_k, seq_k, scale,
-                   causal):
-    # grid (bh, num_q, num_k): k blocks stream while the dq accumulator
-    # stays pinned (same streaming scheme as the dkv kernel)
-    qi = pl.program_id(1)
-    kj = pl.program_id(2)
-    num_k = seq_k // block_k
-
-    @pl.when(kj == 0)
-    def _init():
-        acc_dq[...] = jnp.zeros_like(acc_dq)
-
-    def tile(rows, cols, q_first, k_first):
-        k_sub = k_ref[cols, :]                          # [sub_k, d]
-        _, ds = _p_and_ds(
-            q_ref[rows, :], k_sub, v_ref[cols, :], do_ref[rows, :],
-            o_ref[rows, :], lse_ref[rows, :], scale, q_first, k_first,
-            causal)
-        acc_dq[:, rows] += _dot(k_sub, ds.astype(k_sub.dtype), _TT)
-
-    _for_live_subtiles(causal, qi, kj, block_q, block_k, tile)
-
-    @pl.when(kj == num_k - 1)
-    def _flush():
+    @pl.when(last_q & (ki == seq_k // block_k - 1))
+    def _flush_dq():
         dq_ref[...] = (acc_dq[...].T * scale).astype(dq_ref.dtype)
 
 
@@ -439,9 +467,9 @@ def _pallas_backward(q, k, v, out, lse, g, is_causal, scale, block_q,
 
     q_idx = (_causal_q_clamp(block_q, block_k) if is_causal
              else _stream_idx)
-    dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, block_q=block_q, block_k=block_k,
-                          seq_q=sq, scale=s, causal=is_causal),
+    dq, dk, dv = pl.pallas_call(
+        functools.partial(_bwd_kernel, block_q=block_q, block_k=block_k,
+                          seq_q=sq, seq_k=sk, scale=s, causal=is_causal),
         grid=(b * h, sk // block_k, sq // block_q),
         in_specs=[
             pl.BlockSpec((None, block_q, d), q_idx),
@@ -452,38 +480,22 @@ def _pallas_backward(q, k, v, out, lse, g, is_causal, scale, block_q,
             pl.BlockSpec((None, block_q, _LANES), q_idx),
         ],
         out_specs=[
+            pl.BlockSpec((None, sq, d), lambda i, j, r: (i, 0, 0)),
             pl.BlockSpec((None, block_k, d), lambda i, j, r: (i, j, 0)),
             pl.BlockSpec((None, block_k, d), lambda i, j, r: (i, j, 0)),
         ],
         out_shape=[
+            _out_struct((b * h, sq, d), q.dtype, q, k, v, g),
             _out_struct((b * h, sk, d), k.dtype, q, k, v, g),
             _out_struct((b * h, sk, d), v.dtype, q, k, v, g),
         ],
-        scratch_shapes=[pltpu.VMEM((d, block_k), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((d, sq), jnp.float32),
+                        pltpu.VMEM((d, block_k), jnp.float32),
                         pltpu.VMEM((d, block_k), jnp.float32)],
-        name="flash_attention_bwd_dkv",
-    )(qr, kr, vr, dor, outr, lse_b)
-
-    kv_idx = (_causal_kv_clamp(block_q, block_k) if is_causal
-              else _stream_idx)
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, block_q=block_q, block_k=block_k,
-                          seq_k=sk, scale=s, causal=is_causal),
-        grid=(b * h, sq // block_q, sk // block_k),
-        in_specs=[
-            pl.BlockSpec((None, block_q, d), lambda i, j, r: (i, j, 0)),
-            pl.BlockSpec((None, block_k, d), kv_idx),
-            pl.BlockSpec((None, block_k, d), kv_idx),
-            pl.BlockSpec((None, block_q, d), lambda i, j, r: (i, j, 0)),
-            pl.BlockSpec((None, block_q, d), lambda i, j, r: (i, j, 0)),
-            pl.BlockSpec((None, block_q, _LANES),
-                         lambda i, j, r: (i, j, 0)),
-        ],
-        out_specs=pl.BlockSpec((None, block_q, d),
-                               lambda i, j, r: (i, j, 0)),
-        out_shape=_out_struct((b * h, sq, d), q.dtype, q, k, v, g),
-        scratch_shapes=[pltpu.VMEM((d, block_q), jnp.float32)],
-        name="flash_attention_bwd_dq",
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_bwd_vmem_limit(sq, block_q, block_k, d,
+                                             q.dtype.itemsize)),
+        name="flash_attention_bwd",
     )(qr, kr, vr, dor, outr, lse_b)
 
     return (dq.reshape(b, h, sq, d), dk.reshape(b, h, sk, d),
@@ -519,9 +531,9 @@ def flash_attention_fwd(q, k, v, mask=None, is_causal=False, scale=None,
                         block_q=None, block_k=None):
     """q,k,v: [B,H,S,D].  Uses the Pallas kernels when mask is None and shapes
     tile; otherwise the XLA composed reference.  Fully differentiable with a
-    Pallas backward (dq/dk/dv kernels recomputing P from the saved
-    logsumexp).  Block sizes (what one grid step holds; the kernels work
-    through it in sub-tiles): explicit arguments win; otherwise the
+    Pallas backward (one kernel for dq, dk and dv, recomputing P from the
+    saved logsumexp).  Block sizes (what one grid step holds; the kernels
+    work through it in sub-tiles): explicit arguments win; otherwise the
     per-shape measured winners from flash_autotune_cache.json, falling
     back to 512x512 shrunk by `pick_blocks` for sequences they don't
     divide.
@@ -578,12 +590,13 @@ def pick_blocks(seq_q: int, seq_k: int, block_q: int = 512,
 
 # -- measured block-size cache ----------------------------------------------
 # (block_q, block_k) per (seq_q, seq_k, d, dtype, causal), measured on the
-# chip as the device time of the three kernels through `_flash_diff`
-# (PR 30's sweep, PERF.md section 6; tools/bench_kernels.py times the same
-# call by the wall clock and writes the file too).  The entry point
-# prefers a cached winner over the divisibility default when the caller
-# left the blocks at their defaults.  A cache measured on other kernels is
-# not a measurement: measure again after a change to the kernels.
+# chip as the device time of the forward and the backward kernel through
+# `_flash_diff` (PR 33's sweep, PERF.md section 6; tools/bench_kernels.py
+# times the same call by the wall clock and writes the file too).  The
+# entry point prefers a cached winner over the divisibility default when
+# the caller left the blocks at their defaults.  A cache measured on other
+# kernels is not a measurement: measure again after a change to the
+# kernels.
 _AUTOTUNE_FILE = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                               "flash_autotune_cache.json")
 _AUTOTUNE: dict = {}

@@ -45,20 +45,36 @@ class TestFlashAttention:
                                    atol=2e-3)
         assert lse.shape == (2, 256) and bool(jnp.all(jnp.isfinite(lse)))
 
+    # what a grid step holds of S=256 in the one backward kernel: the whole
+    # head (dQ complete inside the step, as in the train cells); several
+    # K blocks and several q blocks (dQ summed across grid steps that are
+    # not consecutive, dK/dV across consecutive ones); uneven pairs
+    BWD_BLOCKS = {"one_grid_step": (256, 256), "k_and_q_blocks": (128, 128),
+                  "uneven": (128, 64), "k_blocks": (256, 64),
+                  "q_blocks": (64, 256)}
+
+    @pytest.mark.parametrize("blocks", BWD_BLOCKS.values(),
+                             ids=BWD_BLOCKS.keys())
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
     @pytest.mark.parametrize("causal", [False, True])
-    def test_backward_matches_xla(self, interpret_pallas, causal):
-        q, k, v, g = self._inputs(1)
+    def test_backward_matches_xla(self, interpret_pallas, causal, dtype,
+                                  blocks):
+        q, k, v, g = self._inputs(1, dtype=dtype)
+        f32 = dtype == jnp.float32
         out_p, vjp_p = jax.vjp(
-            lambda a, b, c: FA._flash_diff(a, b, c, causal, None, 128, 128),
+            lambda a, b, c: FA._flash_diff(a, b, c, causal, None, *blocks),
             q, k, v)
         out_x, vjp_x = jax.vjp(
-            lambda a, b, c: FA._xla_reference(a, b, c, None, causal, None),
-            q, k, v)
-        np.testing.assert_allclose(np.asarray(out_p), np.asarray(out_x),
-                                   atol=2e-3)
+            lambda a, b, c: FA._composed_attention(a, b, c, None, causal,
+                                                   None), q, k, v)
+        np.testing.assert_allclose(
+            self._f32(out_p), self._f32(out_x),
+            atol=2e-3 if f32 else self.BF16_OUT_ATOL)
         for got, want in zip(vjp_p(g), vjp_x(g)):
-            np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                       atol=2e-2)
+            assert got.dtype == dtype
+            np.testing.assert_allclose(
+                self._f32(got), self._f32(want),
+                atol=2e-2 if f32 else self.BF16_GRAD_ATOL)
 
     @pytest.mark.parametrize("causal", [False, True])
     def test_streaming_forward_matches_xla(self, interpret_pallas,
@@ -92,22 +108,6 @@ class TestFlashAttention:
     def test_noncausal_threshold_stays_1024(self):
         assert FA._auto_threshold(is_causal=True) == 512
         assert FA._auto_threshold(is_causal=False) == 1024
-
-    def test_uneven_blocks_backward(self, interpret_pallas):
-        # block_q != block_k exercises the causal loop-bound arithmetic
-        q, k, v, g = self._inputs(2, S=256)
-        out_p, vjp_p = jax.vjp(
-            lambda a, b, c: FA._flash_diff(a, b, c, True, None, 128, 64),
-            q, k, v)
-        out_x, vjp_x = jax.vjp(
-            lambda a, b, c: FA._xla_reference(a, b, c, None, True, None),
-            q, k, v)
-        np.testing.assert_allclose(np.asarray(out_p), np.asarray(out_x),
-                                   atol=2e-3)
-        for got, want in zip(vjp_p(g), vjp_x(g)):
-            np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                       atol=2e-2)
-
 
     # ---- operands in their own dtype: bf16 products, f32 accumulation ----
     # Tolerances for bf16 operands against `_composed_attention` on the
@@ -162,8 +162,8 @@ class TestFlashAttention:
                                        atol=self.BF16_GRAD_ATOL)
 
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-    @pytest.mark.parametrize("sub", [(512, 512), (64, 128), (128, 64),
-                                     (64, 64)])
+    @pytest.mark.parametrize("sub", [(512, 512), (96, 192), (64, 128),
+                                     (128, 64), (64, 64)])
     @pytest.mark.parametrize("blocks", [(64, 128), (256, 128), (128, 256),
                                         (256, 256)])
     def test_causal_tiles_below_on_and_above_the_diagonal(
@@ -172,8 +172,9 @@ class TestFlashAttention:
         # the kernel, and one wholly above the diagonal is skipped.  Every
         # ratio of block to sub-tile and of rows to columns has to find
         # the live ones (S=256: up to 4 x 4 sub-tiles, inside one grid
-        # step or across several), in all three kernels; (512, 512) is the
-        # shipped sub-tile, larger than these blocks: one a grid step
+        # step or across several), in both kernels; (512, 512) is the
+        # shipped sub-tile, larger than these blocks, and (96, 192) divides
+        # none of them: one sub-tile a grid step
         monkeypatch.setattr(FA, "_SUB_Q", sub[0])
         monkeypatch.setattr(FA, "_SUB_K", sub[1])
         q, k, v, g = self._inputs(6, dtype=dtype)
@@ -194,16 +195,20 @@ class TestFlashAttention:
                 self._f32(got), self._f32(want),
                 atol=2e-2 if f32 else self.BF16_GRAD_ATOL)
 
+    @pytest.mark.parametrize("lengths", [(128, 256), (256, 128)])
     @pytest.mark.parametrize("sub", [(64, 128), (128, 64)])
     def test_full_attention_sub_tiles(self, interpret_pallas, monkeypatch,
-                                      sub):
+                                      sub, lengths):
         # no mask: every sub-tile of every tile runs, cross-length too
+        # (more keys than queries: one q block a head; more queries than
+        # keys: dQ's rows span two q blocks)
         monkeypatch.setattr(FA, "_SUB_Q", sub[0])
         monkeypatch.setattr(FA, "_SUB_K", sub[1])
-        q, _, _, g = self._inputs(8, S=128)
-        _, k, v, _ = self._inputs(8, S=256)
+        sq, sk = lengths
+        q, _, _, g = self._inputs(8, S=sq)
+        _, k, v, _ = self._inputs(8, S=sk)
         out_p, vjp_p = jax.vjp(
-            lambda a, b, c: FA._flash_diff(a, b, c, False, None, 128, 256),
+            lambda a, b, c: FA._flash_diff(a, b, c, False, None, 128, sk),
             q, k, v)
         out_x, vjp_x = jax.vjp(
             lambda a, b, c: FA._composed_attention(a, b, c, None, False,
@@ -214,12 +219,38 @@ class TestFlashAttention:
             np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                        atol=2e-2)
 
+    @pytest.mark.parametrize("dtype, d, seq, blocks, stated", [
+        (jnp.bfloat16, 64, 1024, (1024, 1024), False),   # the train cells
+        (jnp.float32, 64, 1024, (1024, 1024), False),
+        (jnp.bfloat16, 64, 4096, (1024, 1024), False),
+        (jnp.bfloat16, 128, 2048, (1024, 1024), False),
+        (jnp.bfloat16, 64, 8192, (1024, 1024), True),
+        (jnp.bfloat16, 64, 2048, (2048, 2048), True),
+        (jnp.float32, 64, 32768, (1024, 1024), True),
+    ])
+    def test_backward_states_a_vmem_limit_only_where_the_shapes_need_it(
+            self, dtype, d, seq, blocks, stated):
+        # dQ's block and accumulator span a head's rows: the backward
+        # kernel's VMEM grows with the sequence, and only past what every
+        # kernel gets does it state a limit of its own (that the chip's
+        # compiler accepts what is stated, and needs it: test_tpu_compile)
+        limit = FA._bwd_vmem_limit(seq, *blocks, d,
+                                   jnp.dtype(dtype).itemsize)
+        if not stated:
+            assert limit is None
+        else:
+            # at least the head's dQ accumulator and output block, and
+            # inside the chip's 128 MiB
+            held = seq * d * (4 + jnp.dtype(dtype).itemsize)
+            assert FA._SCOPED_VMEM_BYTES < limit < 128 * 2 ** 20
+            assert limit > held
+
     @pytest.mark.parametrize("forward", ["resident", "streaming"])
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
     def test_matrix_products_take_the_operands_dtype(self, monkeypatch,
                                                      dtype, forward):
-        """Every `dot_general` of the traced kernel bodies (forward, dk/dv
-        and dq) takes both operands in the caller's dtype — bf16 in, bf16
+        """Every `dot_general` of the traced kernel bodies (forward and
+        backward) takes both operands in the caller's dtype — bf16 in, bf16
         products; float32 in, float32 products — and gives float32."""
         if forward == "streaming":
             monkeypatch.setattr(FA, "_RESIDENT_KV_BYTES", 0)
@@ -237,10 +268,10 @@ class TestFlashAttention:
                 dots = [e for e in _walk(eqn.params["jaxpr"])
                         if e.primitive.name == "dot_general"]
                 kernels[eqn.params["name"]] = dots
-        # products a sub-tile: 2 forward, 4 for dk/dv, 3 for dq
+        # products a sub-tile: 2 forward; 5 backward (S and dP once, then
+        # dV, dK and dQ from the same P and dS)
         assert {n: len(d) for n, d in kernels.items()} == {
-            "flash_attention_fwd": 2, "flash_attention_bwd_dkv": 4,
-            "flash_attention_bwd_dq": 3}
+            "flash_attention_fwd": 2, "flash_attention_bwd": 5}
         for name, dots in kernels.items():
             for e in dots:
                 assert [x.aval.dtype for x in e.invars] == [dtype, dtype], \

@@ -124,18 +124,18 @@ def test_flash_fwd_bwd_at_the_train_shape(one_chip, dtype, batch):
             jnp.float32).sum()
 
     compiled = _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)), x, x, x)
-    # forward, dk/dv and dq kernels, each under its own name (what a
-    # device trace shows them as)
-    assert compiled.as_text().count(chip_smoke.KERNEL) >= 3
-    for name in ("flash_attention_fwd", "flash_attention_bwd_dkv",
-                 "flash_attention_bwd_dq"):
+    # the forward and the backward kernel, each under its own name (what
+    # a device trace shows them as)
+    assert compiled.as_text().count(chip_smoke.KERNEL) >= 2
+    for name in ("flash_attention_fwd", "flash_attention_bwd"):
         assert _has_kernel(compiled, name), name
     # what the benchmark's `classify` counts to find them
     # (`flash_attn_roofline.train`): [b*h, seq, d] operands, exactly
-    # three for the forward call, five or more for each backward call
+    # three for the forward call (q, k, v), five or more for a backward
+    # call (q, k, v, o, dO; the sixth operand is the log-sum-exp)
     shaped = {}
     for line in compiled.as_text().splitlines():
-        m = re.search(r"%\w*?(flash_attention_(?:fwd|bwd_dkv|bwd_dq))_*"
+        m = re.search(r"%\w*?(flash_attention_(?:fwd|bwd))_*"
                       r"(\.\d+)? = .*custom-call\((.*?)\), "
                       r"custom_call_target", line)
         if m:
@@ -146,8 +146,54 @@ def test_flash_fwd_bwd_at_the_train_shape(one_chip, dtype, batch):
                 len(re.findall(rf"\[{batch * HEADS},{seq},{HEAD_DIM}\]",
                                layouts)))
     assert shaped == {"flash_attention_fwd": (3, 3),
-                      "flash_attention_bwd_dkv": (6, 5),
-                      "flash_attention_bwd_dq": (6, 5)}
+                      "flash_attention_bwd": (6, 5)}
+    # the cell's shape states no VMEM limit (a stated one is room the
+    # compiler keeps free around the kernel)
+    assert FA._bwd_vmem_limit(seq, *blocks, HEAD_DIM,
+                              jnp.dtype(dtype).itemsize) is None
+
+
+@pytest.mark.parametrize("dtype, head_dim, seq, blocks", [
+    (jnp.bfloat16, 64, 8192, (1024, 1024)),
+    (jnp.bfloat16, 64, 8192, (2048, 2048)),     # the cache's longest entry
+    (jnp.bfloat16, 128, 2048, (2048, 1024)),    # the cache's heads of 128
+    (jnp.bfloat16, 64, 32768, (1024, 1024)),
+    (jnp.float32, 64, 32768, (1024, 1024)),     # refused at 16 MB
+    (jnp.bfloat16, 128, 65536, (1024, 1024)),
+    (jnp.bfloat16, 64, 131072, (1024, 1024)),   # 107 of the chip's 128 MiB
+])
+def test_flash_backward_keeps_a_heads_dq_in_vmem(one_chip, dtype, head_dim,
+                                                 seq, blocks):
+    """dQ's block and accumulator span a head's rows, so the backward
+    kernel's VMEM grows with the sequence; past what every kernel gets it
+    asks for what its shapes need (`_bwd_vmem_limit`), and the chip's
+    compiler accepts that up to the lengths a chip's 128 MB hold."""
+    x = jax.ShapeDtypeStruct((1, 2, seq, head_dim), dtype, sharding=one_chip)
+    lse = jax.ShapeDtypeStruct((2, seq), jnp.float32, sharding=one_chip)
+    compiled = _compile(
+        functools.partial(FA._pallas_backward, is_causal=True, scale=None,
+                          block_q=blocks[0], block_k=blocks[1]),
+        x, x, x, x, lse, x)
+    assert _has_kernel(compiled, "flash_attention_bwd")
+
+
+def test_flash_fwd_bwd_inside_a_shard_map(topo):
+    """`_flash_diff` differentiated inside a `shard_map` with
+    ``check_vma=True`` (batch over the four chips): every output of both
+    kernels has to say over which mesh axes it varies (`_out_struct`), the
+    backward's three too."""
+    mesh = Mesh(np.asarray(topo.devices), ("dp",))
+    x = jax.ShapeDtypeStruct((4, HEADS, MAX_LEN, HEAD_DIM), jnp.bfloat16,
+                             sharding=NamedSharding(mesh, P("dp")))
+    attend = jax.shard_map(
+        lambda q, k, v: FA._flash_diff(q, k, v, True, None, *TRAIN_BLOCKS),
+        mesh=mesh, in_specs=(P("dp"),) * 3, out_specs=P("dp"),
+        check_vma=True)
+    compiled = _compile(jax.grad(
+        lambda q, k, v: attend(q, k, v).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2)), x, x, x)
+    for name in ("flash_attention_fwd", "flash_attention_bwd"):
+        assert _has_kernel(compiled, name), name
 
 
 def test_layer_norm_768(one_chip):
