@@ -952,9 +952,9 @@ def _gpt_prefill(params, ids, true_len, bt_row, kv, key, *,
         q = qkv[:, 0].transpose(1, 0, 2)[None]  # [1, H, S, D]
         k = qkv[:, 1].transpose(1, 0, 2)[None]
         v = qkv[:, 2].transpose(1, 0, 2)[None]
-        kv, rk = kv.write("k", li, qkv[None, :, 1], bt, start, cap)
-        kv, rv = kv.write("v", li, qkv[None, :, 2], bt, start, cap)
-        refolds += rk + rv
+        kv, r = kv.write(li, qkv[None, :, 1], qkv[None, :, 2], bt, start,
+                         cap)
+        refolds += r
         attn = _sdpa_reference(q, k, v, None, 0.0, None, True)[0]
         attn = attn.transpose(1, 0, 2).reshape(s_pad, h)
         x = x + _wmm(attn, blk, "out_w") + blk["out_b"]
@@ -978,8 +978,9 @@ def _gpt_decode_step(params, kv, block_tables, seq_lens, tokens, active,
     """One batched decode step over every slot: write the incoming
     token's K/V into its page, ragged paged attention over the pool,
     sample the next token.  The pool (`pa.KVPool`) is donated; a float
-    one is rewritten one page a live slot where it lies: on the chip
-    nothing pool-sized moves but the per-layer slice the kernel takes
+    one has the page of each live slot, K and V, rewritten where it
+    lies by one `paged_kv_write` kernel a layer: on the chip nothing
+    pool-sized moves but the per-layer slice the attention kernel takes
     (tests/test_tpu_compile.py).  Inactive slots write nothing (cap 0)
     and read length 0.  Returns ``(kv, next tokens [B])``."""
     b = tokens.shape[0]
@@ -996,9 +997,9 @@ def _gpt_decode_step(params, kv, block_tables, seq_lens, tokens, active,
         qkv = _wmm(y, blk, "qkv_w") + blk["qkv_b"]
         qkv = qkv.reshape(b, 3, num_heads, head_dim)
         q, k, v = qkv[:, 0], qkv[:, 1], qkv[:, 2]  # [B, H, D]
-        kv, rk = kv.write("k", li, k[:, None], block_tables, seq_lens, caps)
-        kv, rv = kv.write("v", li, v[:, None], block_tables, seq_lens, caps)
-        refolds += rk + rv
+        kv, r = kv.write(li, k[:, None], v[:, None], block_tables, seq_lens,
+                         caps)
+        refolds += r
         attn = kv.attend(q, li, block_tables, lens_now)
         x = x + _wmm(attn.reshape(b, h), blk, "out_w") + blk["out_b"]
         y = _ln(x, blk["ln2_w"], blk["ln2_b"], eps)
@@ -1034,10 +1035,8 @@ def _gpt_layer_bq(blk, li, x, kv, block_tables, seq_lens, write_caps,
     qkv = cst(qkv.reshape(b, qn, 3, num_heads, head_dim),
               None, None, None, "mp", None)
     q = qkv[:, :, 0]                                     # [B, Q, H, D]
-    kv, rk = kv.write("k", li, qkv[:, :, 1], block_tables, seq_lens,
-                      write_caps, mesh=mesh)
-    kv, rv = kv.write("v", li, qkv[:, :, 2], block_tables, seq_lens,
-                      write_caps, mesh=mesh)
+    kv, refolds = kv.write(li, qkv[:, :, 1], qkv[:, :, 2], block_tables,
+                           seq_lens, write_caps, mesh=mesh)
     attn = cst(kv.attend(q, li, block_tables, lens_now,
                          q_offsets=seq_lens, mesh=mesh),
                None, None, "mp", None)
@@ -1052,7 +1051,7 @@ def _gpt_layer_bq(blk, li, x, kv, block_tables, seq_lens, write_caps,
             None, "mp")
     # row-parallel fc2: second all-reduce of the block
     x = cst(x + (_wmm(y, blk, "fc2_w") + blk["fc2_b"]).reshape(b, qn, h))
-    return x, kv, rk + rv
+    return x, kv, refolds
 
 
 def _gpt_mixed_step(params, kv, block_tables, seq_lens, tokens,

@@ -46,12 +46,12 @@ multiplies the page tile by it in-register after the DMA (the Tensix/TPP in-kern
 dequant materialization pass ever exists), and the XLA reference
 dequantizes the gathered pages before the identical attention math so
 the two backends stay bit-identical to each other.  The write side of a
-float pool is `paged_kv_write` (a page at a time, where the pool lies;
-its rows are `kv_pool_width` wide); that of an int8 pool is
-`paged_quant_write`: the serving step executables quantize every
-scattered K/V chunk in-graph (per-head absmax folded into the running
-page scale, existing page rows re-quantized when the scale grows —
-the "refold").
+float pool is the `paged_kv_write` kernel (the K and V pages a write
+touches, read and written back where the pool lies; its rows are
+`kv_pool_width` wide); that of an int8 pool is `paged_quant_write`: the
+serving step executables quantize every scattered K/V chunk in-graph
+(per-head absmax folded into the running page scale, existing page rows
+re-quantized when the scale grows — the "refold").
 
 `KVPool` is the one place that knows which of the two a pool is: the
 serving step bodies hold a pool and ask it to `write` rows and to
@@ -139,7 +139,8 @@ def default_page_size(max_len, d, dtype=jnp.float32):
 # ---------------------------------------------------------------------------
 # Write-capped K/V row coordinates: the int8 pool's row scatter
 # (`paged_quant_write`) and the oracle of tests/test_paged_kv_write.py.
-# Float pools are written a page at a time, by `paged_kv_write` below.
+# Float pools are written a page a grid step, by the `paged_kv_write`
+# kernel below.
 # ---------------------------------------------------------------------------
 def paged_write_indices(block_tables, seq_lens, write_caps, qn,
                         num_pages_total, page):
@@ -233,64 +234,159 @@ def kv_layer(pool, li, head_dim):
     return pool[li, :, :, :, :head_dim]
 
 
-@jax.jit
-def paged_kv_write(pool, li, rows, block_tables, seq_lens, write_caps):
-    """In-place write of new K or V rows into layer ``li`` of a float
-    page pool: row ``i < write_caps[b]`` of sequence ``b`` lands at
-    position ``seq_lens[b] + i`` of its pages; every other row of the
-    pool keeps its bytes.
+def _kv_write_kernel(li_ref, spans_ref, src_ref, sl_ref, cap_ref, *refs,
+                     page, n_span, num_pages, head_dim, n_chunks):
+    # grid (b, j): span j of sequence b, one page of K and one of V a
+    # step.  refs: K's rows (the one block, or the two chunks the page
+    # straddles), V's likewise, the K and V pools' page blocks in, the
+    # same out (aliased), then the padding scratch of a short run.
+    del li_ref
+    per = 1 if n_chunks == 1 else 2
+    k_rows, v_rows = refs[:per], refs[per:2 * per]
+    k_in, v_in, k_out, v_out = refs[2 * per:2 * per + 4]
+    pad = refs[2 * per + 4:]
+    b, j = pl.program_id(0), pl.program_id(1)
+    i = b * n_span + j
 
-    pool: [L, Hkv, P, page, W] (donated by the caller's jit; W =
-    `kv_pool_width` of D); rows: [B, Q, Hkv, D]; block_tables:
-    [B, pages_max] int32; seq_lens, write_caps: [B] int32, caps in
-    [0, Q] (0 = the slot writes nothing).
+    def page_of(s):
+        return jnp.minimum(spans_ref[src_ref[s]], num_pages - 1)
 
-    One page at a time: for each sequence and each page its run can
-    touch (`paged_write_spans`: at most ``n_span``), read the page
-    ``[Hkv, page, W]``, select the new rows in by a row mask, write the
-    page back with `lax.dynamic_update_slice`.  The update's window is a
-    whole page in the pool's own dimension order, so the pool is updated
-    where it lies — a row scatter's window is ``[Hkv, D]``, and the TPU
-    compiler re-laid the whole pool out around it (heads next to ``D``),
-    twice a layer.
+    # the first step on a page takes it as the pool holds it; the steps
+    # after it on the same page (dead spans that repeat it) keep what the
+    # out block holds, since the pipeline neither refetches nor writes
+    # back a block whose index repeats
+    @pl.when((i == 0) | (page_of(i) != page_of(jnp.maximum(i - 1, 0))))
+    def _take():
+        k_out[...] = k_in[...]
+        v_out[...] = v_in[...]
 
-    `dynamic_update_slice` clamps where a scatter drops: a span entry
-    of ``P`` (inactive slot, page past the run's end) reads and writes
-    back the pool's last page unchanged, and rows past the cap keep
-    what the page held.
+    @pl.when(spans_ref[i] < num_pages)
+    def _write():
+        sl = sl_ref[b]
+        # run row r lands on page row (sl + r) % page: rolling a
+        # page-aligned chunk of the run by s puts each row where it lands
+        s = sl % page
+        shape = k_out.shape[:2] + (head_dim,)
+        t = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        row = j * page - s + t   # the run's row at page row t
+        fresh = (row >= 0) & (row < cap_ref[b])
 
-    Jitted with ``li`` an operand: a step body's 2 x L calls trace and
-    lower the loop once and call it 2 x L times (unrolled into the
-    step's text it doubled the text, and the seconds a start spends
-    lowering it)."""
-    _, hkv, num_pages, page, width = pool.shape
-    b, qn, _, d = rows.shape
+        def window(chunks):
+            if pad:  # a run shorter than a page: make it one
+                pad[0][:, :chunks[0].shape[1], :] = \
+                    chunks[0][...].astype(jnp.float32)
+                chunks = pad
+            first, *second = (pltpu.roll(c[...].astype(jnp.float32), s, 1)
+                              for c in chunks)
+            # rows before page row s come from the chunk the page starts in,
+            # the rest from the next one
+            return first if not second else \
+                jnp.where((t < s) | (s == 0), first, second[0])
+
+        for rows, out in ((k_rows, k_out), (v_rows, v_out)):
+            out[:, :, :head_dim] = jnp.where(
+                fresh, window(rows).astype(out.dtype), out[:, :, :head_dim])
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _paged_kv_write(k_pool, v_pool, li, k_rows, v_rows, block_tables,
+                    seq_lens, write_caps, *, interpret):
+    _, hkv, num_pages, page, width = k_pool.shape
+    b, qn, _, d = k_rows.shape
     n_span = (qn + page - 2) // page + 1
+    n_chunks = -(-qn // page)
     spans = paged_write_spans(block_tables, seq_lens, write_caps, qn,
                               num_pages, page)            # [B * n_span]
-    # head-major and as wide as the pool's rows, a page of padding
-    # either side: the page-aligned window of a run that starts
-    # mid-page stays in bounds
-    rows = jnp.pad(rows.transpose(0, 2, 1, 3).astype(pool.dtype),
-                   ((0, 0), (0, 0), (page, page), (0, width - d)))
-    offs = jnp.arange(page, dtype=jnp.int32)
+    # the step whose blocks each step takes: itself where its span
+    # writes, else the nearest step before it that writes, else the
+    # first that does — a dead span repeats its neighbour's page, so it
+    # moves no page of its own
+    steps = jnp.arange(b * n_span, dtype=jnp.int32)
+    live = spans < num_pages
+    src = jax.lax.cummax(jnp.where(live, steps, -1), axis=0)
+    src = jnp.where(src >= 0, src, jnp.argmax(live).astype(jnp.int32))
+    seq_lens = seq_lens.astype(jnp.int32)
 
-    def write_page(i, pool):
-        bi, j = i // n_span, i % n_span
-        pid = spans[i]
-        # the run's row that sits at this page's row 0 (negative: the
-        # page starts before the run does)
-        row0 = (seq_lens[bi] // page + j) * page - seq_lens[bi]
-        new = jax.lax.dynamic_slice(
-            rows, (bi, 0, row0 + page, 0), (1, hkv, page, width))
-        fresh = (pid < num_pages) & (row0 + offs >= 0) & \
-            (row0 + offs < write_caps[bi])
-        at = (li, 0, jnp.minimum(pid, num_pages - 1), 0, 0)
-        old = jax.lax.dynamic_slice(pool, at, (1, hkv, 1, page, width))
-        return jax.lax.dynamic_update_slice(
-            pool, jnp.where(fresh[:, None], new[:, :, None], old), at)
+    def pool_map(bi, j, li, spans, src, sl, cap):
+        page_id = jnp.minimum(spans[src[bi * n_span + j]], num_pages - 1)
+        return (li[0], 0, page_id, 0, 0)
 
-    return jax.lax.fori_loop(0, b * n_span, write_page, pool)
+    def rows_map(k):
+        def index(bi, j, li, spans, src, sl, cap):
+            s = src[bi * n_span + j]
+            bs, js = s // n_span, s % n_span
+            # the chunk of the run this step's page starts in (k = 0) or
+            # the next one (k = 1)
+            c = js - jnp.where(sl[bs] % page > 0, 1, 0) + k
+            return (bs, 0, jnp.clip(c, 0, n_chunks - 1), 0)
+        return index
+
+    chunk = qn if n_chunks == 1 else page
+    rows_specs = [pl.BlockSpec((None, hkv, chunk, d), rows_map(k))
+                  for k in range(1 if n_chunks == 1 else 2)]
+    page_spec = pl.BlockSpec((None, hkv, None, page, width), pool_map)
+    prefetch = (jnp.reshape(li, (1,)).astype(jnp.int32), spans, src,
+                seq_lens, write_caps.astype(jnp.int32))
+    n_in = len(prefetch) + 2 * len(rows_specs)
+    # head-major rows at their own width: [B, Hkv, Q, D]
+    k_rows, v_rows = (r.transpose(0, 2, 1, 3) for r in (k_rows, v_rows))
+    return pl.pallas_call(
+        functools.partial(_kv_write_kernel, page=page, n_span=n_span,
+                          num_pages=num_pages, head_dim=d,
+                          n_chunks=n_chunks),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(prefetch),
+            grid=(b, n_span),
+            in_specs=rows_specs * 2 + [page_spec, page_spec],
+            out_specs=[page_spec, page_spec],
+            scratch_shapes=[pltpu.VMEM((hkv, page, d), jnp.float32)]
+            if qn < page else []),
+        out_shape=(jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype),
+                   jax.ShapeDtypeStruct(v_pool.shape, v_pool.dtype)),
+        input_output_aliases={n_in: 0, n_in + 1: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret,
+        name="paged_kv_write",
+    )(*prefetch, *(k_rows,) * len(rows_specs), *(v_rows,) * len(rows_specs),
+      k_pool, v_pool)
+
+
+def paged_kv_write(k_pool, v_pool, li, k_rows, v_rows, block_tables,
+                   seq_lens, write_caps):
+    """In-place write of layer ``li``'s new K rows and V rows into a float
+    page pool's K and V pages, in one Pallas kernel: row ``i <
+    write_caps[b]`` of sequence ``b`` lands at position ``seq_lens[b] +
+    i`` of its pages; every other row of the pools keeps its bytes.
+    Returns ``(k_pool, v_pool)``.
+
+    k_pool, v_pool: [L, Hkv, P, page, W] (donated by the caller's jit;
+    W = `kv_pool_width` of D); k_rows, v_rows: [B, Q, Hkv, D];
+    block_tables: [B, pages_max] int32; seq_lens, write_caps: [B] int32,
+    caps in [0, Q] (0 = the slot writes nothing).
+
+    Grid ``(B, n_span)``: a step for each page a sequence's run can
+    touch (`paged_write_spans`).  The span's page id, through the layer
+    ``li``, all scalar prefetch, picks a ``[Hkv, page, W]`` block of each
+    pool in the index map; the pools are aliased onto the outputs, so
+    the pages are read and written back where they lie, and nothing else
+    of the pool moves.  The rows come at their own width: the chunk of
+    the run a page straddles is rolled into place and selected in by a
+    row mask.  A dead span (cap 0, sentinel ``P``, past the run's end)
+    skips its body and repeats the page of the step before it (of the
+    first live step, if none before it is live), so it costs no page
+    transfer and can never write a stale copy over a live write: the
+    pipeline writes a block back only when the next step names another.
+    Pages written by different sequences are distinct, as the allocator
+    hands them out.
+
+    One kernel for every step: decode (Q = 1), mixed and ragged steps,
+    verify, one-request prefill.  ``li`` is an operand, so a step body's
+    L calls lower the kernel once.  Off the TPU the same kernel runs in
+    interpret mode."""
+    return _paged_kv_write(k_pool, v_pool, li, k_rows, v_rows, block_tables,
+                           seq_lens, write_caps,
+                           interpret=jax.default_backend() != "tpu")
 
 
 def paged_quant_write(pages, scales, li, vals, page_idx, slot,
@@ -828,39 +924,55 @@ class KVPool:
             lambda a: jax.device_put(a, by_head), self)
 
     # -- inside a step executable --------------------------------------------
-    def write(self, name, li, rows, block_tables, seq_lens, write_caps,
+    def write(self, li, k_rows, v_rows, block_tables, seq_lens, write_caps,
               mesh=None):
-        """Layer ``li``'s new K rows (``name`` "k") or V rows ("v") in:
-        row ``i < write_caps[b]`` of sequence ``b`` lands at position
-        ``seq_lens[b] + i`` of its pages.  rows: [B, Q, Hkv, D];
-        block_tables: [B, pages_max] int32; seq_lens, write_caps: [B]
-        int32, caps in [0, Q].  Returns ``(pool, refolds)``: the count
-        of page scales that grew (`paged_quant_write`) — the int 0 for
-        a float pool, which traces nothing.  Under ``mesh`` the written
-        arrays stay split on their head axis.
+        """Layer ``li``'s new K rows and V rows in: row ``i <
+        write_caps[b]`` of sequence ``b`` lands at position ``seq_lens[b]
+        + i`` of its pages.  k_rows, v_rows: [B, Q, Hkv, D]; block_tables:
+        [B, pages_max] int32; seq_lens, write_caps: [B] int32, caps in
+        [0, Q].  Returns ``(pool, refolds)``: the count of page scales
+        that grew (`paged_quant_write`) — the int 0 for a float pool,
+        which traces nothing.
 
-        One array a call, so that a step body traces K's rows, K's
-        write, V's rows, V's write in the order it always did."""
-        cst = mesh_constrain(mesh)
-        pages = getattr(self, name)
+        A float pool takes both in one `paged_kv_write` kernel; under
+        ``mesh`` the call sits in a `jax.shard_map` over ``mp`` with
+        pools and rows split on their head axis, as `attend`'s does.
+        An int8 pool's arrays stay split on their head axis by
+        constraint."""
         if not self.quantized:
-            pages = paged_kv_write(pages, li, rows, block_tables, seq_lens,
-                                   write_caps)
-            return dataclasses.replace(self, **{
-                name: cst(pages, None, "mp", None, None, None)}), 0
-        b, qn, hkv, d = rows.shape
-        num_pages, page = pages.shape[2:4]
+            def write(k, v, k_rows, v_rows, block_tables, seq_lens,
+                      write_caps):
+                return paged_kv_write(k, v, li, k_rows, v_rows,
+                                      block_tables, seq_lens, write_caps)
+
+            if mesh is not None:
+                by_head = PartitionSpec(None, "mp")   # pools
+                rows = PartitionSpec(None, None, "mp")
+                rep = PartitionSpec()
+                write = jax.shard_map(
+                    write, mesh=mesh,
+                    in_specs=(by_head, by_head, rows, rows, rep, rep, rep),
+                    out_specs=(by_head, by_head), check_vma=False)
+            k, v = write(self.k, self.v, k_rows, v_rows, block_tables,
+                         seq_lens, write_caps)
+            return dataclasses.replace(self, k=k, v=v), 0
+        cst = mesh_constrain(mesh)
+        b, qn, hkv, d = k_rows.shape
+        num_pages, page = self.k.shape[2:4]
         page_idx, slot = paged_write_indices(
             block_tables, seq_lens, write_caps, qn, num_pages, page)
-        pages, scales, refolds = paged_quant_write(
-            pages, getattr(self, name + "_scales"), li,
-            rows.reshape(b * qn, hkv, d), page_idx.reshape(-1),
-            slot.reshape(-1),
-            paged_write_spans(block_tables, seq_lens, write_caps, qn,
-                              num_pages, page))
-        return dataclasses.replace(self, **{
-            name: cst(pages, None, "mp", None, None, None),
-            name + "_scales": cst(scales, None, "mp", None)}), refolds
+        spans = paged_write_spans(block_tables, seq_lens, write_caps, qn,
+                                  num_pages, page)
+        out, refolds = {}, 0
+        for name, rows in (("k", k_rows), ("v", v_rows)):
+            pages, scales, r = paged_quant_write(
+                getattr(self, name), getattr(self, name + "_scales"), li,
+                rows.reshape(b * qn, hkv, d), page_idx.reshape(-1),
+                slot.reshape(-1), spans)
+            out[name] = cst(pages, None, "mp", None, None, None)
+            out[name + "_scales"] = cst(scales, None, "mp", None)
+            refolds += r
+        return dataclasses.replace(self, **out), refolds
 
     def attend(self, q, li, block_tables, seq_lens, q_offsets=None,
                mesh=None):

@@ -1,11 +1,14 @@
 """`pa.paged_kv_write` against the row scatter it replaced.
 
 The scatter (`pool.at[li, :, page_idx, slot, :D].set(rows)`, coordinates
-from `paged_write_indices`) is the oracle: after the write the whole pool
-is bit-equal to it, and every page the run does not name is bit-untouched.
-`dynamic_update_slice` clamps where the scatter drops, so the cases lean on
-the clamp: inactive slots, caps of 0, sentinels, and a slot whose last page
-is the pool's last page.
+from `paged_write_indices`) is the oracle: after the write each pool, K
+and V, is bit-equal to its own scatter, and every page the run does not
+name is bit-untouched.  The kernel runs here in interpret mode.  A span
+that writes nothing repeats the page of a span that does, and the
+pipeline writes a page back only when the next step names another, so
+the cases lean on that: inactive slots, caps of 0, sentinels, a slot
+whose last page is the pool's last page, and a call whose only live span
+is on that page.
 """
 import numpy as np
 import pytest
@@ -60,6 +63,10 @@ def _case(name):
         return bt, [60, 63, 56], [4, 1, 8], 16
     if name == "prefill_one_request":     # B=1 from position 0, padded
         return bt[:1], [0], [37], 64
+    if name == "one_live_span_on_the_last_page":  # every other span dead
+        return bt, [0, 20, 50], [0, 0, 3], 16
+    if name == "verify_window":           # a few rows, not a page's worth
+        return bt, [14, 0, 33], [5, 0, 3], 5
     raise KeyError(name)
 
 
@@ -67,57 +74,79 @@ CASES = ["decode", "decode_inactive", "decode_all_inactive",
          "decode_page_start", "chunk_mid_page_crossing", "chunk_aligned",
          "chunk_caps_zero_and_partial", "chunk_one_row_among_many",
          "long_run_three_pages", "last_page_of_the_pool",
-         "sentinel_block_table", "past_the_horizon", "prefill_one_request"]
+         "sentinel_block_table", "past_the_horizon", "prefill_one_request",
+         "one_live_span_on_the_last_page", "verify_window"]
 
 
 def _operands(name, seed=0):
+    """K and V pools and rows of a named case, each drawn on its own."""
     bt, lens, caps, qn = _case(name)
     rng = np.random.default_rng(seed)
-    pool = rng.standard_normal((L, HKV, P, PAGE, W)).astype(np.float32)
-    pool[..., D:] = 0.0   # lanes past D hold zeros and stay zeros
-    rows = rng.standard_normal((len(lens), qn, HKV, D)).astype(np.float32)
-    return (jnp.asarray(pool), jnp.asarray(rows), jnp.asarray(bt),
-            jnp.asarray(lens, jnp.int32), jnp.asarray(caps, jnp.int32))
+    pools = rng.standard_normal((2, L, HKV, P, PAGE, W)).astype(np.float32)
+    pools[..., D:] = 0.0   # lanes past D hold zeros and stay zeros
+    rows = rng.standard_normal((2, len(lens), qn, HKV, D)).astype(np.float32)
+    return (*map(jnp.asarray, pools), *map(jnp.asarray, rows),
+            jnp.asarray(bt), jnp.asarray(lens, jnp.int32),
+            jnp.asarray(caps, jnp.int32))
 
 
 @pytest.mark.parametrize("name", CASES)
 def test_bit_equal_to_the_scatter(name):
-    pool, rows, bt, lens, caps = _operands(name)
-    want = _scatter_oracle(pool, LI, rows, bt, lens, caps)
-    got = jax.jit(pa.paged_kv_write, static_argnums=1)(
-        pool, LI, rows, bt, lens, caps)
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    k, v, k_rows, v_rows, bt, lens, caps = _operands(name)
+    got = jax.jit(pa.paged_kv_write, static_argnums=2)(
+        k, v, LI, k_rows, v_rows, bt, lens, caps)
+    for pool, rows, out in ((k, k_rows, got[0]), (v, v_rows, got[1])):
+        want = _scatter_oracle(pool, LI, rows, bt, lens, caps)
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
 
 
 @pytest.mark.parametrize("name", CASES)
 def test_pages_not_named_are_untouched(name):
-    pool, rows, bt, lens, caps = _operands(name, seed=1)
-    got = np.asarray(pa.paged_kv_write(pool, LI, rows, bt, lens, caps))
-    before = np.asarray(pool)
-    page = PAGE
+    k, v, k_rows, v_rows, bt, lens, caps = _operands(name, seed=1)
+    outs = pa.paged_kv_write(k, v, LI, k_rows, v_rows, bt, lens, caps)
     named = set()
     for b, (n0, cap) in enumerate(zip(np.asarray(lens), np.asarray(caps))):
         for pos in range(int(n0), int(n0) + int(cap)):
-            named.add(int(np.asarray(bt)[b, pos // page]))
+            named.add(int(np.asarray(bt)[b, pos // PAGE]))
     others = [p for p in range(P) if p not in named]
-    np.testing.assert_array_equal(got[:, :, others], before[:, :, others])
-    # and no other layer moved at all
     rest = [li for li in range(L) if li != LI]
-    np.testing.assert_array_equal(got[rest], before[rest])
-    # something was written where a cap is positive
-    if int(np.asarray(caps).sum()):
-        assert not np.array_equal(got[LI], before[LI])
+    for pool, out in ((k, outs[0]), (v, outs[1])):
+        got, before = np.asarray(out), np.asarray(pool)
+        np.testing.assert_array_equal(got[:, :, others],
+                                      before[:, :, others])
+        # and no other layer moved at all
+        np.testing.assert_array_equal(got[rest], before[rest])
+        # something was written where a cap is positive
+        if int(np.asarray(caps).sum()):
+            assert not np.array_equal(got[LI], before[LI])
+
+
+@pytest.mark.parametrize("name", ["decode", "chunk_mid_page_crossing",
+                                  "one_live_span_on_the_last_page"])
+def test_k_and_v_rows_land_in_their_own_pools(name):
+    """One call writes both: K's pages hold K's rows and V's pages V's,
+    from pools that start alike and rows that differ."""
+    k, _, k_rows, _, bt, lens, caps = _operands(name, seed=2)
+    v_rows = -2.0 * k_rows + 1.0
+    got_k, got_v = pa.paged_kv_write(k, k, LI, k_rows, v_rows, bt, lens,
+                                     caps)
+    for rows, out in ((k_rows, got_k), (v_rows, got_v)):
+        want = _scatter_oracle(k, LI, rows, bt, lens, caps)
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
+    assert not np.array_equal(np.asarray(got_k), np.asarray(got_v))
 
 
 def test_rows_are_cast_to_the_pool_dtype():
-    pool, rows, bt, lens, caps = _operands("chunk_mid_page_crossing")
-    pool16 = pool.astype(jnp.bfloat16)
-    got = pa.paged_kv_write(pool16, LI, rows, bt, lens, caps)
-    want = _scatter_oracle(pool16, LI, rows.astype(jnp.bfloat16), bt, lens,
-                           caps)
-    assert got.dtype == jnp.bfloat16
-    np.testing.assert_array_equal(np.asarray(got.astype(jnp.float32)),
-                                  np.asarray(want.astype(jnp.float32)))
+    k, v, k_rows, v_rows, bt, lens, caps = _operands(
+        "chunk_mid_page_crossing")
+    k16, v16 = k.astype(jnp.bfloat16), v.astype(jnp.bfloat16)
+    got = pa.paged_kv_write(k16, v16, LI, k_rows, v_rows, bt, lens, caps)
+    for pool, rows, out in ((k16, k_rows, got[0]), (v16, v_rows, got[1])):
+        want = _scatter_oracle(pool, LI, rows.astype(jnp.bfloat16), bt,
+                               lens, caps)
+        assert out.dtype == jnp.bfloat16
+        np.testing.assert_array_equal(np.asarray(out.astype(jnp.float32)),
+                                      np.asarray(want.astype(jnp.float32)))
 
 
 @pytest.mark.parametrize("head_dim, width",

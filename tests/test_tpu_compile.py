@@ -288,9 +288,9 @@ def _engine_param_shapes(cfg, mesh=None, one_chip=None):
 # ---------------------------------------------------------------------------
 CELL = dict(slots=16, num_pages=256)  # benchmarks/workloads/gpt2s-serve-chat
 # opcodes that may hold a layer of the pool or more: the pool passing
-# through, the page write, the per-layer slice for the kernel
-_POOL_OPS = {"parameter", "get-tuple-element", "tuple", "while", "bitcast",
-             "slice", "dynamic-slice", "dynamic-update-slice"}
+# through and the per-layer slice for the kernel (the page write is the
+# `paged_kv_write` kernel, whose outputs alias the pools)
+_POOL_OPS = {"parameter", "get-tuple-element", "tuple", "bitcast", "slice"}
 
 
 def _serve_step(one_chip, which, num_pages):
@@ -326,14 +326,14 @@ def _serve_step(one_chip, which, num_pages):
 def _pool_sized_faults(text, num_pages, layer_elems):
     """Instructions of a compiled step that hold a layer of the pool or
     more (whole layers' worth of elements, the pages among the dimensions,
-    in whatever order) and are not the pool passing through, the in-place
-    page write or the per-layer slice: a copy, a transpose, a fusion of
-    another kind, or any array in another layout than its dimension
-    order."""
+    in whatever order) and are not the pool passing through, the
+    in-place page write or the per-layer slice: a copy, a transpose, a
+    loop, a fusion of another kind, or any array in another layout than
+    its dimension order."""
     faults = []
     for line in text.splitlines():
-        m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = f32\[([\d,]+)\]"
-                     r"\{([\d,]+)[^}]*\} ([\w\-]+)\(", line)
+        m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = \(?f32\[([\d,]+)\]"
+                     r"\{([\d,]+)[^}]*\}.*? ([\w\-]+)\(", line)
         if m is None:
             continue
         name, dims, layout, op = m.groups()
@@ -342,8 +342,10 @@ def _pool_sized_faults(text, num_pages, layer_elems):
             continue
         in_order = layout == ",".join(
             str(i) for i in reversed(range(len(dims))))
-        if op == "fusion":  # named for what it fuses, or traced to it
-            ok = "slice" in name or "dynamic_update_slice" in line
+        if op == "fusion":  # named for what it fuses
+            ok = "slice" in name
+        elif op == "custom-call":
+            ok = re.match(r"paged_kv_write(\.\d+)?$", name) is not None
         else:
             ok = op in _POOL_OPS
         if not (ok and in_order):
@@ -358,7 +360,8 @@ def test_serve_step_writes_kv_where_the_pool_lies(one_chip, as_on_tpu, which):
     1.21 GB pool out around every write, and on the way in and out of the
     step (temp 7.37 GB on the chip, PR 24); with whole pages written into
     lane-wide rows nothing pool-sized is left but the write and the
-    per-layer slice."""
+    per-layer slice.  The write is one `paged_kv_write` kernel a layer,
+    K and V together, and no loop carries the pool."""
     from benchmarks.kernels import paged_attention as bench_pa
 
     compiled = _serve_step(one_chip, which, CELL["num_pages"])
@@ -369,6 +372,15 @@ def test_serve_step_writes_kv_where_the_pool_lies(one_chip, as_on_tpu, which):
     assert any(bench_pa.classify(line, HEADS, CELL["num_pages"], HEAD_DIM)
                for line in text.splitlines())
     page = PA.default_page_size(MAX_LEN, HEAD_DIM, jnp.float32)
+    pool = (f"f32[{BASE['num_layers']},{HEADS},{CELL['num_pages']},{page},"
+            f"{PA.kv_pool_width(HEAD_DIM)}]")
+    assert _has_kernel(compiled, "paged_kv_write")
+    writes = [line for line in text.splitlines()
+              if re.match(r"\s*%paged_kv_write(\.\d+)? = ", line)]
+    assert len(writes) == BASE["num_layers"]
+    assert all(line.count(pool) >= 4 for line in writes)  # K, V in and out
+    assert not [line for line in text.splitlines()
+                if " while(" in line and pool in line]
     layer = HEADS * CELL["num_pages"] * page * HEAD_DIM
     assert _pool_sized_faults(text, CELL["num_pages"], layer) == []
     kv_bytes = 2 * BASE["num_layers"] * layer * 4    # 1.21 GB of K/V
@@ -389,7 +401,8 @@ def test_decode_step_at_four_times_the_pool(one_chip, as_on_tpu):
 def test_sharded_serving_step_holds_the_kernel(topo, as_on_tpu):
     """`_gpt_ragged_step(mesh=mp4)`: GSPMD refuses to partition a Mosaic
     kernel from sharding constraints ("wrap the call in a shard_map") — the
-    step's kernel call sits inside one over ``mp``.  Full width, two
+    step's kernel calls, attention and the K/V write, sit inside one over
+    ``mp``: each chip writes its own heads of its pages.  Full width, two
     layers."""
     cfg = GPTConfig(use_parallel_layers=False, **{**BASE, "num_layers": 2})
     mesh = Mesh(np.asarray(topo.devices).reshape(4), ("mp",))
@@ -416,10 +429,19 @@ def test_sharded_serving_step_holds_the_kernel(topo, as_on_tpu):
     text = compiled.as_text()
     assert chip_smoke.KERNEL in text
     assert " all-reduce" in text  # row-parallel out-proj and fc2
+    # the write kernel on a chip's head-slice of the pools, K and V
+    width = PA.kv_pool_width(HEAD_DIM)
+    local = (f"f32[{cfg.num_layers},{HEADS // 4},{num_pages},{page},"
+             f"{width}]")
+    writes = [line for line in text.splitlines()
+              if re.match(r"\s*%paged_kv_write(\.\d+)? = ", line)]
+    assert len(writes) == cfg.num_layers
+    assert all(line.count(local) >= 4 for line in writes)
     pool_dims = f"{num_pages},{page},{HEAD_DIM}]"
     for line in text.splitlines():
         if " all-gather" in line:
             assert pool_dims not in line
+            assert f"{num_pages},{page},{width}]" not in line
 
 
 def test_hybrid_train_step_holds_the_kernel(topo, as_on_tpu):

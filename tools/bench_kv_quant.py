@@ -209,8 +209,8 @@ def _logit_probe(model, args, prompts, refs):
             qkv = jnp.matmul(y, blk["qkv_w"]) + blk["qkv_b"]
             qkv = qkv.reshape(s, 3, cfg.num_heads, hd)
             q = qkv[:, 0][None]  # [1, S, H, D]
-            kv, _ = kv.write("k", li, qkv[None, :, 1], bt, start, lens)
-            kv, _ = kv.write("v", li, qkv[None, :, 2], bt, start, lens)
+            kv, _ = kv.write(li, qkv[None, :, 1], qkv[None, :, 2], bt, start,
+                             lens)
             attn = kv.attend(q, li, bt, lens, q_offsets=start)
             x = x + jnp.matmul(attn[0].reshape(s, cfg.hidden_size),
                                blk["out_w"]) + blk["out_b"]
