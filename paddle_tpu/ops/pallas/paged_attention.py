@@ -18,7 +18,13 @@ decode.
 
 Kernel shape (the TPU paged-decode idiom):
 
-* grid ``(batch, kv_heads, pages_max)`` with the page axis fastest;
+* grid ``(batch, kv_heads // hb, pages_max)`` with the page axis
+  fastest: a step takes one page of ``hb`` K/V heads, a
+  ``(hb, page, d)`` block of each pool.  ``hb`` is worked out from the
+  shapes (`heads_per_block`: the largest divisor of the K/V heads whose
+  blocks fit a VMEM budget), so a model of a dozen heads of 64 takes
+  them all in one step — a grid step has a fixed cost (~0.17 us on a
+  v5e), and most steps of a serving call are dead;
 * the block table and sequence lengths ride in as **scalar-prefetch**
   operands (`pltpu.PrefetchScalarGridSpec`) so the K/V BlockSpec index
   maps can translate the streamed page number through the block table —
@@ -29,7 +35,8 @@ Kernel shape (the TPU paged-decode idiom):
   off with ``pl.when``;
 * per-(batch, kv-head) online-softmax state (f32 acc / running max /
   running sum) stays resident in VMEM scratch across the page stream, so
-  VMEM usage is constant in sequence length.
+  VMEM usage is constant in sequence length.  Each head of a block runs
+  the arithmetic of a block of one, so ``hb`` never changes a bit.
 
 The XLA reference (`_xla_paged_attention`) is the numerics ground truth
 and the CPU path; it mirrors `nn.functional.attention._sdpa_reference`'s
@@ -525,19 +532,62 @@ def _xla_paged_attention(q, k_pages, v_pages, block_tables, seq_lens,
 # ---------------------------------------------------------------------------
 # Pallas decode kernel
 # ---------------------------------------------------------------------------
-def _decode_kernel(bt_ref, sl_ref, qo_ref, q_ref, k_ref, v_ref, o_ref,
-                   acc, m_scr, l_scr, *, page, pages_max, scale, group,
-                   q_len):
-    # grid (b, h_kv, p): one KV page streams through VMEM per step while
-    # the (b, h)-pinned query tile and f32 softmax state stay resident.
-    # Query rows are (query_token, gqa_group) pairs: row r is query
-    # token r // group, at absolute position qo + r // group, so each
-    # row carries its own causal limit (a per-row ragged mask instead of
-    # the single-token `pos < sl`).
+# VMEM a grid step's blocks and state may take: under the 16 MiB every
+# kernel gets unless it asks for more, with room for the compiler's own
+# temporaries (the block's logits and probabilities, f32).
+_PAGED_VMEM_BUDGET = 12 * 1024 * 1024
+
+
+def _vmem_tile_bytes(rows, cols, dtype):
+    """Bytes of a [rows, cols] VMEM tile of ``dtype`` as the chip lays it
+    out: rows up to whole sublane tiles (8 of 32 bits, 16 of 16, 32 of
+    8), columns up to whole 128-lane rows."""
+    item = jnp.dtype(dtype).itemsize
+    sub = 8 * (4 // item)
+    return -(-rows // sub) * sub * -(-cols // _LANES) * _LANES * item
+
+
+def _head_vmem_bytes(rows, page, d, q_dtype, kv_dtype):
+    """VMEM one K/V head takes in a grid step of the paged kernel: its
+    query and output tiles (``rows`` x ``d``) and its K and V page tiles
+    (``page`` x ``d``), each in the pipeline's two buffers, and its f32
+    online-softmax state (acc, running max, running sum)."""
+    return (2 * (2 * _vmem_tile_bytes(rows, d, q_dtype)
+                 + 2 * _vmem_tile_bytes(page, d, kv_dtype))
+            + _vmem_tile_bytes(rows, d, jnp.float32)
+            + 2 * _vmem_tile_bytes(rows, _LANES, jnp.float32))
+
+
+def heads_per_block(hkv, rows, page, d, q_dtype, kv_dtype):
+    """The K/V heads one grid step of the paged kernel takes: the largest
+    divisor of ``hkv`` whose heads fit `_PAGED_VMEM_BUDGET` together (1
+    where even one does not).  ``rows``: query rows a head, padded to
+    the sublane tile."""
+    head = _head_vmem_bytes(rows, page, d, q_dtype, kv_dtype)
+    fit = max(1, _PAGED_VMEM_BUDGET // head)
+    return max(h for h in range(1, min(hkv, fit) + 1) if hkv % h == 0)
+
+
+def _attend_pages(sl_ref, qo_ref, q_ref, o_ref, acc, m_scr, l_scr, kv, *,
+                  page, pages_max, scale, group, q_len):
+    # grid (b, head block, p): one page of every head of the block
+    # streams through VMEM per step while the (b, head block)-pinned
+    # query tile and f32 softmax state stay resident.  Query rows are
+    # (query_token, gqa_group) pairs: row r is query token r // group, at
+    # absolute position qo + r // group, so each row carries its own
+    # causal limit (a per-row ragged mask instead of the single-token
+    # `pos < sl`).  ``kv()`` gives the block's K and V page tiles in f32,
+    # [hb, page, d].  The block's heads are the leading (batch) dimension
+    # of every operation, so each head runs the arithmetic of a block of
+    # one: the same bits whatever hb is (a Python loop over the heads
+    # gives them too, but is traced and lowered anew at every call site
+    # of every step executable, 0.2 s a call at 12 heads, and runs
+    # slower on the chip).
     b = pl.program_id(0)
     p = pl.program_id(2)
     sl = sl_ref[b]
     qo = qo_ref[b]
+    rows = q_ref.shape[1]
 
     @pl.when(p == 0)
     def _init():
@@ -547,16 +597,6 @@ def _decode_kernel(bt_ref, sl_ref, qo_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(p * page < sl)
     def _compute():
-        q = q_ref[...].astype(jnp.float32) * scale    # [rows, d]
-        k = k_ref[...].astype(jnp.float32)            # [page, d]
-        v = v_ref[...].astype(jnp.float32)
-        rows = q_ref.shape[0]
-        m = m_scr[...][:, 0]
-        l = l_scr[...][:, 0]
-        logits = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # [rows, page]
         # per-row causal limit: row r sees kv pos < min(sl, qo + qi + 1)
         # (padded rows clamp to the last real query so their reads stay
         # inside the live range; their output is sliced away anyway)
@@ -565,33 +605,49 @@ def _decode_kernel(bt_ref, sl_ref, qo_ref, q_ref, k_ref, v_ref, o_ref,
             q_len - 1)
         pos = p * page + jax.lax.broadcasted_iota(
             jnp.int32, (rows, page), 1)
-        masked = pos < jnp.minimum(sl, qo + row_q + 1)
+        masked = (pos < jnp.minimum(sl, qo + row_q + 1))[None]
+
+        q = q_ref[...].astype(jnp.float32) * scale    # [hb, rows, d]
+        k, v = kv()                                   # [hb, page, d]
+        m = m_scr[...][:, :, 0]
+        l = l_scr[...][:, :, 0]
+        logits = jax.lax.dot_general(
+            q, k, (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32,
+        )  # [hb, rows, page]
         logits = jnp.where(masked, logits, -1e30)
         m_blk = jnp.max(logits, axis=-1)
         m_new = jnp.maximum(m, m_blk)
-        pr = jnp.exp(logits - m_new[:, None])
+        pr = jnp.exp(logits - m_new[..., None])
         # a row fully masked on this page (early query, late page) has
         # m_new == -1e30 and exp(0) == 1 everywhere: zero it explicitly
         pr = jnp.where(masked, pr, 0.0)
         alpha = jnp.exp(m - m_new)
         l_new = l * alpha + jnp.sum(pr, axis=-1)
-        acc[...] = acc[...] * alpha[:, None] + jax.lax.dot_general(
-            pr, v, (((1,), (0,)), ((), ())),
+        acc[...] = acc[...] * alpha[..., None] + jax.lax.dot_general(
+            pr, v, (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
         )
-        m_scr[...] = jnp.broadcast_to(m_new[:, None], m_scr.shape)
-        l_scr[...] = jnp.broadcast_to(l_new[:, None], l_scr.shape)
+        m_scr[...] = jnp.broadcast_to(m_new[..., None], m_scr.shape)
+        l_scr[...] = jnp.broadcast_to(l_new[..., None], l_scr.shape)
 
     @pl.when(p == pages_max - 1)
     def _flush():
-        l = l_scr[...][:, 0]
-        o_ref[...] = (acc[...] / jnp.maximum(l, 1e-30)[:, None]
+        l = l_scr[...][:, :, 0]
+        o_ref[...] = (acc[...] / jnp.maximum(l, 1e-30)[..., None]
                       ).astype(o_ref.dtype)
 
 
+def _decode_kernel(bt_ref, sl_ref, qo_ref, q_ref, k_ref, v_ref, o_ref,
+                   acc, m_scr, l_scr, **kw):
+    def kv():
+        return k_ref[...].astype(jnp.float32), v_ref[...].astype(jnp.float32)
+
+    _attend_pages(sl_ref, qo_ref, q_ref, o_ref, acc, m_scr, l_scr, kv, **kw)
+
+
 def _decode_kernel_q(bt_ref, sl_ref, qo_ref, q_ref, k_ref, v_ref, ks_ref,
-                     vs_ref, o_ref, acc, m_scr, l_scr, *, page, pages_max,
-                     scale, group, q_len):
+                     vs_ref, o_ref, acc, m_scr, l_scr, *, page, **kw):
     # Quantized twin of `_decode_kernel`: the K/V page tiles stream in
     # as int8 and dequantize IN-REGISTER right after the DMA.  The
     # scales arrive already gathered through the block table — one
@@ -601,66 +657,26 @@ def _decode_kernel_q(bt_ref, sl_ref, qo_ref, q_ref, k_ref, v_ref, ks_ref,
     # the pool (pool-sized [Hkv, num_pages] tables on the scalar-
     # prefetch channel overflowed the 1 MB of SMEM at 8192 pages x 12
     # heads).  Everything after the dequant multiply is the unquantized
-    # kernel verbatim (the f32 online-softmax state and masking are
-    # identical), which is what keeps the two paths' numerics aligned
-    # with the XLA reference's dequant-then-attend.
-    b = pl.program_id(0)
-    p = pl.program_id(2)
-    sl = sl_ref[b]
-    qo = qo_ref[b]
+    # kernel's (`_attend_pages`), which is what keeps the two paths'
+    # numerics aligned with the XLA reference's dequant-then-attend.
+    sl = sl_ref[pl.program_id(0)]
     live = jnp.maximum((sl + page - 1) // page, 1)
-    ent = jnp.minimum(p, live - 1)  # the streamed page's table entry
+    ent = jnp.minimum(pl.program_id(2), live - 1)  # the page's table entry
 
-    @pl.when(p == 0)
-    def _init():
-        acc[...] = jnp.zeros_like(acc)
-        m_scr[...] = jnp.full_like(m_scr, -1e30)
-        l_scr[...] = jnp.zeros_like(l_scr)
-
-    @pl.when(p * page < sl)
-    def _compute():
-        q = q_ref[...].astype(jnp.float32) * scale    # [rows, d]
+    def kv():
         # dequant in-register, then round-trip through the QUERY dtype
         # exactly like the XLA reference's `.astype(q.dtype)` — for
         # sub-f32 models (bf16) the cast is lossy, and skipping it here
         # would make the two backends attend over different K/V values
         # (a no-op for f32, where the tests pin bit-identical operands)
-        k = (k_ref[...].astype(jnp.float32) * ks_ref[0, ent]
-             ).astype(q_ref.dtype).astype(jnp.float32)
-        v = (v_ref[...].astype(jnp.float32) * vs_ref[0, ent]
-             ).astype(q_ref.dtype).astype(jnp.float32)
-        rows = q_ref.shape[0]
-        m = m_scr[...][:, 0]
-        l = l_scr[...][:, 0]
-        logits = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # [rows, page]
-        row_q = jnp.minimum(
-            jax.lax.broadcasted_iota(jnp.int32, (rows, page), 0) // group,
-            q_len - 1)
-        pos = p * page + jax.lax.broadcasted_iota(
-            jnp.int32, (rows, page), 1)
-        masked = pos < jnp.minimum(sl, qo + row_q + 1)
-        logits = jnp.where(masked, logits, -1e30)
-        m_blk = jnp.max(logits, axis=-1)
-        m_new = jnp.maximum(m, m_blk)
-        pr = jnp.exp(logits - m_new[:, None])
-        pr = jnp.where(masked, pr, 0.0)
-        alpha = jnp.exp(m - m_new)
-        l_new = l * alpha + jnp.sum(pr, axis=-1)
-        acc[...] = acc[...] * alpha[:, None] + jax.lax.dot_general(
-            pr, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m_scr[...] = jnp.broadcast_to(m_new[:, None], m_scr.shape)
-        l_scr[...] = jnp.broadcast_to(l_new[:, None], l_scr.shape)
+        return tuple(
+            jnp.stack([(ref[h].astype(jnp.float32) * s_ref[h, 0, ent]
+                        ).astype(q_ref.dtype).astype(jnp.float32)
+                       for h in range(ref.shape[0])])
+            for ref, s_ref in ((k_ref, ks_ref), (v_ref, vs_ref)))
 
-    @pl.when(p == pages_max - 1)
-    def _flush():
-        l = l_scr[...][:, 0]
-        o_ref[...] = (acc[...] / jnp.maximum(l, 1e-30)[:, None]
-                      ).astype(o_ref.dtype)
+    _attend_pages(sl_ref, qo_ref, q_ref, o_ref, acc, m_scr, l_scr, kv,
+                  page=page, **kw)
 
 
 def _pallas_paged_attention(q, k_pages, v_pages, block_tables, seq_lens,
@@ -679,6 +695,7 @@ def _pallas_paged_attention(q, k_pages, v_pages, block_tables, seq_lens,
     rows = qn * g
     gp = -(-rows // _MIN_GROUP_ROWS) * _MIN_GROUP_ROWS
     s = scale if scale is not None else 1.0 / math.sqrt(d)
+    hb = heads_per_block(hkv, gp, page, d, q.dtype, k_pages.dtype)
 
     qg = q.reshape(b, qn, hkv, g, d).transpose(0, 2, 1, 3, 4)
     qg = qg.reshape(b, hkv, rows, d)
@@ -692,32 +709,32 @@ def _pallas_paged_attention(q, k_pages, v_pages, block_tables, seq_lens,
     q_offsets = q_offsets.astype(jnp.int32)
     quant = k_scales is not None
 
-    def q_map(bi, h, p, *pref):
-        return (bi, h, 0, 0)
+    def q_map(bi, hi, p, *pref):
+        return (bi, hi, 0, 0)
 
-    def kv_map(bi, h, p, bt, sl, *pref):
+    def kv_map(bi, hi, p, bt, sl, *pref):
         # dead pages clamp to the last live page: the repeated index
         # skips the DMA (flash_attention's dead-block clamp, paged form).
         # max(live, 1) keeps a zero-length slot pointing at a real page.
         live = jnp.maximum((sl[bi] + page - 1) // page, 1)
-        return (h, bt[bi, jnp.minimum(p, live - 1)], 0, 0)
+        return (hi, bt[bi, jnp.minimum(p, live - 1)], 0, 0)
 
     in_specs = [
-        pl.BlockSpec((None, None, gp, d), q_map),
-        pl.BlockSpec((None, None, page, d), kv_map),
-        pl.BlockSpec((None, None, page, d), kv_map),
+        pl.BlockSpec((None, hb, gp, d), q_map),
+        pl.BlockSpec((hb, None, page, d), kv_map),
+        pl.BlockSpec((hb, None, page, d), kv_map),
     ]
     operands = (block_tables, seq_lens, q_offsets, qg, k_pages, v_pages)
     if quant:
         # gather the page scales through the block table BEFORE the
         # call: [B, Hkv, 1, pages_max] f32, entry j of row (b, h) being
         # the scale of sequence b's j-th page — the same indirection
-        # the DMA maps use, done once in XLA.  Each (b, h) row rides
-        # into SMEM as a block beside the query tile, on the query's
-        # index map (the unit dim makes the block's last two dims the
-        # array's own, which the TPU lowering asks of a block that is
-        # no multiple of 8x128).
-        scale_spec = pl.BlockSpec((None, None, 1, pages_max), q_map,
+        # the DMA maps use, done once in XLA.  The block's rows ride
+        # into SMEM beside the query tile, on the query's index map
+        # (the unit dim makes the block's last two dims the array's
+        # own, which the TPU lowering asks of a block that is no
+        # multiple of 8x128).
+        scale_spec = pl.BlockSpec((None, hb, 1, pages_max), q_map,
                                   memory_space=pltpu.SMEM)
         in_specs += [scale_spec, scale_spec]
         operands += tuple(
@@ -726,13 +743,13 @@ def _pallas_paged_attention(q, k_pages, v_pages, block_tables, seq_lens,
             for sc in (k_scales, v_scales))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(b, hkv, pages_max),
+        grid=(b, hkv // hb, pages_max),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((None, None, gp, d), q_map),
+        out_specs=pl.BlockSpec((None, hb, gp, d), q_map),
         scratch_shapes=[
-            pltpu.VMEM((gp, d), jnp.float32),
-            pltpu.VMEM((gp, _LANES), jnp.float32),
-            pltpu.VMEM((gp, _LANES), jnp.float32),
+            pltpu.VMEM((hb, gp, d), jnp.float32),
+            pltpu.VMEM((hb, gp, _LANES), jnp.float32),
+            pltpu.VMEM((hb, gp, _LANES), jnp.float32),
         ],
     )
     kernel = _decode_kernel_q if quant else _decode_kernel

@@ -220,6 +220,30 @@ class TestQuantPagedAttention:
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=2e-5)
 
+    @pytest.mark.parametrize("qn", [1, 4])
+    @pytest.mark.parametrize("hb", [4, 1])
+    def test_quant_heads_per_block_matches_reference(
+            self, interpret_pallas, monkeypatch, hb, qn):
+        """The int8 kernel with every K/V head of a page in one grid step
+        (``hb`` = Hkv) and with one head a step: each head reads its own
+        row of the gathered scales."""
+        q, k8, v8, bt, lens, ks, vs = self._quant_pool(
+            4, hq=8, hkv=4, lens=(40, 0, 96))
+        rng = np.random.RandomState(qn)
+        qm = jnp.asarray(rng.randn(3, qn, 8, 32).astype(np.float32))
+        rows = -(-qn * 2 // 8) * 8
+        monkeypatch.setattr(PA, "_PAGED_VMEM_BUDGET", hb *
+                            PA._head_vmem_bytes(rows, 16, 32, jnp.float32,
+                                                jnp.int8))
+        assert PA.heads_per_block(4, rows, 16, 32, jnp.float32,
+                                  jnp.int8) == hb
+        out = PA._pallas_paged_attention(qm, k8, v8, bt, lens,
+                                         k_scales=ks, v_scales=vs)
+        ref = PA._xla_paged_attention(qm, k8, v8, bt, lens,
+                                      k_scales=ks, v_scales=vs)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   atol=2e-5)
+
     def test_entry_point_validates_scales(self):
         q, k8, v8, bt, lens, ks, vs = self._quant_pool(3)
         with pytest.raises(ValueError, match="int8 KV pages need"):

@@ -119,6 +119,55 @@ class TestPagedAttentionKernel:
         ref = PA._xla_paged_attention(q, kp, vp, bt, lens)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref))
 
+    @pytest.mark.parametrize("qn", [1, 64])
+    @pytest.mark.parametrize("hq, hkv, hb", [
+        (12, 12, 12), (12, 12, 6), (12, 12, 4), (12, 12, 3), (12, 12, 1),
+        (8, 2, 2)])
+    def test_heads_per_block_parity(self, interpret_pallas, monkeypatch,
+                                    hq, hkv, hb, qn):
+        """A grid step takes ``hb`` K/V heads of a page; the budget picks
+        ``hb``.  Every blocking gives the reference's answer, and the
+        same bits as one head a step: a zero-length slot, one ending
+        mid-page, one on a page boundary; one query row a slot (decode)
+        or a 64-row chunk with causal offsets."""
+        d, page = 32, 16
+        q, kp, vp, bt, lens = _paged_inputs(
+            10 + hb, b=4, hq=hq, hkv=hkv, d=d, page=page,
+            lens=(0, 37, 128, 100))
+        rng = np.random.RandomState(hb)
+        q = jnp.asarray(rng.randn(4, qn, hq, d).astype(np.float32))
+        offs = jnp.maximum(lens - qn, 0)
+        rows = -(-qn * (hq // hkv) // 8) * 8
+
+        def run(heads):
+            monkeypatch.setattr(PA, "_PAGED_VMEM_BUDGET", heads *
+                                PA._head_vmem_bytes(rows, page, d,
+                                                    jnp.float32, jnp.float32))
+            assert PA.heads_per_block(hkv, rows, page, d, jnp.float32,
+                                      jnp.float32) == heads
+            return np.asarray(PA._pallas_paged_attention(
+                q, kp, vp, bt, lens, q_offsets=offs))
+
+        out = run(hb)
+        ref = PA._xla_paged_attention(q, kp, vp, bt, lens, q_offsets=offs)
+        np.testing.assert_allclose(out, np.asarray(ref), atol=2e-5)
+        assert float(np.abs(out[0]).max()) == 0.0
+        np.testing.assert_array_equal(out, run(1))
+
+    def test_heads_per_block_rule(self, monkeypatch):
+        f32 = jnp.float32
+        # the serve cell: 12 heads of 64, pages of 64, decode (8 rows
+        # after padding) and mixed (64 rows) calls: one block of 12
+        assert PA.heads_per_block(12, 8, 64, 64, f32, f32) == 12
+        assert PA.heads_per_block(12, 64, 64, 64, f32, f32) == 12
+        # large-group GQA: 8 query heads a K/V head, 64 query tokens,
+        # heads of 128 — 512 rows a head, so fewer heads fit a step
+        hb = PA.heads_per_block(8, 512, 64, 128, f32, f32)
+        assert hb < 8 and 8 % hb == 0
+        # a budget no head fits still takes one head a step
+        monkeypatch.setattr(PA, "_PAGED_VMEM_BUDGET", 1)
+        assert PA.heads_per_block(12, 64, 64, 64, f32, f32) == 1
+
 
 class TestPageSizeMachinery:
     def test_pick_page_size_shrinks_to_tile(self):

@@ -238,6 +238,66 @@ def test_paged_kernel_at_the_smoke_pool(one_chip, kv_dtype, qn):
     assert _has_kernel(_compile(_paged, *args), "paged_attention")
 
 
+@pytest.fixture
+def paged_grids(monkeypatch):
+    """The grid of every Pallas call traced while the test runs (trace a
+    function of its own: jit reuses the trace of one it has seen)."""
+    from jax.experimental import pallas as pl
+
+    grids, orig = [], pl.pallas_call
+
+    def spy(*a, **k):
+        grids.append(tuple(k["grid_spec"].grid))
+        return orig(*a, **k)
+
+    monkeypatch.setattr(pl, "pallas_call", spy)
+    return grids
+
+
+@pytest.mark.parametrize("qn", [1, Q_MAX])
+def test_paged_kernel_takes_every_head_of_a_page(one_chip, paged_grids, qn):
+    """The serve cell's decode and mixed calls: 16 slots, 12 K/V heads of
+    64, 16 pages of 64 a slot, f32.  A grid step takes all 12 heads of a
+    page — 16 x 1 x 16 = 256 steps a call, not 16 x 12 x 16 — and the
+    kernel still takes one layer of the pool, [12, 256, 64, 64], which is
+    what the benchmark's `classify` finds it by."""
+    from benchmarks.kernels import paged_attention as bench_pa
+
+    f32 = jnp.float32
+    slots, num_pages = CELL["slots"], CELL["num_pages"]
+    args = _paged_args(one_chip, slots, num_pages, qn, f32, f32)
+    page = args[1].shape[2]
+    rows = -(-qn // 8) * 8
+    assert PA.heads_per_block(HEADS, rows, page, HEAD_DIM, f32, f32) == HEADS
+    text = _compile(lambda *a: _paged(*a), *args).as_text()
+    assert paged_grids == [(slots, 1, MAX_LEN // page)]
+    assert slots * MAX_LEN // page == 256
+    assert any(bench_pa.classify(line, HEADS, num_pages, HEAD_DIM)
+               for line in text.splitlines())
+
+
+def test_paged_kernel_blocks_fewer_heads_where_vmem_is_short(
+        one_chip, paged_grids):
+    """Large-group GQA: 8 K/V heads of 128, 8 query heads each, a 64-token
+    chunk — 512 query rows a head.  All 8 heads' blocks would pass the
+    VMEM budget, so a step takes fewer, and the call compiles."""
+    f32 = jnp.float32
+    slots, hkv, hq, d, qn, num_pages = 16, 8, 64, 128, Q_MAX, 256
+    page = PA.default_page_size(MAX_LEN, d, f32)
+
+    def S(shape, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    kv = S((hkv, num_pages, page, d), f32)
+    hb = PA.heads_per_block(hkv, qn * hq // hkv, page, d, f32, f32)
+    assert hb < hkv
+    compiled = _compile(lambda *a: _paged(*a), S((slots, qn, hq, d), f32),
+                        kv, kv,
+                        S((slots, MAX_LEN // page)), S((slots,)), S((slots,)))
+    assert paged_grids == [(slots, hkv // hb, MAX_LEN // page)]
+    assert _has_kernel(compiled, "paged_attention")
+
+
 @pytest.mark.parametrize("qn", [1, Q_MAX])
 @pytest.mark.parametrize("num_pages", [8192, 12288])
 def test_int8_kernel_at_the_pools_int8_exists_for(one_chip, num_pages, qn):
