@@ -59,11 +59,13 @@ import contextlib
 import json
 import queue
 import threading
+import time
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Dict, Optional
 from urllib.parse import parse_qs, urlparse
 
 from ..observability import fleettrace
+from ..observability import flight as _flight
 
 __all__ = ["EdgeServer"]
 
@@ -74,7 +76,12 @@ _REQUEST_KWARGS = ("eos_token_id", "priority", "deadline_ms",
 
 def _edge_span(name: str, tid: int = 0, **args):
     """RAII span on the ``edge`` track — a no-op context unless
-    FLAGS_fleet_trace, so the default edge records nothing."""
+    FLAGS_fleet_trace, so the default edge records nothing.  These
+    spans hold a whole request (its SSE stream, its resume, an
+    adoption's replay) and with it every wait for the device, so they
+    stay off the profiler's trace: there they would take the chip's
+    idle from the serve loop's own spans.  The relay's per-token work
+    is an ``edge.pump`` / ``edge.write`` span there instead."""
     if not fleettrace.enabled():
         return contextlib.nullcontext()
     from ..observability import tracing
@@ -85,8 +92,8 @@ def _edge_span(name: str, tid: int = 0, **args):
 
 class _Relay:
     """Thread-safe conveyor from the frontend's event loop to one SSE
-    handler thread: the async pump feeds ``("tok", value)`` /
-    ``("done", reason, total)`` items, the handler drains and frames
+    handler thread: the async pump feeds ``("tok", value, emitted_at)``
+    / ``("done", reason, total)`` items, the handler drains and frames
     them.  ``start_index`` is the absolute index of the first token
     the consumer will see (nonzero on resumed streams)."""
 
@@ -216,10 +223,13 @@ class EdgeServer:
             coro, self._aloop).result(timeout=timeout)
 
     async def _pump(self, stream, relay: _Relay):
-        """Event-loop side of one SSE stream: token queue -> relay."""
+        """Event-loop side of one SSE stream: token queue -> relay, each
+        hand-off an ``edge.pump`` span in the profiler's trace."""
         try:
             async for tok in stream:
-                relay.q.put(("tok", int(tok)))
+                with _flight.annotation("edge.pump",
+                                        request=relay.request_id):
+                    relay.q.put(("tok", int(tok), stream.emitted_at))
         finally:
             relay.q.put(("done", stream.finish_reason,
                          len(stream.request.generated_ids)))
@@ -282,7 +292,7 @@ class EdgeServer:
                 # queue then orders snapshot-known tokens ahead of
                 # live recompute by construction
                 for t in info["backfill"]:
-                    relay.q.put(("tok", int(t)))
+                    relay.q.put(("tok", int(t), None))
                 asyncio.run_coroutine_threadsafe(
                     self._pump(info["stream"], relay), self._aloop)
                 self._adopted[int(rid)] = relay
@@ -369,7 +379,16 @@ class _EdgeHandler(BaseHTTPRequestHandler):
             item = relay.q.get(
                 timeout=self.edge.stream_idle_timeout_s)
             if item[0] == "tok":
-                self._sse_event({"i": idx, "t": item[1]})
+                with _flight.annotation("edge.write",
+                                        request=relay.request_id):
+                    self._sse_event({"i": idx, "t": item[1]})
+                if item[2] is not None:
+                    # the token's relay, emission to flushed bytes (an
+                    # adopted stream's backfill carries no stamp)
+                    from ..inference.serving import _stats_add
+
+                    _stats_add(relay_s=time.perf_counter() - item[2],
+                               relayed_tokens=1)
                 idx += 1
             else:
                 self._sse_event({"done": True, "finish_reason": item[1],

@@ -356,13 +356,17 @@ class TokenStream:
         self._frontend = frontend
         self._queue: asyncio.Queue = asyncio.Queue()
         self._ended = False
+        # `perf_counter` at the engine's emission of the token the
+        # iterator returned last (None for tokens pushed without one):
+        # the edge times each token's relay from here
+        self.emitted_at: Optional[float] = None
 
     # -- producer side (driver / engine) ------------------------------------
-    def _push(self, item):
+    def _push(self, item, emitted_at: Optional[float] = None):
         # runs as an event-loop callback (call_soon / _threadsafe):
         # put_nowait on an unbounded queue never raises; boundedness is
         # enforced by the driver pausing between steps (_stream_space)
-        self._queue.put_nowait(item)
+        self._queue.put_nowait((item, emitted_at))
 
     # -- consumer side -------------------------------------------------------
     def __aiter__(self):
@@ -371,7 +375,7 @@ class TokenStream:
     async def __anext__(self) -> int:
         if self._ended:
             raise StopAsyncIteration
-        item = await self._queue.get()
+        item, self.emitted_at = await self._queue.get()
         self._frontend._notify_drained()
         if item is _DONE:
             self._ended = True
@@ -474,6 +478,7 @@ class ServingFrontend:
         # it); None once the driver has waited for work or for a
         # consumer, so `between_steps_s` holds only back-to-back steps
         self._t_stepped: Optional[float] = None
+        self._t_step_begin = 0.0  # when the last `_step` started
         # ops plane: a frontend-wrapped engine serves the stream-aware
         # debug_dump from /statusz instead of the bare engine statusz
         from ..observability import opsserver as _opsserver
@@ -691,7 +696,7 @@ class ServingFrontend:
                         # only mean shutdown mid-step: drop the token)
                         try:
                             _loop.call_soon_threadsafe(
-                                _box[0]._push, tok)
+                                _box[0]._push, tok, time.perf_counter())
                         except RuntimeError:
                             pass
                     req = self.engine.add_request(
@@ -714,7 +719,8 @@ class ServingFrontend:
                         def on_token(tok, _box=box, _loop=_loop):
                             try:
                                 _loop.call_soon_threadsafe(
-                                    _box[0]._push, tok)
+                                    _box[0]._push, tok,
+                                    time.perf_counter())
                             except RuntimeError:
                                 pass
                         return on_token
@@ -805,22 +811,38 @@ class ServingFrontend:
         and back, `_flush_finished`, `_apply_control` — is the
         frontend's time between steps (``between_steps_s``), unless the
         driver waited in between."""
+        self._t_step_begin = time.perf_counter()
         last = self._t_stepped
         if last is not None:
             from .serving import _stats_add
 
-            _stats_add(between_steps_s=time.perf_counter() - last)
+            _stats_add(between_steps_s=self._t_step_begin - last)
         try:
             return self.engine.step()
         finally:
             self._t_stepped = time.perf_counter()
 
+    def _note_hops(self, t_submit: float):
+        """The step's two executor hand-offs, loop -> worker thread and
+        back (``hop_s``, one of ``hops`` a round trip): from the loop's
+        submit of `_step` to its start on the worker, and from its
+        return there to the loop's resumption here.  Both stay dark in
+        the profiler's trace: no span may hold a wait for the device."""
+        from .serving import _stats_add
+
+        _stats_add(hop_s=(self._t_step_begin - t_submit)
+                   + (time.perf_counter() - self._t_stepped), hops=1)
+
     def _span(self, name: str):
         """The event loop's own work between two steps, as a
         ``frontend.<name>`` span in the profiler's trace; the executor
-        hop around it is what stays unattributed there."""
-        return _flight.annotation("frontend." + name,
-                                  engine=self.engine._engine_id)
+        hop around it is what stays unattributed there.  ``wait.<kind>``
+        names pass through whole: the driver's waits for work
+        (``wait.empty``) and for a slow consumer
+        (``wait.backpressure``), open across the await."""
+        return _flight.annotation(
+            name if name.startswith("wait.") else "frontend." + name,
+            engine=self.engine._engine_id)
 
     async def _drive(self):
         from .errors import StepFault
@@ -838,7 +860,8 @@ class ServingFrontend:
                     if self._control:
                         continue
                     self._t_stepped = None
-                    await self._wake.wait()
+                    with self._span("wait.empty"):
+                        await self._wake.wait()
                     continue
                 if not self._closing and not self._stream_space():
                     # a consumer is behind: pause BETWEEN steps until
@@ -849,7 +872,8 @@ class ServingFrontend:
                     self._drained.clear()
                     if not self._stream_space():
                         self._t_stepped = None
-                        await self._drained.wait()
+                        with self._span("wait.backpressure"):
+                            await self._drained.wait()
                     continue
                 # hung-step watchdog (FLAGS_step_timeout_ms): once the
                 # engine is warm, steps run under an abandon timeout —
@@ -871,6 +895,7 @@ class ServingFrontend:
                     if arm_abandon:
                         pre_sig = wd.sig()
                         loop = asyncio.get_running_loop()
+                        t_submit = time.perf_counter()
                         fut = loop.run_in_executor(None, self._step)
                         # the abandoned thread's late raise must not
                         # surface as "exception never retrieved"
@@ -884,6 +909,7 @@ class ServingFrontend:
                             # watchdog exists to bound
                             await asyncio.wait_for(asyncio.shield(fut),
                                                    wd.timeout_s)
+                            self._note_hops(t_submit)
                         except asyncio.TimeoutError:
                             from . import durability
                             from .errors import HungStep
@@ -908,8 +934,10 @@ class ServingFrontend:
                                     continue
                                 raise e
                     elif self._step_in_thread:
+                        t_submit = time.perf_counter()
                         await asyncio.get_running_loop() \
                             .run_in_executor(None, self._step)
+                        self._note_hops(t_submit)
                     else:
                         self._step()
                 except StepFault as e:

@@ -103,6 +103,18 @@ from ..observability import costmodel as _costmodel
 from ..observability import flight as _flight
 
 _STATS = {k: _decode_stat_zero(k) for k in DECODE_STAT_COUNTERS}
+# the collector's counters are the seam's process totals less this
+# baseline, moved on every reset (the gc hook itself takes no lock)
+_GC_BASE = _flight.gc_totals()
+
+
+def _gc_since_base(totals) -> dict:
+    n = totals["collections"]
+    base = _GC_BASE["collections"]
+    return {"gc_pause_s": totals["pause_s"] - _GC_BASE["pause_s"],
+            "gc_collections": sum(n) - sum(base),
+            "gc_gen2_collections": n[2] - base[2]}
+
 
 def _stats_add(**deltas):
     """Apply counter deltas atomically (one lock round per engine step,
@@ -125,6 +137,7 @@ def decode_stats(reset=False):
     adds after the snapshot are never lost to the reset."""
     with _TELEMETRY_LOCK:
         out = dict(_STATS)
+        out.update(_gc_since_base(_flight.gc_totals()))
         if reset:
             reset_decode_stats()
     steps = max(out["steps"], 1)
@@ -143,9 +156,11 @@ def decode_stats(reset=False):
 
 
 def reset_decode_stats():
+    global _GC_BASE
     with _TELEMETRY_LOCK:
         for k in _STATS:
             _STATS[k] = 0.0 if isinstance(_STATS[k], float) else 0
+        _GC_BASE = _flight.gc_totals()
 
 
 # Sampling lives in nn.decode (neutral layer — eager GPT.generate must
@@ -1750,15 +1765,16 @@ class DecodeEngine:
         _opsserver.register_engine(self)
         _opsserver.maybe_start_ops_server()
 
-    def _phase(self, name: str):
+    def _phase(self, name: str, **args):
         """Context manager timing a LEAF flight-recorder phase (device
         dispatch, fetch, cache ops) and opening its ``engine.<name>``
         span in the profiler's trace — the span alone when the
         recorder is off, so call sites read `with self._phase("x"):`
-        without repeating the None check."""
+        without repeating the None check.  ``args`` ride on the span
+        as stats."""
         fr = self._flight
-        return fr.phase(name) if fr is not None else \
-            _flight.engine_span(self, name)
+        return fr.phase(name, **args) if fr is not None else \
+            _flight.engine_span(self, name, **args)
 
     def _excl_phase(self, name: str):
         """Like `_phase` for COMPOSITE host phases (admit/draft/emit):
@@ -2974,70 +2990,81 @@ class DecodeEngine:
         host side.  ``decode_rows=False`` (the speculative path) feeds
         ONLY prompt chunks — decoding slots advance through the spec
         round that follows in the same engine step."""
-        slots, qmax = self._slots, self._q_max
-        # ragged mode widens the grid to Q_r >= Q_max so the ONE
-        # executable's token shape also fits verify windows (K+1);
-        # chunk spans stay capped by Q_max (the chunk-budget invariant)
-        width = self._q_ragged if self._ragged else qmax
-        tokens = np.zeros((slots, width), np.int32)
-        caps = np.zeros(slots, np.int32)
-        sample_idx = np.zeros(slots, np.int32)
-        sample_mask = np.zeros(slots, bool)
-        prefilling = [s for s in range(slots)
-                      if self._active[s] and self._is_prefilling(s)]
-        # fair-share chunking: the step's token budget splits evenly
-        # across prefilling slots (remainder to the lower slots), so a
-        # short prompt admitted next to a long one finishes its prefill
-        # in one step instead of queueing behind the long prompt's whole
-        # stream — bounded TTFT for everyone, not just slot 0
-        budget = self._chunk_budget
-        chunk_of = {}
-        for i, s in enumerate(prefilling):
-            req = self._by_slot[s]
-            cur = int(self._prefill_pos[s])
-            share = -(-budget // (len(prefilling) - i))  # ceil
-            c = min(len(req.prompt_ids) - cur, share, qmax)
-            if c == 0:
-                continue  # budget spent: the slot waits one step
-            budget -= c
-            tokens[s, :c] = req.prompt_ids[cur:cur + c]
-            caps[s] = c
-            chunk_of[s] = c
-            if cur + c == len(req.prompt_ids):
-                # last chunk: this step produces the first token
-                sample_idx[s] = c - 1
-                sample_mask[s] = True
-        if decode_rows:
-            for s in range(slots):
-                if self._active[s] and s not in chunk_of and \
-                        not self._is_prefilling(s):
-                    tokens[s, 0] = self._last[s]
-                    caps[s] = 1
-                    sample_idx[s] = 0
+        # batch assembly, from here to the dispatch span: rows and
+        # chunks, page growth, the step's key, fresh quant scales
+        with _flight.engine_span(self, "assemble"):
+            slots, qmax = self._slots, self._q_max
+            # ragged mode widens the grid to Q_r >= Q_max so the ONE
+            # executable's token shape also fits verify windows (K+1);
+            # chunk spans stay capped by Q_max (the chunk-budget
+            # invariant)
+            width = self._q_ragged if self._ragged else qmax
+            tokens = np.zeros((slots, width), np.int32)
+            caps = np.zeros(slots, np.int32)
+            sample_idx = np.zeros(slots, np.int32)
+            sample_mask = np.zeros(slots, bool)
+            prefilling = [s for s in range(slots)
+                          if self._active[s] and self._is_prefilling(s)]
+            # fair-share chunking: the step's token budget splits
+            # evenly across prefilling slots (remainder to the lower
+            # slots), so a short prompt admitted next to a long one
+            # finishes its prefill in one step instead of queueing
+            # behind the long prompt's whole stream — bounded TTFT for
+            # everyone, not just slot 0
+            budget = self._chunk_budget
+            chunk_of = {}
+            for i, s in enumerate(prefilling):
+                req = self._by_slot[s]
+                cur = int(self._prefill_pos[s])
+                share = -(-budget // (len(prefilling) - i))  # ceil
+                c = min(len(req.prompt_ids) - cur, share, qmax)
+                if c == 0:
+                    continue  # budget spent: the slot waits one step
+                budget -= c
+                tokens[s, :c] = req.prompt_ids[cur:cur + c]
+                caps[s] = c
+                chunk_of[s] = c
+                if cur + c == len(req.prompt_ids):
+                    # last chunk: this step produces the first token
+                    sample_idx[s] = c - 1
                     sample_mask[s] = True
-        self._grow_block_tables(writes=caps)
+            decoding = 0
+            if decode_rows:
+                for s in range(slots):
+                    if self._active[s] and s not in chunk_of and \
+                            not self._is_prefilling(s):
+                        tokens[s, 0] = self._last[s]
+                        caps[s] = 1
+                        sample_idx[s] = 0
+                        sample_mask[s] = True
+                        decoding += 1
+            self._grow_block_tables(writes=caps)
 
-        fn = self._ragged_fn_tracker() if self._ragged \
-            else self._mixed_fn_tracker()
-        if self._fault is not None:
-            # fault site BEFORE the invocation (and the step counter):
-            # an injected raise leaves no half-donated state, so the
-            # containment ladder's retry re-enters cleanly
-            self._resilience.step_fault_point("mixed_step")
-        self._step_no += 1
-        key = jax.random.fold_in(
-            self._key, _fold_counter(self._step_no, RNG_DECODE_DOMAIN))
-        fr = self._flight
-        # phase attribution: chunk-only mixed steps are prompt work
-        # ("prefill"), chunk-carrying full steps are fused ("mixed"),
-        # chunkless full steps are plain decode through the mixed
-        # executable ("decode")
-        phase_name = "prefill" if not decode_rows else \
-            ("mixed" if chunk_of else "decode")
-        self._flush_fresh_scales()
+            fn = self._ragged_fn_tracker() if self._ragged \
+                else self._mixed_fn_tracker()
+            if self._fault is not None:
+                # fault site BEFORE the invocation (and the step
+                # counter): an injected raise leaves no half-donated
+                # state, so the containment ladder's retry re-enters
+                # cleanly
+                self._resilience.step_fault_point("mixed_step")
+            self._step_no += 1
+            key = jax.random.fold_in(
+                self._key,
+                _fold_counter(self._step_no, RNG_DECODE_DOMAIN))
+            fr = self._flight
+            # phase attribution: chunk-only mixed steps are prompt
+            # work ("prefill"), chunk-carrying full steps are fused
+            # ("mixed"), chunkless full steps are plain decode through
+            # the mixed executable ("decode")
+            phase_name = "prefill" if not decode_rows else \
+                ("mixed" if chunk_of else "decode")
+            self._flush_fresh_scales()
         t0 = time.perf_counter()
         t0_ns = _obs.now_ns()
-        with self._phase(phase_name):
+        with self._phase(phase_name, decode_rows=decoding,
+                         chunk_rows=len(chunk_of),
+                         chunk_tokens=sum(chunk_of.values())):
             if self._ragged:
                 # the unified executable takes no sample_idx /
                 # sample_mask operands — every position draws a
@@ -3603,28 +3630,31 @@ class DecodeEngine:
             # the ONE ragged executable (decode rows carry span 1), so
             # steady-state serving never touches _gpt_decode_step
             return self._mixed_step()
-        self._grow_block_tables()
+        with _flight.engine_span(self, "assemble"):
+            self._grow_block_tables()
 
-        fn = self._decode_fn
-        if fn is None:
-            fn = self._decode_fn = _JitTracker(
-                functools.partial(_gpt_decode_step,
-                                  num_heads=self._num_heads,
-                                  head_dim=self._head_dim,
-                                  eps=self._eps, **self._sampling),
-                "decode_compiles", donate_argnums=(1,),
-                site="DecodeEngine decode step (_gpt_decode_step)")
+            fn = self._decode_fn
+            if fn is None:
+                fn = self._decode_fn = _JitTracker(
+                    functools.partial(_gpt_decode_step,
+                                      num_heads=self._num_heads,
+                                      head_dim=self._head_dim,
+                                      eps=self._eps, **self._sampling),
+                    "decode_compiles", donate_argnums=(1,),
+                    site="DecodeEngine decode step (_gpt_decode_step)")
 
-        if self._fault is not None:
-            self._resilience.step_fault_point("decode_step")
-        self._step_no += 1
-        key = jax.random.fold_in(
-            self._key, _fold_counter(self._step_no, RNG_DECODE_DOMAIN))
-        fr = self._flight
-        self._flush_fresh_scales()
+            if self._fault is not None:
+                self._resilience.step_fault_point("decode_step")
+            self._step_no += 1
+            key = jax.random.fold_in(
+                self._key,
+                _fold_counter(self._step_no, RNG_DECODE_DOMAIN))
+            fr = self._flight
+            self._flush_fresh_scales()
         t0 = time.perf_counter()
         t0_ns = _obs.now_ns()
-        with self._phase("decode"):
+        with self._phase("decode", decode_rows=int(self._active.sum()),
+                         chunk_rows=0, chunk_tokens=0):
             self._kv, toks = fn(
                 self._params, self._kv,
                 jnp.asarray(self._bt), jnp.asarray(self._lens),

@@ -77,6 +77,7 @@ from .serving import (RNG_DECODE_DOMAIN, _JitTracker,
                       _pack_refolds, _quantize_gpt_params,
                       _reset_kv_scales, _stats_add, sample_logits)
 from .. import observability as _obs
+from ..observability import flight as _flight
 from ..ops.pallas import paged_attention as pa
 
 __all__ = ["Drafter", "PromptLookupDrafter", "DraftModelDrafter",
@@ -648,27 +649,30 @@ class SpeculativeDecoder:
             # round.
             eng._mixed_step(decode_rows=False)
 
-        # verify window per slot, clamped to the request's remaining
-        # token budget: KV rows past position prompt+max_new-2 are never
-        # needed, and writing them would outrun the pool reservation.
-        # Slots still mid-prefill keep cap 0 and skip the round.
-        caps = np.zeros(slots, np.int32)
-        for s in range(slots):
-            if not eng._active[s] or eng._is_prefilling(s):
-                continue
-            req = eng._by_slot[s]
-            need = req.max_new_tokens - len(req.output_ids)
-            k_s = int(self.k_slot[s]) if self.adaptive else self.k
-            caps[s] = min(k_s + 1, need)
-        if not caps.any():
-            # every live slot is still prefilling: the chunk step above
-            # WAS this engine step — it owns the latency observation
-            _obs.STEP_SECONDS.observe(time.perf_counter() - t_round0)
-            return True
-        eng._grow_block_tables(writes=caps)
-        # quantized pools: freshly granted pages' scales zero BEFORE
-        # the draft catch-up / verify write into them
-        eng._flush_fresh_scales()
+        # batch assembly before the draft, and again before the verify
+        # dispatch (`DecodeEngine._mixed_step`'s ``engine.assemble``)
+        with _flight.engine_span(eng, "assemble"):
+            # verify window per slot, clamped to the request's remaining
+            # token budget: KV rows past position prompt+max_new-2 are never
+            # needed, and writing them would outrun the pool reservation.
+            # Slots still mid-prefill keep cap 0 and skip the round.
+            caps = np.zeros(slots, np.int32)
+            for s in range(slots):
+                if not eng._active[s] or eng._is_prefilling(s):
+                    continue
+                req = eng._by_slot[s]
+                need = req.max_new_tokens - len(req.output_ids)
+                k_s = int(self.k_slot[s]) if self.adaptive else self.k
+                caps[s] = min(k_s + 1, need)
+            if not caps.any():
+                # every live slot is still prefilling: the chunk step above
+                # WAS this engine step — it owns the latency observation
+                _obs.STEP_SECONDS.observe(time.perf_counter() - t_round0)
+                return True
+            eng._grow_block_tables(writes=caps)
+            # quantized pools: freshly granted pages' scales zero BEFORE
+            # the draft catch-up / verify write into them
+            eng._flush_fresh_scales()
         pos_before = eng._lens.copy()
 
         fr = eng._flight
@@ -698,42 +702,44 @@ class SpeculativeDecoder:
                          tid=eng._engine_id,
                          args={"drafter": self.drafter.name, "k": self.k})
 
-        if eng._ragged:
-            # FLAGS_ragged_step: the verify window is just a per-row
-            # span on the engine's ONE ragged executable — same
-            # program, same shapes as its decode/mixed dispatches, so
-            # a speculative engine still compiles exactly one step
-            # executable
-            fn = eng._ragged_fn_tracker()
-        else:
-            fn = self._verify_fn
-            if fn is None:
-                fn = self._verify_fn = _JitTracker(
-                    functools.partial(_gpt_spec_verify,
-                                      num_heads=eng._num_heads,
-                                      head_dim=eng._head_dim,
-                                      eps=eng._eps, **eng._sampling),
-                    "verify_compiles", donate_argnums=(1,),
-                    site="SpeculativeDecoder verify (_gpt_spec_verify)")
+        with _flight.engine_span(eng, "assemble"):
+            if eng._ragged:
+                # FLAGS_ragged_step: the verify window is just a per-row
+                # span on the engine's ONE ragged executable — same
+                # program, same shapes as its decode/mixed dispatches, so
+                # a speculative engine still compiles exactly one step
+                # executable
+                fn = eng._ragged_fn_tracker()
+            else:
+                fn = self._verify_fn
+                if fn is None:
+                    fn = self._verify_fn = _JitTracker(
+                        functools.partial(_gpt_spec_verify,
+                                          num_heads=eng._num_heads,
+                                          head_dim=eng._head_dim,
+                                          eps=eng._eps, **eng._sampling),
+                        "verify_compiles", donate_argnums=(1,),
+                        site="SpeculativeDecoder verify (_gpt_spec_verify)")
 
-        tokens = np.concatenate(
-            [eng._last[:, None].astype(np.int32), drafts], axis=1)
-        if eng._ragged and tokens.shape[1] < eng._q_ragged:
-            # pad the window out to the ragged grid's fixed Q_r (the
-            # chunked-prefill width may exceed K+1); padding columns
-            # sit past every cap and are never written or read
             tokens = np.concatenate(
-                [tokens, np.zeros((slots, eng._q_ragged -
-                                   tokens.shape[1]), np.int32)],
-                axis=1)
-        if eng._fault is not None:
-            eng._resilience.step_fault_point("verify")
-        eng._step_no += 1
-        key = jax.random.fold_in(
-            eng._key, _fold_counter(eng._step_no, RNG_DECODE_DOMAIN))
+                [eng._last[:, None].astype(np.int32), drafts], axis=1)
+            if eng._ragged and tokens.shape[1] < eng._q_ragged:
+                # pad the window out to the ragged grid's fixed Q_r (the
+                # chunked-prefill width may exceed K+1); padding columns
+                # sit past every cap and are never written or read
+                tokens = np.concatenate(
+                    [tokens, np.zeros((slots, eng._q_ragged -
+                                       tokens.shape[1]), np.int32)],
+                    axis=1)
+            if eng._fault is not None:
+                eng._resilience.step_fault_point("verify")
+            eng._step_no += 1
+            key = jax.random.fold_in(
+                eng._key, _fold_counter(eng._step_no, RNG_DECODE_DOMAIN))
         t0 = time.perf_counter()
         tv_ns = _obs.now_ns()
-        with eng._phase("verify"):
+        with eng._phase("verify", decode_rows=int((caps > 0).sum()),
+                        chunk_rows=0, chunk_tokens=0):
             eng._kv, targets = fn(
                 eng._params, eng._kv,
                 eng._dev(eng._bt), eng._dev(eng._lens),

@@ -57,8 +57,10 @@ tests/test_analysis.py.
 """
 from __future__ import annotations
 
+import gc
 import json
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -68,7 +70,7 @@ from .metrics import _state
 from ..analysis.sanitizer import TrackedLock as _TrackedLock
 
 __all__ = ["PHASES", "BURN_KINDS", "FlightRecorder", "annotation",
-           "engine_span"]
+           "engine_span", "gc_totals"]
 
 # step-phase attribution vocabulary (the paddle_step_phase_seconds
 # label set); see the module docstring for the disjointness contract
@@ -121,15 +123,60 @@ def annotation(name: str, **args):
     return _annotation_cls(name, **args)
 
 
-def engine_span(engine, name: str):
+def engine_span(engine, name: str, **args):
     """The ``engine.<name>`` span of ``engine``'s current step — THE
     seam between a flight-recorder phase and the profiler's trace:
     every phase timer below opens one beside its `perf_counter` pair,
     and `DecodeEngine._phase` hands it out alone when the recorder is
     off.  A step's spans share ``step=`` (stamped at the top of
-    `DecodeEngine.step`)."""
+    `DecodeEngine.step`); ``args`` add stats of their own (a dispatch
+    span's batch composition)."""
     return annotation("engine." + name, step=engine._span_step,
-                      engine=engine._engine_id)
+                      engine=engine._engine_id, **args)
+
+
+# Python's garbage collector, on whichever thread ran it: every
+# collection is a ``host.gc`` span (``generation=``) while a profile is
+# taken, and adds to process-wide totals the serve counters read
+# (``gc_pause_s`` / ``gc_collections`` / ``gc_gen2_collections``).  The
+# totals only grow, and are written only here: readers keep a baseline
+# instead of resetting them, so the hook takes no lock (a collection
+# can start while its thread holds any lock at all).
+_GC = {"pause_s": 0.0, "collections": [0, 0, 0]}
+_gc_open = []  # [t0, span] of the collection under way
+
+
+def _gc_hook(phase, info):
+    if phase == "start":
+        span = None
+        cls = _annotation_cls
+        if cls is None:
+            # never import from inside a collection: the class is
+            # there once anything has imported jax's profiler
+            cls = getattr(sys.modules.get("jax.profiler"),
+                          "TraceAnnotation", None)
+        if cls is not None:
+            span = cls("host.gc", generation=info["generation"])
+            span.__enter__()
+        _gc_open[:] = [time.perf_counter(), span]
+    elif _gc_open:
+        t0, span = _gc_open
+        del _gc_open[:]
+        _GC["pause_s"] += time.perf_counter() - t0
+        _GC["collections"][info["generation"]] += 1
+        if span is not None:
+            span.__exit__(None, None, None)
+
+
+def gc_totals() -> dict:
+    """Seconds inside collections and collections by generation, since
+    the process started (this module's import)."""
+    return {"pause_s": _GC["pause_s"],
+            "collections": list(_GC["collections"])}
+
+
+if _gc_hook not in gc.callbacks:
+    gc.callbacks.append(_gc_hook)
 
 
 class _Phase:
@@ -137,13 +184,13 @@ class _Phase:
     phase of the open record, and is an ``engine.<phase>`` span in a
     running profile."""
 
-    __slots__ = ("fr", "name", "_t0", "_span")
+    __slots__ = ("fr", "name", "args", "_t0", "_span")
 
-    def __init__(self, fr, name):
-        self.fr, self.name = fr, name
+    def __init__(self, fr, name, args):
+        self.fr, self.name, self.args = fr, name, args
 
     def __enter__(self):
-        self._span = engine_span(self.fr.engine, self.name)
+        self._span = engine_span(self.fr.engine, self.name, **self.args)
         self._span.__enter__()
         self._t0 = time.perf_counter()
         return self
@@ -271,8 +318,8 @@ class FlightRecorder:
             return
         cur["phases"][name] = cur["phases"].get(name, 0.0) + dt
 
-    def phase(self, name: str) -> _Phase:
-        return _Phase(self, name)
+    def phase(self, name: str, **args) -> _Phase:
+        return _Phase(self, name, args)
 
     def exclusive_phase(self, name: str) -> _ExclusivePhase:
         return _ExclusivePhase(self, name)

@@ -141,6 +141,17 @@ DECODE_STAT_COUNTERS = (
     # between_steps_s`` is the wall a busy engine spent, counted once)
     "queue_wait_s", "admissions", "first_token_wait_s", "first_tokens",
     "mixed_time_s", "host_in_step_s", "between_steps_s",
+    # where the serve loop's host time goes between the chip's steps
+    # (read by `benchmarks/trace_split.py` beside the ``engine.*`` /
+    # ``wait.*`` / ``edge.*`` / ``host.gc`` profiler spans): a token's
+    # wall from its emission in the engine to the flush of its SSE
+    # bytes at the edge, summed over the tokens relayed; the frontend's
+    # two executor hand-offs a step (loop -> worker thread -> loop),
+    # one hop per round trip; and Python's garbage collector, any
+    # thread (wall inside collections, collections, and the full
+    # generation-2 ones among them)
+    "relay_s", "relayed_tokens", "hop_s", "hops",
+    "gc_pause_s", "gc_collections", "gc_gen2_collections",
 )
 DECODE_STAT_DERIVED = ("avg_step_ms", "batch_occupancy",
                        "kv_block_utilization",

@@ -1,9 +1,9 @@
 """Profiling plane (observability.profiling): sampled device-sync
 probes, hot-op attribution, bounded capture sessions, the /profilez +
 /tracez ops endpoints, and the dropped-span counter.  The disarmed
-path (profile=0, the default) is pinned bit-exact with zero probes;
-ratio GATES (overhead, attribution, drift) live in
-tools/bench_profiling.py where the step sizes make them meaningful.
+path (profile=0, the default) is pinned bit-exact with zero probes.
+Ratios (overhead, attribution, drift) are asserted for sanity only:
+CPU steps are too small for a gate on them.
 """
 import json
 import urllib.request
@@ -142,8 +142,7 @@ class TestProbes:
         assert obs.PHASE_MFU_MEASURED.value(phase="decode") > 0
         drift = obs.MFU_DRIFT.value(phase="decode")
         # sub-ms CPU dispatches are timer-noise dominated, so only
-        # sanity is asserted here; the near-zero steady state is the
-        # bench's full-scale gate (tools/bench_profiling.py)
+        # sanity is asserted here
         assert drift >= 0.0 and np.isfinite(drift)
 
     def test_statusz_section(self, served):

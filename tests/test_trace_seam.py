@@ -1,26 +1,39 @@
-"""The serve loop's phases as spans on the profiler's clock (ISSUE 26).
+"""The serve loop's phases as spans on the profiler's clock.
 
 One seam: a flight-recorder phase is, at the same call site, an
 ``engine.<phase>`` `jax.profiler.TraceAnnotation`; `_host_fetch` is
-``engine.fetch``; what `DecodeEngine.step` does after the batch is
+``engine.fetch``; batch assembly before a dispatch is
+``engine.assemble``; what `DecodeEngine.step` does after the batch is
 ``engine.step_tail``; the frontend's own work between two steps is
-``frontend.control`` / ``frontend.flush``.  Pinned here, on the CPU:
+``frontend.control`` / ``frontend.flush``, its waits for work and for a
+slow consumer ``wait.empty`` / ``wait.backpressure``; the edge's
+hand-off of a token is ``edge.pump`` (event loop) and ``edge.write``
+(SSE handler); a garbage collection is ``host.gc``.  Pinned here, on the
+CPU:
 
 * a profiled tiny engine behind `ServingFrontend` yields every span at
   least once, read back with `benchmarks.trace_reduce.read_xplane`;
   names stay clean (``step=`` / ``engine=`` ride as stats);
-* on one thread spans nest only under admit / draft / emit, a step's
-  spans share ``step=``, and steps do not interleave;
+* on one thread spans nest only under admit / assemble / draft /
+  emit, a step's spans share ``step=``, and steps do not interleave;
+  dispatch spans carry the batch's rows; no new span holds a fetch;
+* annotations that overlap without nesting keep their true ends;
 * the spans are there with ``flight_window=0`` too, and serving is
   bit-equal with and without a profile running;
 * the seven counters the benchmark's serve metrics read
   (`profiler.DECODE_STAT_COUNTERS`): zero before traffic, one admission
   and one first token a request, mixed steps inside the blended sums,
   the time sums count each second once, nothing booked across a wait,
-  cleared by ``reset=True``.
+  cleared by ``reset=True``;
+* the relay, hop and collector counters: zero before traffic, one
+  relayed token per SSE token, one hop per step's round trip to the
+  executor, one collection per collection.
 """
 import asyncio
+import gc
 import glob
+import http.client
+import json
 import os
 import sys
 import time
@@ -31,7 +44,9 @@ import pytest
 import paddle_tpu as paddle
 from paddle_tpu import observability as obs
 from paddle_tpu import profiler
+from paddle_tpu.fleet import EdgeServer
 from paddle_tpu.inference.frontend import ServingFrontend
+from paddle_tpu.observability import flight
 from paddle_tpu.inference.serving import (DecodeEngine, decode_stats,
                                           reset_decode_stats)
 from paddle_tpu.models.gpt import GPT, GPTConfig
@@ -40,6 +55,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 from benchmarks import trace_reduce  # noqa: E402
+from benchmarks import trace_split  # noqa: E402
 
 TINY = GPTConfig(vocab_size=64, hidden_size=32, num_layers=2,
                  num_heads=4, max_seq_len=256,
@@ -50,13 +66,20 @@ NEW = 6
 SPANS = ("engine.admit", "engine.cache", "engine.decode", "engine.mixed",
          "engine.prefill", "engine.verify", "engine.draft", "engine.fetch",
          "engine.emit", "engine.step_tail", "frontend.control",
-         "frontend.flush")
+         "frontend.flush", "engine.assemble", "wait.empty")
 DISPATCH = ("engine.decode", "engine.mixed", "engine.prefill",
             "engine.verify")
-HOLDERS = ("engine.admit", "engine.draft", "engine.emit")
+HOLDERS = ("engine.admit", "engine.assemble", "engine.draft",
+           "engine.emit")
+# the spans that name the serve loop's idle stretches, none of which may
+# hold a wait for the device
+IDLE_NAMERS = ("engine.assemble", "wait.empty", "wait.backpressure",
+               "edge.pump", "edge.write", "host.gc")
 NEW_COUNTERS = ("queue_wait_s", "admissions", "first_token_wait_s",
                 "first_tokens", "mixed_time_s", "host_in_step_s",
                 "between_steps_s")
+RELAY_COUNTERS = ("relay_s", "relayed_tokens", "hop_s", "hops",
+                  "gc_pause_s", "gc_collections", "gc_gen2_collections")
 
 
 @pytest.fixture(autouse=True)
@@ -124,22 +147,13 @@ def _profiled(fn, d):
     return out, path
 
 
-def _program_events(path):
+def _program_events(path, prefixes=("engine.", "frontend.", "wait.")):
     """[(thread, name, start_ns, end_ns, stats)] of the program's own
-    spans in a trace file."""
-    from jax.profiler import ProfileData
-
-    out = []
-    for plane in ProfileData.from_file(path).planes:
-        if not plane.name.startswith("/host:CPU"):
-            continue
-        for line in plane.lines:
-            for ev in line.events:
-                if ev.name.startswith(("engine.", "frontend.")):
-                    out.append((line.name, ev.name, ev.start_ns,
-                                ev.start_ns + ev.duration_ns,
-                                dict(ev.stats)))
-    return out
+    spans in a trace file; ``thread`` numbers the host lines, as
+    `trace_split.read_xplane_detail` does."""
+    _, host = trace_split.read_xplane_detail(path)
+    return [(t, n, round(s * 1e9), round((s + d) * 1e9), st)
+            for n, s, d, t, st in host if n.startswith(prefixes)]
 
 
 @pytest.fixture(scope="module")
@@ -169,7 +183,8 @@ def test_profile_holds_every_span_of_the_seam(traced, name):
 
 def test_span_names_stay_clean_and_carry_step_and_engine(traced):
     for _, name, _, _, stats in traced["events"]:
-        assert name in SPANS, name  # no `#step=..#` decoration
+        # no `#step=..#` decoration
+        assert name in SPANS + ("wait.backpressure",), name
         assert stats["engine"] in traced["engines"]
         if name.startswith("engine."):
             assert stats["step"] >= 1
@@ -240,6 +255,233 @@ def test_outputs_are_bit_equal_with_and_without_a_profile(model, traced):
     spec = _serve(_engine(model, spec_decode_k=2))
     assert (plain, spec) == traced["outs"]
     assert [len(o) for o in plain] == [NEW] * len(PROMPTS)
+
+
+def test_dispatch_spans_carry_the_batch_composition(traced):
+    seen_chunk = False
+    for _, name, _, _, stats in traced["events"]:
+        if name in ("engine.decode", "engine.mixed", "engine.prefill"):
+            rows = (stats["decode_rows"], stats["chunk_rows"],
+                    stats["chunk_tokens"])
+            assert min(rows) >= 0 and sum(rows[:2]) >= 1, (name, stats)
+            assert stats["decode_rows"] + stats["chunk_rows"] <= 2
+            # the chunk budget caps a step's prompt tokens
+            assert stats["chunk_tokens"] <= 4
+            assert (stats["chunk_rows"] > 0) == (stats["chunk_tokens"] > 0)
+            if name == "engine.mixed":
+                assert stats["chunk_rows"] >= 1
+            if name == "engine.decode":
+                assert stats["chunk_rows"] == 0
+            seen_chunk |= stats["chunk_rows"] > 0
+    assert seen_chunk
+
+
+def test_assembly_ends_before_its_steps_dispatch(traced):
+    """Each dispatch follows an ``engine.assemble`` of its step (a
+    speculative round assembles before its draft, and again before its
+    verify dispatch)."""
+    by_step = {}
+    for _, name, s, e, st in traced["events"]:
+        if name in ("engine.assemble", "engine.draft") or name in DISPATCH:
+            by_step.setdefault((st["engine"], st["step"]), []).append(
+                (s, e, name))
+    assert by_step
+    for spans in by_step.values():
+        spans.sort()
+        names = [n for _, _, n in spans]
+        for k, (s, _, n) in enumerate(spans):
+            if n in DISPATCH:
+                assert k and spans[k - 1][2] == "engine.assemble", names
+                assert spans[k - 1][1] <= s
+
+
+def _holds_a_fetch(events, same_thread):
+    fetches = [(t, s, e) for t, n, s, e, _ in events if n == "engine.fetch"]
+    held = []
+    for t, n, s, e, _ in events:
+        if n not in IDLE_NAMERS:
+            continue
+        for t2, s2, e2 in fetches:
+            if (t2 == t or not same_thread) and s <= s2 and e2 <= e:
+                held.append((n, s, e))
+    return held
+
+
+def test_no_new_span_holds_a_fetch(traced):
+    events = _program_events(traced["path"],
+                             prefixes=("engine.", "wait.", "host."))
+    assert {n for _, n, _, _, _ in events} >= {"engine.assemble",
+                                               "wait.empty"}
+    assert not _holds_a_fetch(events, same_thread=True)
+    # the driver's waits sit on the event loop, and no step runs
+    # while it waits
+    waits = [e for e in events if e[1].startswith("wait.")]
+    assert not _holds_a_fetch(
+        waits + [e for e in events if e[1] == "engine.fetch"],
+        same_thread=False)
+
+
+def test_overlapping_annotations_keep_their_true_ends(tmp_path):
+    """A ``wait.*`` span is open across an await; the pump's spans open
+    and close on the same thread meanwhile, and one may outlive it.
+    The profile keeps each annotation's own start and end."""
+    # each span's wall from stamps inside its bracket (a floor) and
+    # outside it (a ceiling): a thread preempted there moves no bound
+    inner, outer = {}, {}
+
+    async def waiter(ev):
+        o0 = time.perf_counter()
+        with flight.annotation("wait.empty", engine=1):
+            i0 = time.perf_counter()
+            await ev.wait()
+            inner["wait.empty"] = time.perf_counter() - i0
+        outer["wait.empty"] = time.perf_counter() - o0
+
+    async def pumps(ev):
+        for k in range(3):
+            o0 = time.perf_counter()
+            with flight.annotation("edge.pump", request=k):
+                i0 = time.perf_counter()
+                time.sleep(0.002)
+                inner[f"pump{k}"] = time.perf_counter() - i0
+            outer[f"pump{k}"] = time.perf_counter() - o0
+            await asyncio.sleep(0.002)
+        # opened inside the wait, closed after it: not nested
+        span = flight.annotation("edge.write", request=9)
+        o0 = time.perf_counter()
+        span.__enter__()
+        i0 = time.perf_counter()
+        ev.set()
+        await asyncio.sleep(0.004)
+        inner["write"] = time.perf_counter() - i0
+        span.__exit__(None, None, None)
+        outer["write"] = time.perf_counter() - o0
+
+    async def go():
+        ev = asyncio.Event()
+        await asyncio.gather(waiter(ev), pumps(ev))
+
+    _, path = _profiled(lambda: asyncio.run(go()), tmp_path)
+    events = _program_events(path, prefixes=("wait.", "edge."))
+    got = {}
+    for t, n, s, e, st in events:
+        key = n if n == "wait.empty" else (
+            "write" if n == "edge.write" else f"pump{st['request']}")
+        got[key] = (t, (e - s) * 1e-9)
+    assert set(got) == set(inner)
+    assert len({t for t, _ in got.values()}) == 1  # one thread
+    for key, (_, wall) in got.items():
+        assert inner[key] - 50e-6 <= wall <= outer[key] + 50e-6, key
+    (_, _, ws, we, _), = [e for e in events if e[1] == "wait.empty"]
+    (_, _, xs, xe, _), = [e for e in events if e[1] == "edge.write"]
+    assert ws < xs < we < xe
+
+
+def test_a_slow_consumer_is_a_backpressure_wait(model, tmp_path):
+    """With a one-token stream buffer and a consumer that sleeps between
+    reads, the driver pauses between steps: ``wait.backpressure`` spans
+    on the loop's thread, with ``engine=``, holding no fetch."""
+    eng = _engine(model)
+    eng.generate([PROMPTS[0][:9]], max_new_tokens=3)
+
+    async def go():
+        async with ServingFrontend(eng, stream_buffer=1) as fe:
+            s = await fe.submit(PROMPTS[0], max_new_tokens=NEW)
+            out = []
+            async for t in s:
+                out.append(t)
+                await asyncio.sleep(0.05)
+            return out
+
+    out, path = _profiled(lambda: asyncio.run(go()), tmp_path)
+    assert len(out) == NEW
+    events = _program_events(path)
+    waits = [e for e in events if e[1] == "wait.backpressure"]
+    assert waits and all(e[4]["engine"] == eng._engine_id for e in waits)
+    # a pause lasts what a step leaves of the consumer's 50 ms sleep
+    assert max(e[3] - e[2] for e in waits) > 20e6
+    assert not _holds_a_fetch(events, same_thread=False)
+
+
+# ---------------------------------------------------------------------------
+# the edge
+# ---------------------------------------------------------------------------
+def _sse(port, prompt, new):
+    """One `POST /v1/generate`: (request id, tokens)."""
+    conn = http.client.HTTPConnection("127.0.0.1", port, timeout=120)
+    try:
+        conn.request("POST", "/v1/generate", body=json.dumps(
+            {"prompt_ids": prompt, "max_new_tokens": new}),
+            headers={"Content-Type": "application/json"})
+        resp = conn.getresponse()
+        assert resp.status == 200
+        rid, toks = None, []
+        for line in resp:
+            if line.startswith(b"data: "):
+                ev = json.loads(line[6:])
+                if "request_id" in ev:
+                    rid = ev["request_id"]
+                elif "t" in ev:
+                    toks.append(ev["t"])
+        return rid, toks
+    finally:
+        conn.close()
+
+
+def _serve_edge(eng, prompts=PROMPTS, new=NEW):
+    edge = EdgeServer(eng)
+    port = edge.start()
+    try:
+        return [_sse(port, p, new) for p in prompts]
+    finally:
+        edge.close()
+
+
+@pytest.fixture(scope="module")
+def edge_traced(model, tmp_path_factory):
+    """One profile over a chunked engine behind `EdgeServer`, warmed up
+    before; the counters of the served window beside it."""
+    eng = _engine(model)
+    eng.generate([PROMPTS[0][:9]], max_new_tokens=3)
+    reset_decode_stats()
+    served, path = _profiled(lambda: _serve_edge(eng),
+                             tmp_path_factory.mktemp("edge_seam"))
+    return {"served": served, "path": path, "counters": decode_stats(),
+            "events": _program_events(path, prefixes=("edge.",))}
+
+
+@pytest.mark.parametrize("name", ["edge.pump", "edge.write"])
+def test_edge_spans_one_a_token_named_by_request(edge_traced, name):
+    mine = [e for e in edge_traced["events"] if e[1] == name]
+    for rid, toks in edge_traced["served"]:
+        assert len(toks) == NEW
+        assert sum(e[4]["request"] == rid for e in mine) == len(toks)
+    assert len(mine) == NEW * len(PROMPTS)
+    assert all(e[3] > e[2] for e in mine)
+    # the pump runs on the event loop, each write on its handler's
+    # thread (a later handler may reuse an earlier one's)
+    pumps = {e[0] for e in edge_traced["events"] if e[1] == "edge.pump"}
+    writes = {e[0] for e in edge_traced["events"] if e[1] == "edge.write"}
+    assert len(pumps) == 1 and not pumps & writes
+
+
+def test_edge_spans_hold_no_fetch(edge_traced):
+    events = _program_events(edge_traced["path"],
+                             prefixes=("engine.", "edge.", "wait."))
+    assert not _holds_a_fetch(events, same_thread=True)
+
+
+def test_relay_counts_each_token_once(edge_traced):
+    c = edge_traced["counters"]
+    assert c["relayed_tokens"] == NEW * len(PROMPTS)
+    assert 0 < c["relay_s"] < 60.0
+    assert c["hops"] >= c["steps"] > 0 and c["hop_s"] > 0
+
+
+def test_edge_outputs_are_bit_equal_with_and_without_a_profile(
+        model, edge_traced):
+    plain = _serve_edge(_engine(model))
+    assert [t for _, t in plain] == [t for _, t in edge_traced["served"]]
 
 
 # ---------------------------------------------------------------------------
@@ -351,3 +593,90 @@ def test_train_step_call_splits_into_three_spans(tmp_path):
         ["operands", "enqueue", "rebind"] * 2
     for (_, e, _), (s, _, _) in zip(spans, spans[1:]):
         assert e <= s  # one after the other, none holds another
+
+
+# ---------------------------------------------------------------------------
+# the relay, the hops and the collector
+# ---------------------------------------------------------------------------
+@pytest.fixture
+def no_automatic_gc():
+    """Collections only where a test asks for them."""
+    gc.disable()
+    try:
+        yield
+    finally:
+        gc.enable()
+
+
+def test_relay_hop_and_gc_counters_are_in_the_schema_and_zero_before(
+        model, no_automatic_gc):
+    assert set(RELAY_COUNTERS) <= set(profiler.DECODE_STAT_COUNTERS)
+    _engine(model)
+    reset_decode_stats()
+    st = profiler.decode_stats()
+    assert [st[k] for k in RELAY_COUNTERS] == [0] * len(RELAY_COUNTERS)
+    assert not [k for k in RELAY_COUNTERS if "retrace" in k]
+
+
+def test_one_hop_a_steps_round_trip_to_the_executor(model):
+    eng = _engine(model)
+    eng.generate([PROMPTS[0][:9]], max_new_tokens=3)
+    calls = []
+    real = eng.step
+
+    def counted():
+        calls.append(1)
+        return real()
+    eng.step = counted
+    reset_decode_stats()
+    _serve(eng)
+    st = decode_stats()
+    assert st["hops"] == len(calls) > 0
+    assert 0 < st["hop_s"] < st["hops"] * 1.0
+    assert st["relayed_tokens"] == 0  # no edge, no relay
+
+
+def test_steps_on_the_loop_take_no_hop(model):
+    eng = _engine(model)
+
+    async def go():
+        async with ServingFrontend(eng, step_in_thread=False) as fe:
+            s = await fe.submit(PROMPTS[0], max_new_tokens=3)
+            return [t async for t in s]
+
+    assert len(asyncio.run(go())) == 3
+    st = decode_stats()
+    assert st["steps"] > 0 and st["hops"] == 0 and st["hop_s"] == 0
+
+
+def test_one_collection_counts_once_by_generation(no_automatic_gc):
+    reset_decode_stats()
+    gc.collect()
+    st = decode_stats()
+    assert st["gc_collections"] == 1 and st["gc_gen2_collections"] == 1
+    assert st["gc_pause_s"] > 0
+    gc.collect(0)
+    gc.collect(1)
+    st = decode_stats(reset=True)
+    assert st["gc_collections"] == 3 and st["gc_gen2_collections"] == 1
+    st = decode_stats()
+    assert st["gc_collections"] == 0 and st["gc_pause_s"] == 0
+
+
+def test_a_collection_is_a_span_on_the_thread_that_ran_it(tmp_path):
+    def collect():
+        with flight.annotation("engine.admit", step=1, engine=1):
+            gc.collect(1)
+        t = __import__("threading").Thread(target=gc.collect)
+        t.start()
+        t.join()
+
+    _, path = _profiled(collect, tmp_path)
+    events = _program_events(path, prefixes=("host.", "engine."))
+    gcs = [e for e in events if e[1] == "host.gc"]
+    assert {e[4]["generation"] for e in gcs} >= {1, 2}
+    (admit,) = [e for e in events if e[1] == "engine.admit"]
+    inside = [e for e in gcs if e[4]["generation"] == 1
+              and admit[2] <= e[2] and e[3] <= admit[3]]
+    assert inside and inside[0][0] == admit[0]
+    assert any(e[4]["generation"] == 2 and e[0] != admit[0] for e in gcs)

@@ -25,7 +25,7 @@ Three legs (the ISSUE-13 acceptance bar):
   bit-exact with zero new executables and 0 warm retraces, and the
   per-step wall overhead <= ``--overhead-bound`` (2% by default; full
   scale only), on the smaller of the interleaved differential and the
-  direct per-entry-point accounting — the bench_flight methodology.
+  direct per-entry-point accounting.
 
 Emits BENCH_cost.json.
 
@@ -286,7 +286,7 @@ def main():
     ap.add_argument("--page-size", type=int, default=16)
     ap.add_argument("--reps", type=int, default=4)
     # overhead-leg shapes: decode-dominated, production-like steps
-    # (ctx-512, the bench_decode/bench_flight scale the fixed
+    # (ctx-512, the bench_decode scale the fixed
     # host-microsecond accounting cost is judged against)
     ap.add_argument("--oh-prompt", type=int, default=512)
     ap.add_argument("--oh-new", type=int, default=32)
