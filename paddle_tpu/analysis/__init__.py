@@ -162,9 +162,13 @@ REPO_ENGINE_RULE = EngineRule(
         "_admit", "_admit_one", "_finish", "_emit", "_bind_slot",
         "_prefill_into", "_cancel_queued", "_cancel_running",
         "_retire_queued", "_grow_block_tables", "_mixed_step",
-        "_stamp_admit", "_stamp_first_token", "_on_first_token",
+        "_stamp_admit", "_stamp_first_token", "_land_row",
         "_register_prompt_pages", "_register_generated_pages",
         "_debug_check_pool",
+        # the one-step lookahead: a step dispatched, landed, dropped,
+        # or read back early; a slot released ahead of its last token
+        "_dispatch", "_retire", "_drain", "drain", "_drop",
+        "_settle_inflight", "_release_finishing_slots", "_requeue",
         # fault containment / recovery (inference.resilience): the
         # ladder's retry unit, slot quarantine, and admission unwind
         # mutate the engine — callable only from sanctioned sites

@@ -765,8 +765,9 @@ class ServingFrontend:
                    for s in self._streams.values())
 
     def _has_work(self) -> bool:
-        eng = self.engine
-        return bool(eng._queue) or bool(eng._active.any())
+        # a step in flight is work: the engine lands it before the
+        # driver waits in ``wait.empty``
+        return self.engine._busy()
 
     def _recover_engine(self, fault, snapshot=None) -> bool:
         """Supervision: a step fault survived the engine's whole

@@ -596,7 +596,9 @@ class EngineSnapshot:
 
     def __init__(self, engine):
         self.engine_id = engine._engine_id
-        self.step_no = int(engine._step_no)
+        # the last step landed: one still in flight is dispatched again
+        self.step_no = int(engine._step_no) - \
+            (engine._inflight is not None)
         self.prefill_no = int(engine._prefill_no)
         running = sorted(
             (r for r in engine._by_slot if r is not None),
@@ -766,7 +768,7 @@ def serve_with_recovery(engine, max_recoveries: Optional[int] = None,
         if max_recoveries is None else int(max_recoveries)
     recoveries = 0
     steps = 0
-    while engine._queue or engine._active.any():
+    while engine._busy():
         if steps >= max_steps:
             raise RuntimeError(
                 f"serve_with_recovery(max_steps={max_steps}) exhausted "

@@ -152,6 +152,8 @@ def decode_stats(reset=False):
         out["spec_proposed"], 1)
     out["mean_accepted_per_step"] = out["spec_emitted"] / max(
         out["spec_slot_steps"], 1)
+    # share of the steps dispatched while the one before was in flight
+    out["lookahead_share"] = out["lookahead_steps"] / steps
     return out
 
 
@@ -987,9 +989,25 @@ def _gpt_prefill(params, ids, true_len, bt_row, kv, key, *,
     return kv, _pack_refolds(kv, token, refolds)
 
 
+def _prev_tokens(tokens, prev, from_prev):
+    """The incoming token of each slot (``tokens`` [B], or row 0 of
+    [B, Q]): the host's, or where ``from_prev`` is set the token the step
+    before sampled for the slot, read from that step's output ``prev``
+    as it lies on the device (its first ``B`` elements: a quantized
+    pool's refold count rides behind them).  ``prev=None`` (the
+    drafter's steps) leaves the text as it was."""
+    if prev is None:
+        return tokens
+    b = tokens.shape[0]
+    if tokens.ndim == 1:
+        return jnp.where(from_prev, prev[:b], tokens)
+    return tokens.at[:, 0].set(
+        jnp.where(from_prev, prev[:b], tokens[:, 0]))
+
+
 def _gpt_decode_step(params, kv, block_tables, seq_lens, tokens, active,
-                     key, *, num_heads, head_dim, eps, sampler,
-                     temperature, top_k, top_p):
+                     key, prev=None, from_prev=None, *, num_heads,
+                     head_dim, eps, sampler, temperature, top_k, top_p):
     """One batched decode step over every slot: write the incoming
     token's K/V into its page, ragged paged attention over the pool,
     sample the next token.  The pool (`pa.KVPool`) is donated; a float
@@ -997,10 +1015,14 @@ def _gpt_decode_step(params, kv, block_tables, seq_lens, tokens, active,
     lies by one `paged_kv_write` kernel a layer: on the chip nothing
     pool-sized moves but the per-layer slice the attention kernel takes
     (tests/test_tpu_compile.py).  Inactive slots write nothing (cap 0)
-    and read length 0.  Returns ``(kv, next tokens [B])``."""
+    and read length 0.  A slot with ``from_prev`` set takes its
+    incoming token from ``prev``, the output of the step dispatched
+    before this one (`_prev_tokens`).  Returns ``(kv, next tokens
+    [B])``."""
     b = tokens.shape[0]
     h = num_heads * head_dim
 
+    tokens = _prev_tokens(tokens, prev, from_prev)
     pos = seq_lens  # the incoming token's position
     x = params["wte"][tokens] + params["wpe"][pos]  # [B, h]
     caps = active.astype(jnp.int32)  # one row a live slot, none otherwise
@@ -1070,9 +1092,9 @@ def _gpt_layer_bq(blk, li, x, kv, block_tables, seq_lens, write_caps,
 
 
 def _gpt_mixed_step(params, kv, block_tables, seq_lens, tokens,
-                    write_caps, sample_idx, sample_mask, key, *,
-                    num_heads, head_dim, eps, sampler, temperature,
-                    top_k, top_p):
+                    write_caps, sample_idx, sample_mask, key, prev=None,
+                    from_prev=None, *, num_heads, head_dim, eps, sampler,
+                    temperature, top_k, top_p):
     """ONE mixed prefill+decode step over every slot: prefilling slots
     contribute a prompt chunk (rows 0..cap-1 of their ``tokens`` row),
     decoding slots contribute their last sampled token (cap 1), stalled
@@ -1089,13 +1111,15 @@ def _gpt_mixed_step(params, kv, block_tables, seq_lens, tokens,
 
     tokens: [B, Q_max] int32; write_caps/sample_idx: [B] int32;
     sample_mask: [B] bool; kv donated (the update is in
-    place, on the chip too: see `_gpt_decode_step`).
+    place, on the chip too: see `_gpt_decode_step`); ``from_prev`` [B]
+    bool takes row 0 of a slot from ``prev`` (`_prev_tokens`).
     Returns (kv, sampled [B] int32).
 
     The shapes are fixed per engine, so this compiles ONCE — the pow-2
     bucket zoo of legacy prefill executables collapses into this single
     program, and the `_JitTracker` retrace contract covers it.
     """
+    tokens = _prev_tokens(tokens, prev, from_prev)
     b, qn = tokens.shape
 
     offs = jnp.arange(qn, dtype=jnp.int32)
@@ -1207,6 +1231,34 @@ def _reset_kv_scales(k_scales, v_scales, fresh_idx):
     k_scales = k_scales.at[:, :, fresh_idx].set(0.0)
     v_scales = v_scales.at[:, :, fresh_idx].set(0.0)
     return k_scales, v_scales
+
+
+class _Step:
+    """One dispatched step executable, from its dispatch until the host
+    has landed its tokens (`DecodeEngine._retire`).  It keeps what it
+    was dispatched with — for each slot the request of its row
+    (``reqs``; None: no row) and that request's preemption count at the
+    time (a re-admitted request is another binding), the row's write
+    (``caps``: a chunk's length, 1 for a decode row), the chunks, which
+    rows sample, and the KV lengths it started from — so the host can
+    predict the next step from it and land it after the slots have moved
+    on.  ``final`` are the slots whose row samples its request's last
+    token by length: released before the next admission, their request
+    lands here without a slot.  ``release`` holds the page lists of
+    requests that left while this step wrote into them; they go back to
+    the pool once its tokens are read (``host``)."""
+
+    __slots__ = ("step_no", "exe", "decode_rows", "ahead", "toks", "host",
+                 "reqs", "leases", "caps", "chunk_of", "sample_idx",
+                 "sample_mask", "lens", "tokens", "final", "release",
+                 "n_active", "t0", "t0_ns", "dispatch_s", "fetch_s")
+
+    def carries(self, s: int, req) -> bool:
+        """Is the row of slot ``s`` this binding of ``req``?"""
+        return self.reqs[s] is req and self.leases[s] == req.preemptions
+
+    def writes_for(self, req) -> bool:
+        return self.host is None and any(r is req for r in self.reqs)
 
 
 # ---------------------------------------------------------------------------
@@ -1347,7 +1399,12 @@ class DecodeEngine:
                               top_k=int(top_k), top_p=float(top_p))
         self._eos = eos_token_id
         self._key = jax.random.PRNGKey(seed)
-        self._step_no = 0
+        self._step_no = 0  # the last step dispatched
+        # the step dispatched and not yet landed (`step`'s lookahead),
+        # and the ``prev`` operand of a step dispatched with none
+        self._inflight: Optional[_Step] = None
+        self._no_prev = jnp.zeros(self._slots + int(self._kv_quant),
+                                  jnp.int32)
         # what the profiler spans of the current `step()` call carry as
         # ``step=``, and the wall of that call which ``decode_time_s``
         # / ``prefill_time_s`` already hold (dispatch to fetched tokens)
@@ -2028,6 +2085,7 @@ class DecodeEngine:
         from .durability import retire_engine_series
 
         retire_engine_series(self._engine_id)
+        self._inflight = None
         self._by_slot = [None] * self._slots
         self._active = np.zeros(self._slots, bool)
         self._queue.clear()
@@ -2517,7 +2575,7 @@ class DecodeEngine:
         # the pass's wall time is real either way; the token count,
         # prefill count and TTFT stamp wait for the NaN-sentinel check
         # below — a quarantined prefill emitted nothing (mirrors the
-        # chunked path, where _on_first_token checks before stamping)
+        # chunked path, where _land_row checks before stamping)
         dt = time.perf_counter() - t0
         self._batch_s += dt
         _stats_add(prefill_time_s=dt)
@@ -2649,10 +2707,11 @@ class DecodeEngine:
                                         req._page_hashes[i])
         req._reg_pages = len(req._page_hashes)
 
-    def _register_generated_pages(self, slot: int, req: Request):
-        """Decode just advanced ``slot``: content-address any GENERATED
-        page that became full (ROADMAP quantized-serving rung (d)), so
-        beam/agent fanout sharing a decode prefix maps it instead of
+    def _register_generated_pages(self, req: Request, kv_len: int):
+        """Decode just advanced ``req`` to ``kv_len`` KV rows: content-
+        address any GENERATED page that became full (ROADMAP quantized-
+        serving rung (d)), so beam/agent fanout sharing a decode prefix
+        maps it instead of
         recomputing.  Safe to freeze: KV rows ``< lens`` are final (a
         speculative rejection only ever shrinks lens back to the
         accepted point BEFORE new rows are written, and every later
@@ -2667,7 +2726,7 @@ class DecodeEngine:
         if not self._cache_generated or not self._prefix_cache or \
                 req.t_first_token_ns is None:
             return
-        full = int(self._lens[slot]) // self._page
+        full = int(kv_len) // self._page
         if full <= req._reg_pages:
             return
         toks = req.prompt_ids + req.output_ids
@@ -2686,16 +2745,21 @@ class DecodeEngine:
                 self.pool.register_page(req.pages[i], hashes[i])
         req._reg_pages = full
 
-    def _finish(self, slot: int, reason: str):
-        req = self._by_slot[slot]
-        self.pool.release_pages(req.pages)
+    def _finish(self, slot: Optional[int], reason: str, req=None):
+        """``req`` (default: the request of ``slot``) leaves the engine;
+        ``slot=None`` for one whose slot was already released
+        (`_release_finishing_slots`)."""
+        if req is None:
+            req = self._by_slot[slot]
+        self._release_pages(req)
         self.pool.reserved -= max(
             self._pages_for(req.total_kv_tokens()) - len(req.pages), 0)
         req.state = "done"
         req.finish_reason = reason
         req.slot = None
         req.pages = []
-        self._release_slot(slot)
+        if slot is not None:
+            self._release_slot(slot)
         _stats_add(**{{"eos": "finished_eos", "length": "finished_length",
                        "evicted": "evicted", "cancelled": "cancelled",
                        "fault": "finished_fault"}[reason]: 1})
@@ -2729,11 +2793,30 @@ class DecodeEngine:
             # it ran to completion, but past its deadline: a violation,
             # distinct from queued-expiry (which never takes a slot)
             self._slo_violation(req, "deadline")
-        if self._spec is not None:
+        if self._spec is not None and slot is not None:
             self._spec.on_finish(slot, req)
         if self._flight is not None:
             # after the SLO checks above: slo_met is final here
             self._flight.note_finish(req)
+
+    def _release_pages(self, req: Request):
+        """Give ``req``'s pages back to the pool: at once, or, while the
+        step in flight writes into them, when its tokens are read (no
+        page is handed out under a write that may still be running)."""
+        rec = self._inflight
+        if rec is not None and rec.writes_for(req):
+            rec.release.append(req.pages)
+        else:
+            self.pool.release_pages(req.pages)
+
+    def _settle_inflight(self):
+        """A request left between steps (evict, cancel, preempt) while
+        the step in flight writes its pages: read that step's tokens now
+        (they still land at the next step) so the pages go back before
+        anything else is admitted."""
+        rec = self._inflight
+        if rec is not None and rec.release:
+            self._fetch(rec)
 
     def evict(self, req: Request):
         """Cancel a request: a queued request leaves the queue, a
@@ -2748,6 +2831,7 @@ class DecodeEngine:
                 0 <= req.slot < self._slots and \
                 self._by_slot[req.slot] is req:
             self._finish(req.slot, "evicted")
+            self._settle_inflight()
             return
         if req.state == "done":
             return  # already finished; nothing to release
@@ -2774,10 +2858,15 @@ class DecodeEngine:
             raise ValueError(
                 f"preempt() is for running requests; this one is "
                 f"{req.state!r}")
-        slot = req.slot
+        self._requeue(req, int(self._lens[req.slot]), req.slot)
+
+    def _requeue(self, req: Request, kv_len: int,
+                 slot: Optional[int] = None):
+        """`preempt`'s fold of a running request with ``kv_len`` KV rows
+        back into the queue; ``slot=None`` for one whose slot was
+        already released (`_drop`)."""
         total_pages = self._pages_for(req.total_kv_tokens())
         n_gen = len(req.output_ids)
-        kv_len = int(self._lens[slot])
         replay_hashes = None
         if self._prefix_cache and req.t_first_token_ns is not None:
             # content-address every fully written page of the replay
@@ -2806,17 +2895,20 @@ class DecodeEngine:
         req.preemptions += 1
         # release the device-side claim (pages + outstanding
         # reservation) and the slot — the same teardown as _finish,
-        # minus the finished bookkeeping
-        self.pool.release_pages(req.pages)
+        # minus the finished bookkeeping; a row of it in flight is
+        # dropped where that step lands
+        self._release_pages(req)
+        self._settle_inflight()
         self.pool.reserved -= max(total_pages - len(req.pages), 0)
         req.pages = []
         req.cached_page_count = 0
         req.cached_prefix_len = 0
         req.slot = None
         req.state = "queued"
-        self._release_slot(slot)
-        if self._spec is not None:
-            self._spec.on_finish(slot, req)
+        if slot is not None:
+            self._release_slot(slot)
+            if self._spec is not None:
+                self._spec.on_finish(slot, req)
         # back of the line position-wise, but schedulers order by
         # (priority, deadline, id) anyway and the id is the original
         # (oldest-first within its class); FIFO resumes it first
@@ -2837,6 +2929,7 @@ class DecodeEngine:
                 self._by_slot[req.slot] is not req:
             raise ValueError("request is not running on this engine")
         self._finish(req.slot, "cancelled")
+        self._settle_inflight()
 
     def _retire_queued(self, req: Request, reason: str):
         """Take a still-queued request out of the admission queue
@@ -2881,11 +2974,13 @@ class DecodeEngine:
                 f"running request")
         self._retire_queued(req, "cancelled")
 
-    def _grow_block_tables(self, writes=None):
+    def _grow_block_tables(self, writes=None, lens=None):
         """Ensure pages exist for every KV row the next step will write:
         positions ``lens[slot] .. lens[slot] + writes[slot] - 1``
         (``writes`` defaults to one token per slot; the speculative
-        verify step writes up to K+1).  Slot reuse keeps this a pop from
+        verify step writes up to K+1; ``lens`` defaults to the host's
+        KV lengths, a step assembled while another is in flight passes
+        the lengths that step leaves).  Slot reuse keeps this a pop from
         the free list, not an allocation; the pages stay with the
         request until it finishes, so a speculative rejection rolls back
         ``seq_lens`` WITHOUT touching the pool.
@@ -2895,7 +2990,8 @@ class DecodeEngine:
         persists, quarantines a request — which frees pages.  Partial
         growth is consistent state (grown pages belong to their
         requests), so the retry re-enters here idempotently."""
-        fr = self._flight
+        if lens is None:
+            lens = self._lens
         with self._phase("cache"):
             if self._fault is not None:
                 self._resilience.fault_point("pool")
@@ -2906,7 +3002,7 @@ class DecodeEngine:
                 w = 1 if writes is None else int(writes[slot])
                 if w == 0:
                     continue  # nothing written this step
-                pidx = (int(self._lens[slot]) + w - 1) // self._page
+                pidx = (int(lens[slot]) + w - 1) // self._page
                 while pidx >= len(req.pages):
                     req.pages.append(self._alloc_page())
                     self.pool.reserved -= 1
@@ -2983,17 +3079,89 @@ class DecodeEngine:
                 site="DecodeEngine ragged step (_gpt_ragged_step)")
         return fn
 
-    def _mixed_step(self, decode_rows=True) -> bool:
-        """One fused prefill+decode step: assemble the fixed-shape
-        [slots, Q_max] mixed batch under the chunk-token budget, run the
-        single donated mixed executable, land chunks / tokens on the
-        host side.  ``decode_rows=False`` (the speculative path) feeds
-        ONLY prompt chunks — decoding slots advance through the spec
-        round that follows in the same engine step."""
+    # -- one step: assemble, dispatch, land ----------------------------------
+    def _drain_cause(self) -> Optional[str]:
+        """Why the next step must not be dispatched before the one in
+        flight has landed, or None when it may: each cause is a path
+        that reads fetched values before its next dispatch, or that
+        times or audits one step at a time."""
+        if self._spec is not None:
+            return "speculative"  # acceptance sizes the next windows
+        if self._ragged:
+            return "ragged"  # the host picks each span's sampled row
+        if not self._chunked:
+            return "legacy_prefill"  # admission runs a prompt pass
+        if self._watchdog is not None:
+            return "watchdog"  # its snapshot is taken before each step
+        if self._profiling is not None and self._profiling.probing:
+            return "probe"  # the probe blocks on the step's output
+        if _san.active() is not None:
+            return "sanitizer"  # host syncs are counted per step
+        if self._fault is not None:
+            return "fault_injection"  # faults fire at a step's sites
+        return None
+
+    def _final_by_length(self, rec: _Step, s: int, req: Request) -> bool:
+        """Does the row of slot ``s`` in ``rec`` sample ``req``'s last
+        token by length?"""
+        return rec.carries(s, req) and bool(rec.sample_mask[s]) and \
+            len(req.output_ids) + 1 >= req.max_new_tokens
+
+    def _release_finishing_slots(self):
+        """Before admission, while a step is in flight: a slot whose row
+        there samples its request's last token by length has nothing
+        left to run, so it is free for this step's admission at once,
+        as it would be after that step landed.  The request keeps its
+        pages; its token lands without a slot (`_retire`)."""
+        rec = self._inflight
+        if rec is None:
+            return
+        for s in range(self._slots):
+            req = self._by_slot[s]
+            if req is not None and self._final_by_length(rec, s, req):
+                rec.final.append(s)
+                req.slot = None
+                self._release_slot(s)
+
+    def _dispatch(self, decode_rows=True) -> Optional[_Step]:
+        """Assemble the next step and dispatch it, or return None when no
+        slot has a row and a step is in flight (with none in flight, an
+        empty batch still dispatches: the containment ladder's last
+        probe of an engine it has emptied).  The rows come from the
+        host's state advanced by the step in flight, if one is: a slot
+        that step carries starts at the KV length and prefill cursor it
+        leaves, and where it samples, the slot's incoming token is the
+        one it samples, read on the device (``from_prev``) without a
+        trip through the host.
+        A prefilling slot runs the fixed-shape [slots, Q_max] mixed
+        executable: prefilling slots carry a prompt chunk under the
+        chunk-token budget, decoding slots their one row; with no slot
+        prefilling it is the decode executable (the ragged one, where
+        the engine has it, in both cases).  ``decode_rows=False`` (the
+        speculative path) feeds ONLY prompt chunks — decoding slots
+        advance through the spec round that follows in the same engine
+        step."""
+        prev = self._inflight
         # batch assembly, from here to the dispatch span: rows and
         # chunks, page growth, the step's key, fresh quant scales
         with _flight.engine_span(self, "assemble"):
             slots, qmax = self._slots, self._q_max
+            lens = self._lens.copy()
+            cursor = self._prefill_pos.copy()
+            from_prev = np.zeros(slots, bool)
+            live = [s for s in range(slots) if self._active[s]]
+            if not live and prev is not None:
+                return None
+            if prev is not None:
+                for s in live:
+                    if prev.carries(s, self._by_slot[s]):
+                        lens[s] += prev.caps[s]
+                        cursor[s] += prev.chunk_of.get(s, 0)
+                        from_prev[s] = prev.sample_mask[s]
+            prefilling = [s for s in live if int(cursor[s]) <
+                          len(self._by_slot[s].prompt_ids)]
+            exe = "ragged" if self._ragged else \
+                "mixed" if prefilling or not decode_rows else "decode"
             # ragged mode widens the grid to Q_r >= Q_max so the ONE
             # executable's token shape also fits verify windows (K+1);
             # chunk spans stay capped by Q_max (the chunk-budget
@@ -3003,8 +3171,6 @@ class DecodeEngine:
             caps = np.zeros(slots, np.int32)
             sample_idx = np.zeros(slots, np.int32)
             sample_mask = np.zeros(slots, bool)
-            prefilling = [s for s in range(slots)
-                          if self._active[s] and self._is_prefilling(s)]
             # fair-share chunking: the step's token budget splits
             # evenly across prefilling slots (remainder to the lower
             # slots), so a short prompt admitted next to a long one
@@ -3013,9 +3179,9 @@ class DecodeEngine:
             # everyone, not just slot 0
             budget = self._chunk_budget
             chunk_of = {}
-            for i, s in enumerate(prefilling):
+            for i, s in enumerate(prefilling if exe != "decode" else ()):
                 req = self._by_slot[s]
-                cur = int(self._prefill_pos[s])
+                cur = int(cursor[s])
                 share = -(-budget // (len(prefilling) - i))  # ceil
                 c = min(len(req.prompt_ids) - cur, share, qmax)
                 if c == 0:
@@ -3030,177 +3196,307 @@ class DecodeEngine:
                     sample_mask[s] = True
             decoding = 0
             if decode_rows:
-                for s in range(slots):
-                    if self._active[s] and s not in chunk_of and \
-                            not self._is_prefilling(s):
-                        tokens[s, 0] = self._last[s]
-                        caps[s] = 1
-                        sample_idx[s] = 0
-                        sample_mask[s] = True
-                        decoding += 1
-            self._grow_block_tables(writes=caps)
+                for s in live:
+                    if s in prefilling:
+                        continue
+                    tokens[s, 0] = 0 if from_prev[s] else self._last[s]
+                    caps[s] = 1
+                    sample_mask[s] = True
+                    decoding += 1
+            from_prev &= caps > 0
+            self._grow_block_tables(writes=caps, lens=lens)
 
-            fn = self._ragged_fn_tracker() if self._ragged \
-                else self._mixed_fn_tracker()
+            if exe == "ragged":
+                fn = self._ragged_fn_tracker()
+            elif exe == "mixed":
+                fn = self._mixed_fn_tracker()
+            else:
+                fn = self._decode_fn_tracker()
             if self._fault is not None:
                 # fault site BEFORE the invocation (and the step
                 # counter): an injected raise leaves no half-donated
                 # state, so the containment ladder's retry re-enters
                 # cleanly
-                self._resilience.step_fault_point("mixed_step")
+                self._resilience.step_fault_point(
+                    "decode_step" if exe == "decode" else "mixed_step")
             self._step_no += 1
             key = jax.random.fold_in(
                 self._key,
                 _fold_counter(self._step_no, RNG_DECODE_DOMAIN))
-            fr = self._flight
             # phase attribution: chunk-only mixed steps are prompt
             # work ("prefill"), chunk-carrying full steps are fused
-            # ("mixed"), chunkless full steps are plain decode through
-            # the mixed executable ("decode")
+            # ("mixed"), chunkless full steps are plain decode
             phase_name = "prefill" if not decode_rows else \
                 ("mixed" if chunk_of else "decode")
             self._flush_fresh_scales()
+        rec = _Step()
+        rec.step_no, rec.exe = self._step_no, exe
+        rec.decode_rows, rec.ahead = decode_rows, prev is not None
+        rec.reqs = [self._by_slot[s] if caps[s] else None
+                    for s in range(slots)]
+        rec.leases = [r.preemptions if r is not None else 0
+                      for r in rec.reqs]
+        rec.caps, rec.chunk_of, rec.lens = caps, chunk_of, lens
+        rec.sample_idx, rec.sample_mask = sample_idx, sample_mask
+        rec.tokens, rec.final, rec.release = tokens, [], []
+        rec.host, rec.fetch_s = None, 0.0
+        rec.n_active = len(live)
+        prev_toks = self._no_prev if prev is None else prev.toks
+        bt = self._bt.copy()  # the host grows the next step's meanwhile
         t0 = time.perf_counter()
         t0_ns = _obs.now_ns()
         with self._phase(phase_name, decode_rows=decoding,
                          chunk_rows=len(chunk_of),
-                         chunk_tokens=sum(chunk_of.values())):
-            if self._ragged:
+                         chunk_tokens=sum(chunk_of.values()),
+                         lookahead=int(rec.ahead)):
+            if exe == "ragged":
                 # the unified executable takes no sample_idx /
                 # sample_mask operands — every position draws a
                 # target and the host selects each slot's span-end
-                # row after the fetch below
+                # row after the fetch (`_fetch`)
                 self._kv, toks = fn(
-                    self._params, self._kv, self._dev(self._bt),
-                    self._dev(self._lens), self._dev(tokens),
+                    self._params, self._kv, self._dev(bt),
+                    self._dev(lens), self._dev(tokens),
                     self._dev(caps), self._dev(key))
+            elif exe == "mixed":
+                self._kv, toks = fn(
+                    self._params, self._kv, jnp.asarray(bt),
+                    jnp.asarray(lens), jnp.asarray(tokens),
+                    jnp.asarray(caps), jnp.asarray(sample_idx),
+                    jnp.asarray(sample_mask), key, prev_toks,
+                    jnp.asarray(from_prev))
             else:
                 self._kv, toks = fn(
-                    self._params, self._kv,
-                    jnp.asarray(self._bt), jnp.asarray(self._lens),
-                    jnp.asarray(tokens), jnp.asarray(caps),
-                    jnp.asarray(sample_idx),
-                    jnp.asarray(sample_mask), key)
+                    self._params, self._kv, jnp.asarray(bt),
+                    jnp.asarray(lens), jnp.asarray(tokens[:, 0]),
+                    jnp.asarray(caps > 0), key, prev_toks,
+                    jnp.asarray(from_prev))
             if self._profiling is not None:
-                # sampled device-sync probe (see _step_inner):
-                # attributed to the DISPATCHED executable (ragged
-                # or mixed) regardless of the flight phase this
-                # step ran under — a chunkless full step runs the
-                # program under the "decode" phase, and scoring it
+                # sampled device-sync probe: block on the step's
+                # output INSIDE the phase (the phase wall absorbs the
+                # wait) so dispatch-start -> ready is the executable's
+                # measured device seconds.  Attributed to the
+                # DISPATCHED executable regardless of the flight phase
+                # this step ran under — a chunkless mixed step runs
+                # the program under the "decode" phase, and scoring it
                 # against the decode profile would poison the
                 # calibration
-                self._profiling.probe(
-                    "ragged" if self._ragged else "mixed",
-                    toks, t0, t0_ns)
-        toks = self._note_refolds(self._host_fetch(toks))
-        if self._ragged:
+                self._profiling.probe(exe, toks, t0, t0_ns)
+        rec.toks = toks
+        rec.t0, rec.t0_ns = t0, t0_ns
+        rec.dispatch_s = time.perf_counter() - t0
+        self._batch_s += rec.dispatch_s
+        return rec
+
+    def _decode_fn_tracker(self) -> _JitTracker:
+        fn = self._decode_fn
+        if fn is None:
+            fn = self._decode_fn = _JitTracker(
+                functools.partial(_gpt_decode_step,
+                                  num_heads=self._num_heads,
+                                  head_dim=self._head_dim,
+                                  eps=self._eps, **self._sampling),
+                "decode_compiles", donate_argnums=(1,),
+                site="DecodeEngine decode step (_gpt_decode_step)")
+        return fn
+
+    def _fetch(self, rec: _Step, *also: _Step):
+        """Read ``rec``'s tokens back, once — THE blocking wait of a
+        step — and give back the pages that waited for it.  A read that
+        fails drops ``rec`` and ``also`` (`_drop`) before it raises."""
+        if rec.host is not None:
+            return
+        t = time.perf_counter()
+        try:
+            toks = self._note_refolds(self._host_fetch(rec.toks))
+        except BaseException:
+            self._drop(rec, *also)
+            raise
+        if rec.exe == "ragged":
             # host-side span-end selection: a decode row's token sits
             # at column 0, a finishing chunk's at column c-1; padding
             # columns (and sat-out slots) are garbage.  np.where keeps
             # NAN_TOKEN (-1) for masked slots, so per-row quarantine
             # still fires
-            toks = np.where(sample_mask,
-                            toks[np.arange(slots), sample_idx], 0)
-        dt = time.perf_counter() - t0
-        self._batch_s += dt
+            toks = np.where(rec.sample_mask,
+                            toks[np.arange(self._slots), rec.sample_idx],
+                            0)
+        rec.host = toks
+        rec.fetch_s = time.perf_counter() - t
+        self._batch_s += rec.fetch_s
+        for pages in rec.release:
+            self.pool.release_pages(pages)
+        rec.release = []
+
+    def _drop(self, *recs: Optional[_Step]):
+        """Throw dispatched steps away after waiting for them: an
+        exception with a step in flight leaves the host state at the
+        last step landed, and the step numbers after it are dispatched
+        again.  A request whose last token was in a dropped step, and
+        whose slot was already released for it, goes back to the head
+        of the queue with its generation folded into its prompt (as a
+        preemption would), so it is neither lost nor short a token."""
+        recs = [r for r in recs if r is not None]
+        if not recs:
+            return
+        if self._inflight in recs:
+            self._inflight = None
+        for rec in recs:
+            try:
+                jax.block_until_ready(rec.toks)
+            except Exception:
+                pass  # a failed step: nothing of it is read
+            for pages in rec.release:
+                self.pool.release_pages(pages)
+            rec.release = []
+            for s in rec.final:
+                req = rec.reqs[s]
+                if req.state == "running" and req.slot is None:
+                    self._requeue(req, kv_len=int(rec.lens[s]))
+        self._step_no = min(r.step_no for r in recs) - 1
+        if self._flight is not None:
+            self._flight.event("lookahead_drop",
+                               steps=[r.step_no for r in recs])
+
+    def _drain(self, cause: str):
+        """Land the step in flight before anything else runs; ``cause``
+        (`_drain_cause`, or "empty": no slot has a row for the next
+        step, or "caller": `drain`) goes on the flight record."""
+        rec, self._inflight = self._inflight, None
+        if rec is None:
+            return
+        _stats_add(lookahead_drains=1)
+        if self._flight is not None:
+            self._flight.event("lookahead_drain", cause=cause,
+                               step=rec.step_no)
+        self._retire(rec)
+
+    def drain(self):
+        """Land the step still in flight, if any.  ``step(); drain()``
+        is the synchronous schedule: every step read back before the
+        next is dispatched, as before lookahead (tests compare the two
+        schedules through it)."""
+        self._drain("caller")
+
+    def _retire(self, rec: _Step, *also: _Step):
+        """Read ``rec`` back (`_fetch`) and land its tokens: chunks
+        advance their slots, first and decode tokens are emitted,
+        finishes checked.  A row whose request left since ``rec`` was
+        dispatched (eos, a quarantine, an evict or cancel, a
+        preemption) is dropped."""
+        self._fetch(rec, *also)
+        toks = rec.host
+        slots = self._slots
+        dt = rec.dispatch_s + rec.fetch_s
         if self._fault is not None:
             toks = self._resilience.corrupt_tokens(
-                toks, [s for s in range(slots) if sample_mask[s]])
+                toks, [s for s in range(slots) if rec.sample_mask[s]])
 
         # the drafter sees the SAME chunks through the same executable
         # shape (speculative path: caps carry only prompt chunks there)
-        if self._spec is not None and chunk_of:
-            self._spec.drafter.ingest_chunks(tokens, caps)
+        if self._spec is not None and rec.chunk_of:
+            self._spec.drafter.ingest_chunks(rec.tokens, rec.caps)
 
-        n_active = int(self._active.sum())
-        chunk_tokens = sum(chunk_of.values())
-        if decode_rows:
-            # a full mixed step IS this engine-step's decode step
-            _stats_add(mixed_steps=1, prefill_chunks=len(chunk_of),
-                       steps=1, decode_time_s=dt, mixed_time_s=dt,
-                       occupancy_sum=n_active / slots,
-                       kv_util_sum=self.pool.utilization())
-        else:
+        kv_util = self.pool.utilization()
+        if not rec.decode_rows:
             # chunk-only (speculative path): the spec round that follows
             # accounts the engine step; this wall is prefill work
-            _stats_add(mixed_steps=1, prefill_chunks=len(chunk_of),
+            _stats_add(mixed_steps=1, prefill_chunks=len(rec.chunk_of),
                        prefill_time_s=dt, mixed_time_s=dt)
-        for c in chunk_of.values():
+        else:
+            # a full mixed step IS this engine-step's decode step
+            mixed = rec.exe != "decode"
+            _stats_add(mixed_steps=int(mixed),
+                       prefill_chunks=len(rec.chunk_of), steps=1,
+                       decode_time_s=dt, mixed_time_s=dt if mixed else 0.0,
+                       occupancy_sum=rec.n_active / slots,
+                       kv_util_sum=kv_util,
+                       lookahead_steps=int(rec.ahead))
+        for c in rec.chunk_of.values():
             _obs.PREFILL_CHUNK_TOKENS.observe(c)
-        self._observe_step(t0_ns, dt, n_active, "mixed_step",
-                           extra_args={"prefilling": len(chunk_of),
-                                       "chunk_tokens": chunk_tokens},
-                           observe_hist=decode_rows)
+        if rec.exe == "decode":
+            self._observe_step(rec.t0_ns, dt, rec.n_active, "decode_step")
+        else:
+            self._observe_step(
+                rec.t0_ns, dt, rec.n_active, "mixed_step",
+                extra_args={"prefilling": len(rec.chunk_of),
+                            "chunk_tokens": sum(rec.chunk_of.values())},
+                observe_hist=rec.decode_rows)
 
-        emitted = 0
+        emitted = dropped = 0
         with self._excl_phase("emit"):
             for s in range(slots):
-                if not self._active[s]:
+                req = rec.reqs[s]
+                if req is None:
                     continue
-                req = self._by_slot[s]
-                c = chunk_of.get(s)
-                if c is not None:
-                    self._prefill_pos[s] += c
-                    self._lens[s] += c
-                    req.prefill_chunks += 1
-                    if int(self._prefill_pos[s]) == len(req.prompt_ids):
-                        if self._on_first_token(s, req, int(toks[s])):
-                            emitted += 1
-                elif caps[s] == 1:
-                    tok = int(toks[s])
-                    if tok < 0:
-                        # non-finite logits on this row only:
-                        # quarantine the slot, never the batch (lens
-                        # stays — the garbage K/V row is released with
-                        # the pages)
-                        self._quarantine_slot(s, "nan_logits")
-                        continue
-                    self._lens[s] += 1
-                    self._last[s] = tok
-                    self._emit(req, [tok])
-                    emitted += 1
-                    self._register_generated_pages(s, req)
-                    reason = self._done(req, tok)
-                    if reason:
-                        self._finish(s, reason)
-        _stats_add(tokens=emitted)
-        return True
+                if req.state != "running" or \
+                        rec.leases[s] != req.preemptions:
+                    dropped += 1
+                    continue
+                emitted += self._land_row(rec, s, req, int(toks[s]))
+        _stats_add(tokens=emitted, lookahead_dropped_rows=dropped)
 
-    def _on_first_token(self, slot: int, req: Request, tok: int) -> bool:
-        """A slot's LAST prompt chunk landed: the mixed step sampled its
-        first token — stamp TTFT now (not at admission, not at the first
-        chunk) and flip the slot into plain decoding.  The prompt's full
-        pages are content-final from here on, so they enter the prefix
-        cache before any finish-path release can park them.  A RESUMED
-        request (preempted earlier) keeps its original TTFT — the token
-        sampled here is mid-generation, not its first.  Returns False
-        when the token was the NaN sentinel: the slot is quarantined
-        and — crucially — its pages are NOT registered (K/V computed
-        under non-finite activations must never enter the prefix
-        cache)."""
+    def _land_row(self, rec: _Step, s: int, req: Request, tok: int) -> int:
+        """Land row ``s`` of ``rec`` on ``req``; returns the tokens
+        emitted (0 or 1).  A slot's LAST prompt chunk brings its first
+        token: TTFT is stamped then (not at admission, not at the first
+        chunk), and the prompt's full pages enter the prefix cache
+        before any finish-path release can park them — unless the token
+        is the NaN sentinel: the request is quarantined and its pages
+        are NOT registered (K/V computed under non-finite activations
+        must never enter the prefix cache).  A RESUMED request keeps its
+        original TTFT.  A decode token with non-finite logits
+        quarantines this row only, never the batch."""
+        slot = None if s in rec.final else s
+        kv_len = int(rec.lens[s]) + int(rec.caps[s])
+        c = rec.chunk_of.get(s)
+        if slot is not None:
+            self._lens[s] = kv_len
+            if c is not None:
+                self._prefill_pos[s] += c
+        if c is not None:
+            req.prefill_chunks += 1
+            if not rec.sample_mask[s]:
+                return 0  # mid-prompt: nothing sampled yet
         if tok < 0:
-            self._quarantine_slot(slot, "nan_logits")
-            return False
-        self._register_prompt_pages(req)
+            self._quarantine_slot(slot, "nan_logits", req=req)
+            return 0
+        if c is not None:
+            self._register_prompt_pages(req)
+        if slot is not None:
+            self._last[s] = tok
         self._emit(req, [tok])
-        self._last[slot] = tok
-        _stats_add(prefills=1)
-        self._stamp_first_token(req, prompt_len=len(req.prompt_ids),
-                                chunks=req.prefill_chunks)
+        if c is not None:
+            _stats_add(prefills=1)
+            self._stamp_first_token(req, prompt_len=len(req.prompt_ids),
+                                    chunks=req.prefill_chunks)
+        else:
+            self._register_generated_pages(req, kv_len)
         reason = self._done(req, tok)
         if reason:
-            self._finish(slot, reason)
+            self._finish(slot, reason, req=req)
+        return 1
+
+    def _mixed_step(self, decode_rows=True) -> bool:
+        """One step dispatched and landed at once (the speculative
+        round's prompt chunks: ``decode_rows=False``)."""
+        rec = self._dispatch(decode_rows=decode_rows)
+        if rec is not None:
+            self._retire(rec)
         return True
 
-    def _quarantine_slot(self, slot: int, site: str, message: str = ""):
+    def _quarantine_slot(self, slot: Optional[int], site: str,
+                         message: str = "", req=None):
         """Containment verdict for ONE slot: its request leaves the
         engine with ``finish_reason="fault"`` and a structured
         `FaultInfo`, its pages and slot are released through the
         normal `_finish` teardown, and every other slot keeps serving.
         Used by the NaN/inf logit guard (only the offending row is
         poisoned — evicting the batch for one sick request would be
-        the availability bug this PR exists to remove)."""
-        req = self._by_slot[slot]
+        the availability bug this PR exists to remove).  ``slot=None``
+        with ``req``: a request whose slot was already released."""
+        if req is None:
+            req = self._by_slot[slot]
         if req.fault_info is None:
             req.fault_info = FaultInfo(
                 site=site, step=self._step_no, recovered=False,
@@ -3216,16 +3512,20 @@ class DecodeEngine:
         if self._flight is not None:
             self._flight.event("quarantine", request=req.request_id,
                                slot=slot, site=site)
-        self._finish(slot, "fault")
+        self._finish(slot, "fault", req=req)
 
     def _debug_check_pool(self):
         """FLAGS_kv_pool_debug / FLAGS_sanitize: full pool-consistency
         audit at an engine idle point (between steps, no device call in
         flight) — every live request's page list cross-checked against
-        the pool's free/private/cached partition and refcounts."""
-        self.pool.assert_consistent(
-            live_pages=[p for r in self._by_slot if r is not None
-                        for p in r.pages])
+        the pool's free/private/cached partition and refcounts.  Pages
+        waiting for the step in flight to land (`_release_pages`) are
+        still live."""
+        live = [p for r in self._by_slot if r is not None for p in r.pages]
+        rec = self._inflight
+        if rec is not None:
+            live += [p for pages in rec.release for p in pages]
+        self.pool.assert_consistent(live_pages=live)
 
     def _dev(self, x):
         """Host->device for step-executable operands.  Single-chip:
@@ -3460,6 +3760,15 @@ class DecodeEngine:
         propose->verify->accept round when spec decoding is on.
         Returns False when there is nothing left to do.
 
+        The step dispatched here stays in flight when this returns, and
+        the call lands the step dispatched before it (`_step_inner`):
+        tokens reach requests one call after their step's dispatch,
+        while the chip already runs the next step.  A slot whose
+        request's last token (by length) is in flight is free for this
+        call's admission.  `drain` lands the step in flight; engines
+        that must see every step's tokens first run synchronously
+        (`_drain_cause`).
+
         The device step runs under the containment ladder
         (`inference.resilience.ResilienceManager.run_step`): a raising
         step executable is retried with capped exponential backoff,
@@ -3496,6 +3805,7 @@ class DecodeEngine:
             # legacy one-shot prefill runs INSIDE admission, and its
             # device/fetch time must not double-count
             with self._excl_phase("admit"):
+                self._release_finishing_slots()
                 self._admit()
             # admission-pressure gauges, sampled every step AFTER
             # admission (what is left queued is the backlog the
@@ -3517,7 +3827,7 @@ class DecodeEngine:
                 # flight record BEFORE the device step runs, so the
                 # record's predicted/actual pair is an honest forecast
                 self._cost.note_step_begin(fr)
-            if not self._active.any():
+            if not self._active.any() and self._inflight is None:
                 if self._durability is not None:
                     self._durability.on_step_boundary()
                 if fr is not None:
@@ -3614,93 +3924,47 @@ class DecodeEngine:
     def _step_inner(self) -> bool:
         """ONE batched device step over the already-admitted batch —
         the containment ladder's unit of retry (`step` wraps it; never
-        call it from outside the ladder).  Dispatches to the
-        speculative round, the mixed prefill+decode step, or the
-        classic decode step exactly as `step` historically did."""
+        call it from outside the ladder): the speculative round, or one
+        mixed prefill+decode or decode step (`_dispatch`).
+
+        With nothing that forces a drain (`_drain_cause`) the step is
+        dispatched BEFORE the one in flight is read back, and stays in
+        flight when this returns: the host's fetch and landing of step
+        N, the frontend's hops and the next call's admission and
+        assembly run while the chip works on step N+1.  The step's key
+        is still ``fold_in(key, _fold_counter(n, RNG_DECODE_DOMAIN))``
+        for step number ``n``.  An exception with a step in flight
+        drops that step (`_drop`) before the ladder sees it."""
         if self._fault is not None:
             # "slow_step" site: a deterministic injected stall (the
             # latency-fault class — SLO metrics see it, nothing raises)
             self._resilience.fault_point("slow_step")
+        cause = self._drain_cause()
+        if cause is not None:
+            self._drain(cause)
         if self._spec is not None and self._resilience.spec_active():
             return self._spec.step()
-        if self._chunked and self._prefilling_any():
-            return self._mixed_step()
-        if self._ragged:
-            # ragged unified path: a chunkless step still dispatches
-            # the ONE ragged executable (decode rows carry span 1), so
-            # steady-state serving never touches _gpt_decode_step
-            return self._mixed_step()
-        with _flight.engine_span(self, "assemble"):
-            self._grow_block_tables()
-
-            fn = self._decode_fn
-            if fn is None:
-                fn = self._decode_fn = _JitTracker(
-                    functools.partial(_gpt_decode_step,
-                                      num_heads=self._num_heads,
-                                      head_dim=self._head_dim,
-                                      eps=self._eps, **self._sampling),
-                    "decode_compiles", donate_argnums=(1,),
-                    site="DecodeEngine decode step (_gpt_decode_step)")
-
-            if self._fault is not None:
-                self._resilience.step_fault_point("decode_step")
-            self._step_no += 1
-            key = jax.random.fold_in(
-                self._key,
-                _fold_counter(self._step_no, RNG_DECODE_DOMAIN))
-            fr = self._flight
-            self._flush_fresh_scales()
-        t0 = time.perf_counter()
-        t0_ns = _obs.now_ns()
-        with self._phase("decode", decode_rows=int(self._active.sum()),
-                         chunk_rows=0, chunk_tokens=0):
-            self._kv, toks = fn(
-                self._params, self._kv,
-                jnp.asarray(self._bt), jnp.asarray(self._lens),
-                jnp.asarray(self._last), jnp.asarray(self._active), key)
-            if self._profiling is not None:
-                # sampled device-sync probe: block on the step's
-                # output INSIDE the phase (the phase wall absorbs
-                # the wait) so dispatch-start -> ready is the
-                # executable's measured device seconds
-                self._profiling.probe("decode", toks, t0, t0_ns)
-        toks = self._note_refolds(self._host_fetch(toks))
-        dt = time.perf_counter() - t0
-        self._batch_s += dt
-        if self._fault is not None:
-            toks = self._resilience.corrupt_tokens(
-                toks, [s for s in range(self._slots) if self._active[s]])
-
-        n_active = int(self._active.sum())
-        kv_util = self.pool.utilization()  # pre-finish, as historically
-        emitted = 0
-        self._observe_step(t0_ns, dt, n_active, "decode_step")
-
-        with self._excl_phase("emit"):
-            for slot in range(self._slots):
-                if not self._active[slot]:
-                    continue
-                tok = int(toks[slot])
-                req = self._by_slot[slot]
-                if tok < 0:
-                    # non-finite logits on this row: quarantine the
-                    # slot only — the rest of the batch emitted
-                    # healthy tokens
-                    self._quarantine_slot(slot, "nan_logits")
-                    continue
-                self._lens[slot] += 1
-                self._last[slot] = tok
-                self._emit(req, [tok])
-                emitted += 1
-                self._register_generated_pages(slot, req)
-                reason = self._done(req, tok)
-                if reason:
-                    self._finish(slot, reason)
-        _stats_add(steps=1, decode_time_s=dt, tokens=emitted,
-                   occupancy_sum=n_active / self._slots,
-                   kv_util_sum=kv_util)
+        prev = self._inflight
+        try:
+            cur = self._dispatch()
+        except BaseException:
+            self._drop(prev)
+            raise
+        if cur is None:
+            self._drain("empty")
+        elif cause is not None:
+            self._retire(cur)
+        else:
+            self._inflight = cur
+            if prev is not None:
+                self._retire(prev, cur)
         return True
+
+    def _busy(self) -> bool:
+        """Work left: a queued or running request, or a step in
+        flight."""
+        return bool(self._queue) or bool(self._active.any()) or \
+            self._inflight is not None
 
     def run(self, max_steps=100000):
         """Drive the loop until every queued/running request finishes.
@@ -3710,7 +3974,7 @@ class DecodeEngine:
         each active slot by at least one token, so a healthy serve
         always terminates on its own)."""
         steps = 0
-        while self._queue or self._active.any():
+        while self._busy():
             if steps >= max_steps:
                 raise RuntimeError(
                     f"run(max_steps={max_steps}) exhausted with "

@@ -806,7 +806,7 @@ class SpeculativeDecoder:
                 eng._lens[s] += n_emit
                 eng._last[s] = emit[-1]
                 emitted_total += n_emit
-                eng._register_generated_pages(s, req)
+                eng._register_generated_pages(req, int(eng._lens[s]))
                 self.drafter.on_accept(s, int(pos_before[s]), n_emit)
                 if self.adaptive:
                     self._adapt_k(s, m, usable)

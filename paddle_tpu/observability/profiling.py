@@ -427,6 +427,13 @@ class Profiler:
             pass
 
     # -- engine-thread hooks (DecodeEngine.step) -----------------------------
+    @property
+    def probing(self) -> bool:
+        """Does the current step probe (`note_step_begin` decided)?  A
+        probing step's dispatch blocks on its output, so the engine
+        lands the step in flight before it."""
+        return self._probe_now
+
     def note_step_begin(self):
         """Between-steps hook, engine thread, BEFORE admission: arm a
         pending capture and decide whether this step probes.  The

@@ -152,10 +152,20 @@ DECODE_STAT_COUNTERS = (
     # generation-2 ones among them)
     "relay_s", "relayed_tokens", "hop_s", "hops",
     "gc_pause_s", "gc_collections", "gc_gen2_collections",
+    # the serve loop's one-step lookahead (`DecodeEngine.step`): steps
+    # dispatched while the step before them was still in flight; steps
+    # in flight landed before the next dispatch (a speculative round,
+    # the ragged step, the legacy prefill, an armed watchdog, a probing
+    # step, the sanitizer, fault injection, an engine going empty, or
+    # the caller's `drain`; the cause is on the flight record); and rows
+    # landed for a request that had left meanwhile (eos, quarantine,
+    # cancel, preemption), whose tokens are thrown away
+    "lookahead_steps", "lookahead_drains", "lookahead_dropped_rows",
 )
 DECODE_STAT_DERIVED = ("avg_step_ms", "batch_occupancy",
                        "kv_block_utilization",
-                       "acceptance_rate", "mean_accepted_per_step")
+                       "acceptance_rate", "mean_accepted_per_step",
+                       "lookahead_share")
 
 
 def _decode_stat_zero(key):

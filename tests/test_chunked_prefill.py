@@ -63,6 +63,14 @@ def _engine(m, **kw):
     return DecodeEngine(m, **kw)
 
 
+def _step(eng):
+    """One step on the synchronous schedule: dispatched and landed in
+    this call (`step` alone leaves it in flight; tests/test_lookahead.py
+    pins the two schedules' tokens equal)."""
+    eng.step()
+    eng.drain()
+
+
 # ---------------------------------------------------------------------------
 # RNG stream domains (satellite: fold_in counters can never alias)
 # ---------------------------------------------------------------------------
@@ -194,12 +202,12 @@ class TestChunkedScheduling:
         # chunks land at steps 1..3 (16 + 16 + 8): no first token, no
         # TTFT observation until the LAST one
         for expect_pos in (16, 32):
-            eng.step()
+            _step(eng)
             assert req.output_ids == []
             assert req.t_first_token_ns is None
             assert int(eng._prefill_pos[0]) == expect_pos
             assert obs.REQUEST_TTFT.series_state()["count"] == 0
-        eng.step()
+        _step(eng)
         assert int(eng._prefill_pos[0]) == 40
         assert len(req.output_ids) == 1
         assert req.t_first_token_ns is not None
@@ -234,12 +242,12 @@ class TestChunkedScheduling:
         eng = _engine(m, prefill_chunk_tokens=8)
         ra = eng.add_request(rng.randint(0, 64, (4,)).astype(np.int32),
                              max_new_tokens=20)
-        eng.step()  # consumes ra's prompt, first token
+        _step(eng)  # consumes ra's prompt, first token
         assert len(ra.output_ids) == 1
         rb = eng.add_request(rng.randint(0, 64, (24,)).astype(np.int32),
                              max_new_tokens=6)
         for i in range(3):  # rb needs 3 chunks of 8
-            eng.step()
+            _step(eng)
             assert len(ra.output_ids) == 2 + i  # ra never stalled
         assert len(rb.output_ids) == 1  # rb's first token on chunk 3
         st = decode_stats()
@@ -260,13 +268,13 @@ class TestChunkedScheduling:
         eng = _engine(m, prefill_chunk_tokens=8)
         r0 = eng.add_request(prompts[0], max_new_tokens=5)
         r1 = eng.add_request(prompts[1], max_new_tokens=5)
-        eng.step()  # 8-token budget splits 4 + 4
+        _step(eng)  # 8-token budget splits 4 + 4
         assert int(eng._prefill_pos[0]) == 4
         assert int(eng._prefill_pos[1]) == 4
-        eng.step()
+        _step(eng)
         assert int(eng._prefill_pos[0]) == 8
         assert int(eng._prefill_pos[1]) == 8
-        eng.step()  # both prompts land, both first tokens sampled
+        _step(eng)  # both prompts land, both first tokens sampled
         assert len(r0.output_ids) == 1 and len(r1.output_ids) == 1
         eng.run()
         assert [list(r0.output_ids), list(r1.output_ids)] == legacy
@@ -284,7 +292,7 @@ class TestChunkedScheduling:
             rng.randint(0, 64, (40,)).astype(np.int32), max_new_tokens=4)
         short_r = eng.add_request(
             rng.randint(0, 64, (4,)).astype(np.int32), max_new_tokens=4)
-        eng.step()  # long gets ceil(8/2)=4, short gets its whole 4
+        _step(eng)  # long gets ceil(8/2)=4, short gets its whole 4
         assert len(short_r.output_ids) == 1
         assert long_r.output_ids == []
         assert int(eng._prefill_pos[0]) == 4

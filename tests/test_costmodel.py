@@ -393,7 +393,13 @@ class TestHeadroomAndAdmission:
             runner = eng.add_request(_prompts(1)[0], max_new_tokens=6)
             cand = eng.add_request(_prompts(1, seed=1)[0],
                                    max_new_tokens=4, slo_tpot_ms=0.001)
-            eng.run()
+            # the synchronous schedule: the runner's last token lands
+            # before the next admission (under the lookahead its slot
+            # is free, and the candidate in, while that token is in
+            # flight)
+            while eng._busy():
+                eng.step()
+                eng.drain()
             assert runner.finish_reason == "length"
             assert cand.finish_reason == "length"
             # the candidate entered only after the runner left
@@ -471,8 +477,11 @@ class TestSurfaces:
         eng = _engine(model, flight_window=256)
         reqs = eng.generate(_prompts(2), max_new_tokens=6)
         window = eng._flight.snapshot()
-        rid = window["records"][-1]["slots"][0]["request"] \
-            if window["records"][-1].get("slots") else 0
+        # the last record with a batch (the engine's last call lands
+        # the step in flight with its slots already empty)
+        rid = next((rec["slots"][0]["request"]
+                    for rec in reversed(window["records"])
+                    if rec.get("slots")), 0)
         lines = explain(window, rid)
         assert any("pred=" in ln and "/act=" in ln for ln in lines), \
             lines[:10]

@@ -72,6 +72,11 @@ def _engine(m, **kw):
     return DecodeEngine(m, **kw)
 
 
+def _landed_step(eng):
+    eng.step()
+    eng.drain()
+
+
 def _prompt(rng, n=8):
     return rng.randint(0, TINY.vocab_size, (n,)).astype(np.int32)
 
@@ -224,6 +229,10 @@ class TestDeadline:
 # priority ordering under slot exhaustion
 # ---------------------------------------------------------------------------
 class TestPriorityOrdering:
+    """Admission order, one landed step a call (`step(); drain()`: the
+    synchronous schedule), so a finish and the next admission fall in
+    separate calls as the assertions read them."""
+
     def test_interactive_admitted_before_earlier_batch(self):
         m = _tiny_gpt(seed=6)
         rng = np.random.RandomState(2)
@@ -236,9 +245,9 @@ class TestPriorityOrdering:
         inter = eng.add_request(_prompt(rng), max_new_tokens=4,
                                 priority=PRIORITY_INTERACTIVE)
         while runner.state != "done":
-            eng.step()
+            _landed_step(eng)
         assert batch.state == "queued" and inter.state == "queued"
-        eng.step()  # one admission: priority beats arrival order
+        _landed_step(eng)  # one admission: priority beats arrival order
         assert inter.state == "running"
         assert batch.state == "queued"
         eng.run()
@@ -259,14 +268,14 @@ class TestPriorityOrdering:
         tight = eng.add_request(_prompt(rng), max_new_tokens=4,
                                 deadline_ms=30_000.0)
         while runner.state != "done":
-            eng.step()
+            _landed_step(eng)
             assert loose.state == "queued" and tight.state == "queued"
-        eng.step()
+        _landed_step(eng)
         assert tight.state == "running"  # earliest deadline first
         assert loose.state == "queued" and none.state == "queued"
         while tight.state != "done":
-            eng.step()
-        eng.step()
+            _landed_step(eng)
+        _landed_step(eng)
         assert loose.state == "running"  # deadline beats no-deadline
         assert none.state == "queued"
         eng.run()

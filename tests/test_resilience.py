@@ -410,7 +410,9 @@ class TestRecovery:
             eng.step()
         snap = EngineSnapshot(eng)
         assert len(snap) == 2
-        assert snap.step_no == eng._step_no
+        # the last step landed: the one in flight is dispatched again
+        assert eng._inflight is not None
+        assert snap.step_no == eng._step_no - 1
         rec = {id(x.request): x for x in snap.records}
         assert rec[id(r1)].output_ids == list(r1.output_ids)
         assert rec[id(r2)].max_new == NEW
@@ -470,7 +472,10 @@ class TestRecovery:
         for _ in range(5):
             eng.step()
         new = resilience.recover(eng)
-        assert new._step_no == eng._step_no
+        # the rebuilt engine dispatches the step left in flight again,
+        # under the same number (the same key)
+        assert eng._inflight is not None
+        assert new._step_no == eng._step_no - 1
         assert new is not eng and new.pool is not eng.pool
 
     def test_recovery_with_spec_engine(self, model, reference):

@@ -379,8 +379,10 @@ def _serve_step(one_chip, which, num_pages):
     step = functools.partial(
         fn, num_heads=HEADS, head_dim=HEAD_DIM, eps=1e-5, sampler="greedy",
         temperature=1.0, top_k=0, top_p=1.0)
-    return _compile(step, *head, *tail, S((2,), jnp.uint32),
-                    donate_argnums=(1,))
+    # the key, then the step before's sampled tokens and which slots
+    # take their incoming token from them (the engine's lookahead)
+    return _compile(step, *head, *tail, S((2,), jnp.uint32), S((slots,)),
+                    S((slots,), jnp.bool_), donate_argnums=(1,))
 
 
 def _pool_sized_faults(text, num_pages, layer_elems):
